@@ -46,6 +46,9 @@ def main() -> None:
                     help="skip the BENCH_summary.json merge step")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import backend_bench as bb
     from . import obs_bench as obsb
     from . import order_bench as ob

@@ -6,6 +6,4 @@ optim / train / serve / checkpoint / data (substrate), configs (assigned
 architectures), launch (mesh + dry-run + drivers), roofline (HLO analysis).
 """
 
-from . import _jax_compat  # noqa: F401  (side effect: old-JAX shard_map shim)
-
 __version__ = "1.0.0"

@@ -38,7 +38,7 @@ from .backend import (
     resolve_backend,
 )
 from .cost_model import DEFAULT_COST_MODEL, CostModel
-from .errors import (CancelledError, DeadlineError, InputError,
+from .errors import (CancelledError, DeadlineError, InputError, KernelError,
                      NumericalError, ResourceError, TuckerError,
                      check_finite, classify_exception, coerce_exception)
 from .plan import ModeStep, resolve_schedule
@@ -58,7 +58,7 @@ from .sthosvd import (
 
 __all__ = [
     "ALS", "DEFAULT_COST_MODEL", "EIG", "RAND", "SVD",
-    "CancelledError", "CostModel", "DeadlineError", "InputError",
+    "CancelledError", "CostModel", "DeadlineError", "InputError", "KernelError",
     "MemoryCapError", "ModeStep", "NumericalError", "OpsBackend",
     "ResourceError", "ScheduleSearch", "Selector", "SthosvdResult",
     "TuckerConfig", "TuckerError", "TuckerPlan", "TuckerTensor",

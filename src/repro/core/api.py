@@ -363,17 +363,14 @@ def donation_supported(platform: str) -> bool:
     sweep "donated" there would keep the dead copy of X alive anyway.
     """
     import warnings
-    try:
-        dev = jax.devices(platform)[0]
-        # fresh, unshared buffer committed to the probed platform
-        x = jax.device_put(jnp.zeros((2,), jnp.float32) + 1.0, dev)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            jax.block_until_ready(
-                jax.jit(lambda a: a * 2.0, donate_argnums=0)(x))
-        return bool(x.is_deleted())
-    except Exception:  # pragma: no cover - defensive: treat as unsupported
-        return False
+    dev = jax.devices(platform)[0]
+    # fresh, unshared buffer committed to the probed platform
+    x = jax.device_put(jnp.zeros((2,), jnp.float32) + 1.0, dev)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        jax.block_until_ready(
+            jax.jit(lambda a: a * 2.0, donate_argnums=0)(x))
+    return bool(x.is_deleted())
 
 
 # ---------------------------------------------------------------------------
@@ -675,12 +672,13 @@ class TuckerPlan:
         ``submit()`` and quarantines poisoned lanes itself.
 
         On a classified failure (see :mod:`repro.core.errors`) execution
-        degrades along a bounded deterministic ladder — als→eig on
-        numerical breakdown, pallas→matfree on a kernel failure,
-        donated→undonated then replanned-under-a-tighter-cap on runtime
-        OOM — each hop emitted as an obs ``fallback`` event and counted in
-        the metrics registry before the failing class is re-raised only
-        once the ladder is exhausted.
+        degrades along a bounded deterministic ladder — als→eig, then
+        pallas→matfree, on numerical breakdown; donated→undonated then
+        replanned-under-a-tighter-cap on runtime OOM — each hop emitted as
+        an obs ``fallback`` event and counted in the metrics registry
+        before the failing class is re-raised only once the ladder is
+        exhausted.  A kernel that fails to lower or compile
+        (:class:`~repro.core.errors.KernelError`) raises at once.
         """
         if not _obs.enabled():
             return self._execute(x, record=record, donate=donate,
@@ -1416,9 +1414,10 @@ def _next_hop(p: "TuckerPlan", err: BaseException,
             if p2 is not None:
                 return "replan_cap", p2
         return None
-    # unclassified runtime failure: a kernel-backend swap is the only hop
-    # that can plausibly help (and the only one that is safe to try)
-    return to_matfree()
+    # a kernel that failed to lower or compile, or an unclassified failure:
+    # no hop — moving the sweep onto another backend would let a run that
+    # asked for the kernels pass without ever running one
+    return None
 
 
 def _emit_hop(p: "TuckerPlan", name: str, err: BaseException) -> None:

@@ -15,9 +15,10 @@ Built-in backends:
                 (tensor_ops; the paper's Fig. 4 structure via XLA).
   ``explicit``  unfold → GEMM → fold baseline (paper Fig. 3 / Fig. 8).
   ``pallas``    hand-written Pallas TPU kernels (kernels/ops.py): tiled
-                matmul / batched-TTM / TTT with zero-padding shims for
-                non-tile-multiple shapes; interpret-mode fallback off-TPU
-                so the same code path runs (slowly) everywhere.
+                matmul / batched-TTM / TTT that mask ragged edge blocks
+                in-kernel (no padded copies, so the plan's peak model
+                holds as is); interpret-mode fallback off-TPU so the same
+                code path runs (slowly) everywhere.
   ``sharded``   multi-device st-HOSVD over a jax mesh (core/distributed.py):
                 TuckerMPI-style partial-Gram + psum and local TTM under
                 shard_map, resharding to the largest remaining mode between
@@ -68,9 +69,6 @@ class OpsBackend:
         O(I_n·J_n) buffer; the paper's Fig. 8 memory axis).  Note the SVD
         *solver* unfolds regardless of backend — see
         :func:`repro.core.solvers.svd_solve`.
-    tile_align
-        Hardware tile multiple the backend pads to internally (informs the
-        plan-aware-memory model; None = no padding).
     cost_scale
         Relative per-FLOP cost hint vs ``matfree`` on this backend's native
         platform; the selector/cost model may scale Eq. 4/5 estimates by it.
@@ -91,7 +89,6 @@ class OpsBackend:
     dtypes: tuple[str, ...] = ("*",)
     platforms: tuple[str, ...] = ("*",)
     matricizes: bool = False
-    tile_align: int | None = None
     cost_scale: float = 1.0
     interpret_fallback: bool = False
     requires_mesh: bool = False
@@ -240,7 +237,7 @@ register_backend(OpsBackend(
     name="pallas", loader=_load_pallas,
     # fp64 has no Mosaic tile mapping; fp32/bf16 are what the kernels tile
     dtypes=("float32", "bfloat16"), platforms=("tpu",),
-    matricizes=False, tile_align=128,
+    matricizes=False,
     # hand-tiled MXU kernels: modestly better than XLA's generic batched GEMM
     cost_scale=0.9,
     # kernels/ops.py defaults interpret=True off-TPU, so explicit

@@ -43,7 +43,8 @@ from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax.core import Tracer
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..obs import drift as _drift
@@ -52,11 +53,6 @@ from . import tensor_ops as T
 from .plan import ModeStep, solve_step
 from .solvers import DEFAULT_ALS_ITERS, als_solve
 from .sthosvd import ModeTrace, SthosvdResult, TuckerTensor
-
-try:  # jax.core.Tracer is deprecated on newer jax; _src.core keeps it
-    from jax.core import Tracer as _Tracer
-except (ImportError, AttributeError):  # pragma: no cover - jax-version dependent
-    from jax._src.core import Tracer as _Tracer
 
 
 def _spec_for(ndim: int, mode: int | None, axis: str) -> P:
@@ -68,12 +64,40 @@ def _spec_for(ndim: int, mode: int | None, axis: str) -> P:
 
 def _reshard(x: jax.Array, mesh: Mesh, mode: int | None, axis: str) -> jax.Array:
     """Move ``x`` onto the mesh, sharded on ``mode`` (None = replicated).
-    Inside a jit trace this lowers to a sharding constraint (GSPMD inserts
-    the all-to-all); eagerly it is a device_put."""
+    On an ``Explicit`` shard axis (what ``jax.make_mesh`` builds) this is
+    ``jax.sharding.reshard``, eager or traced.  On an ``Auto`` axis it is a
+    sharding constraint inside a jit trace (GSPMD inserts the all-to-all)
+    and a device_put eagerly."""
     sh = NamedSharding(mesh, _spec_for(x.ndim, mode, axis))
-    if isinstance(x, _Tracer):
+    if _explicit(mesh, axis):
+        return jax.sharding.reshard(x, sh)
+    if isinstance(x, Tracer):
         return jax.lax.with_sharding_constraint(x, sh)
     return jax.device_put(x, sh)
+
+
+def _explicit(mesh: Mesh, axis: str) -> bool:
+    return mesh.axis_types[mesh.axis_names.index(axis)] == AxisType.Explicit
+
+
+def _als_gspmd(y: jax.Array, step: ModeStep, mesh: Mesh, axis: str, *,
+               als_iters: int):
+    """ALS on a ``y`` sharded on ``step.shard_mode``, partitioned by GSPMD:
+    its TTM/TTT chain contracts the sharded mode and XLA inserts the psums.
+    An ``Explicit`` shard axis is switched to ``Auto`` for the solve
+    (``auto_axes``), since explicit sharding types refuse a contraction
+    over a sharded dim.  Returns ``(u, y_new)``, ``y_new`` on the same
+    shard mode."""
+    def solve(y):
+        res = als_solve(y, step.mode, step.r_n, num_iters=als_iters)
+        return res.u, res.y_new
+
+    if not _explicit(mesh, axis):
+        u, y_new = solve(y)
+        return u, _reshard(y_new, mesh, step.shard_mode, axis)
+    out = (NamedSharding(mesh, P()),
+           NamedSharding(mesh, _spec_for(y.ndim, step.shard_mode, axis)))
+    return jax.sharding.auto_axes(solve, axes=axis, out_sharding=out)(y)
 
 
 @lru_cache(maxsize=256)
@@ -204,9 +228,7 @@ def solve_step_sharded(y: jax.Array, step: ModeStep, mesh: Mesh, axis: str,
         y = _ttm_local(mesh, axis, n, step.mode, step.shard_mode)(y, u.T)
         return u, y
     if step.method == "als":
-        # GSPMD path: y carries the shard constraint, XLA inserts the psums
-        u, y_new = als_solve(y, step.mode, step.r_n, num_iters=als_iters)
-        return u, _reshard(y_new, mesh, step.shard_mode, axis)
+        return _als_gspmd(y, step, mesh, axis, als_iters=als_iters)
     raise ValueError(f"unknown distributed method {step.method!r}")
 
 
@@ -256,7 +278,7 @@ def solve_group_sharded(y: jax.Array, group, mesh: Mesh, axis: str, *,
         if step.method == "als":
             # GSPMD from the shared (still un-shrunk) input; the eager
             # y_new it also produces is unused and DCE'd under jit
-            u, _ = als_solve(y, step.mode, step.r_n, num_iters=als_iters)
+            u, _ = _als_gspmd(y, step, mesh, axis, als_iters=als_iters)
             factors[step.mode] = u
     modes_all = tuple(s.mode for s in group)
     uts = tuple(factors[m].T for m in modes_all)

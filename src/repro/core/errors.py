@@ -12,6 +12,9 @@ pattern-matching XLA message strings:
     ``FloatingPointError``.
   * :class:`ResourceError`    — the runtime ran out of something (XLA
     ``RESOURCE_EXHAUSTED`` / OOM, a dead or abandoned worker).
+  * :class:`KernelError`      — a Pallas kernel failed to lower or compile
+    for the platform (a Mosaic error).  Never degraded onto another
+    backend: a run that asked for the kernels must not pass without them.
   * :class:`DeadlineError`    — a serve request missed its ``deadline_s``
     before dispatch.  Subclasses ``TimeoutError``.
   * :class:`CancelledError`   — the caller retracted the request via
@@ -27,15 +30,17 @@ the matching builtins keeps every pre-taxonomy ``except ValueError`` /
 
 The execute-time fallback ladder (see ``TuckerPlan.execute``) keys its
 hops off these classes: rand→eig on a sketch error-target miss, als→eig
-on :class:`NumericalError`, pallas→matfree on a kernel failure,
+(then pallas→matfree) on :class:`NumericalError`,
 donated→undonated→replanned-under-a-tighter-cap on
-:class:`ResourceError`.
+:class:`ResourceError`.  :class:`KernelError` and unclassified failures
+raise without a hop.
 """
 
 from __future__ import annotations
 
 __all__ = [
-    "CancelledError", "DeadlineError", "InputError", "NumericalError",
+    "CancelledError", "DeadlineError", "InputError", "KernelError",
+    "NumericalError",
     "ResourceError", "TuckerError", "check_finite", "check_result_finite",
     "classify_exception", "coerce_exception",
 ]
@@ -66,6 +71,11 @@ class ResourceError(TuckerError):
     an allocation failure, or a serve worker that died/was abandoned."""
 
 
+class KernelError(TuckerError):
+    """A Pallas kernel failed to lower or to compile (Mosaic) for the
+    platform it was asked to run on."""
+
+
 class DeadlineError(TuckerError, TimeoutError):
     """A served request's ``deadline_s`` expired before it was dispatched
     (checked at admission and again at wave formation)."""
@@ -81,6 +91,13 @@ _RESOURCE_MARKERS = (
     "RESOURCE_EXHAUSTED", "RESOURCE EXHAUSTED", "Out of memory",
     "out of memory", "OOM", "failed to allocate", "Failed to allocate",
     "Resource exhausted",
+)
+#: exception types and message fragments that mark a kernel that failed to
+#: lower (Pallas) or to compile (Mosaic); checked before the resource
+#: markers, since a kernel over its VMEM budget also reports exhaustion
+_KERNEL_TYPES = ("LoweringException", "MosaicError")
+_KERNEL_MARKERS = (
+    "Mosaic", "Only interpret mode is supported",
 )
 #: message fragments that mark a numerical breakdown
 _NUMERICAL_MARKERS = (
@@ -99,7 +116,10 @@ def classify_exception(exc: BaseException) -> TuckerError | None:
         return exc
     msg = str(exc)
     wrapped: TuckerError | None = None
-    if isinstance(exc, MemoryError) or \
+    if type(exc).__name__ in _KERNEL_TYPES or \
+            any(m in msg for m in _KERNEL_MARKERS):
+        wrapped = KernelError(f"kernel failed to lower or compile: {msg}")
+    elif isinstance(exc, MemoryError) or \
             any(m in msg for m in _RESOURCE_MARKERS):
         wrapped = ResourceError(f"resource exhausted: {msg}")
     elif isinstance(exc, (FloatingPointError, ZeroDivisionError)) or \

@@ -5,14 +5,23 @@ These are the primitives behind the ``pallas`` ops backend
 ``impl="auto"`` on TPU — routes every TTM/TTT/Gram of a plan's sweep through
 this module.
 
-Dispatch mirrors the paper's Fig. 4 structure:
-  mode == 0    → single GEMM   u @ X_(0-view)          (matmul kernel)
-  mode == N-1  → single GEMM   X_(view) @ uᵀ           (matmul kernel)
-  interior     → batched GEMM over merged outer dims   (ttm_interior kernel)
+Dispatch mirrors the paper's Fig. 4 structure, on views that only merge
+adjacent axes:
+  mode == 0    → (I_n, B) view:  TTM = one GEMM u @ X,   Gram/TTT = X Yᵀ
+  mode == N-1  → (A, I_n) view:  TTM = one GEMM X @ uᵀ,  Gram/TTT = Xᵀ Y
+  interior     → (A, I_n, B) view: batched GEMM / TTT over the merged A
+No view puts a unit or tiny axis on the lanes: a tensor whose last axis is
+narrower than a lane tile is first transposed so a wide axis is last
+(:func:`_lane_order`), one relayout copy instead of a 128-fold padded one.
+No operand is padded either: the kernels take ragged edge blocks and zero
+the out-of-range part of a contraction in-kernel, so the only HBM buffers
+are the tensor, its view (XLA copies it when a merge crosses the tiled
+minor axes) and the result.
 
-Wrappers zero-pad every tiled dim up to the block multiple (exact for the
-contraction dims, sliced off for output dims) and pick TPU-legal tiles:
-lane (last) dim tiles are multiples of 128, sublane dims multiples of 8.
+Tiles: an output or target-mode axis takes its whole extent up to
+``_TILE`` (always legal) and ``_TILE`` tiles beyond; a streamed (reduced
+or batch) axis takes as much as fits ``_BLOCK_BYTES`` against its block's
+padded cross-section — multiples of 128 on lanes and 8 on sublanes.
 
 ``interpret`` defaults to True off-TPU so the same code path validates on
 CPU (Pallas interpreter) and compiles to Mosaic on the TPU target.
@@ -24,11 +33,17 @@ import math
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from .matmul import matmul
 from .ttm import ttm_interior
-from .ttt import ttt_pallas3
+from .ttt import ttt_nt, ttt_tn
+
+_LANE, _SUBLANE = 128, 8
+#: cap on a non-streamed block axis (a multiple of _LANE, so always legal)
+_TILE = 512
+#: one streamed input block, padded, at fp32: double-buffered inputs plus
+#: the resident output tile stay inside the default scoped VMEM
+_BLOCK_BYTES = 2 << 20
 
 
 def _default_interpret() -> bool:
@@ -39,22 +54,44 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def _tile(dim: int, cap: int, align: int) -> int:
-    """Tile size ≤ cap, aligned to ``align``, no larger than needed."""
-    return min(cap, _round_up(dim, align))
+def _fit(dim: int) -> int:
+    """Block extent along a non-streamed axis: whole, or ``_TILE`` tiles."""
+    return dim if dim <= _TILE else _TILE
 
 
-def _pad_to(x: jax.Array, targets: tuple[int, ...]) -> jax.Array:
-    pads = [(0, t - s) for s, t in zip(x.shape, targets)]
-    if any(p[1] for p in pads):
-        x = jnp.pad(x, pads)
-    return x
+def _stream(dim: int, cross: int, align: int) -> int:
+    """Block extent along a streamed axis whose block has padded
+    cross-section ``cross`` elements: the whole axis when it fits
+    ``_BLOCK_BYTES``, else the largest ``align`` multiple that does."""
+    n = max(align, _BLOCK_BYTES // (4 * cross) // align * align)
+    return dim if dim <= n else n
 
 
-def _as3(x: jax.Array, mode: int) -> jax.Array:
-    a = math.prod(x.shape[:mode]) if mode else 1
-    b = math.prod(x.shape[mode + 1:]) if mode < x.ndim - 1 else 1
-    return x.reshape(a, x.shape[mode], b)
+def _batch(a: int, slab: int) -> int:
+    """Slices of the merged outer axis per block: the largest divisor of
+    ``a`` whose ``slab``-element slices fit ``_BLOCK_BYTES``."""
+    cap = max(1, _BLOCK_BYTES // (4 * slab))
+    return max(d for d in range(1, min(a, cap) + 1) if a % d == 0)
+
+
+def _split(shape: tuple[int, ...], mode: int) -> tuple[int, int, int]:
+    return (math.prod(shape[:mode]), shape[mode], math.prod(shape[mode + 1:]))
+
+
+def _lane_order(shape: tuple[int, ...], mode: int) -> tuple[int, ...] | None:
+    """Axis order that puts a lane-wide axis last, for a tensor whose last
+    axis is narrower than a lane tile: the other narrow axes lead, the wide
+    ones follow, and a narrow target ``mode`` sits just before the last wide
+    axis, so its view is (A, I_n, B) with B wide and nothing merged across
+    the tiled minor axes.  None when the last axis is wide, or none is."""
+    if shape[-1] >= _LANE or all(d < _LANE for d in shape):
+        return None
+    narrow = [m for m, d in enumerate(shape) if d < _LANE and m != mode]
+    wide = [m for m, d in enumerate(shape) if d >= _LANE or m == mode]
+    if shape[mode] < _LANE:
+        wide.remove(mode)
+        wide.insert(len(wide) - 1, mode)
+    return tuple(narrow + wide)
 
 
 @partial(jax.jit, static_argnames=("mode", "interpret"))
@@ -63,38 +100,27 @@ def ttm(x: jax.Array, u: jax.Array, mode: int, *, interpret: bool | None = None)
     interpret = _default_interpret() if interpret is None else interpret
     r, i = u.shape
     assert x.shape[mode] == i, (x.shape, u.shape, mode)
+    order = _lane_order(x.shape, mode)
+    if order is not None:
+        y = ttm(x.transpose(order), u, order.index(mode), interpret=interpret)
+        return y.transpose(tuple(order.index(m) for m in range(x.ndim)))
     out_shape = x.shape[:mode] + (r,) + x.shape[mode + 1:]
-    n = x.ndim
-
+    a, _, b = _split(x.shape, mode)
+    bi, br = _fit(i), _fit(r)
     if mode == 0:
-        x2 = x.reshape(i, -1)
-        bm = _tile(r, 128, 8)
-        bk = _tile(i, 128, 8)
-        bn = _tile(x2.shape[1], 512, 128)
-        up = _pad_to(u, (_round_up(r, bm), _round_up(i, bk)))
-        xp = _pad_to(x2, (_round_up(i, bk), _round_up(x2.shape[1], bn)))
-        y = matmul(up, xp, bm=bm, bn=bn, bk=bk, interpret=interpret)
-        y = y[:r, :x2.shape[1]]
-    elif mode == n - 1:
-        x2 = x.reshape(-1, i)
-        m = x2.shape[0]
-        bm = _tile(m, 128, 8)
-        bk = _tile(i, 128, 8)
-        bn = _tile(r, 128, 128)
-        xp = _pad_to(x2, (_round_up(m, bm), _round_up(i, bk)))
-        ut = _pad_to(u.T, (_round_up(i, bk), _round_up(r, bn)))
-        y = matmul(xp, ut, bm=bm, bn=bn, bk=bk, interpret=interpret)
-        y = y[:m, :r]
+        bn = _stream(b, _round_up(max(bi, br), _SUBLANE), _LANE)
+        y = matmul(u, x.reshape(i, b), bm=br, bn=bn, bk=bi,
+                   interpret=interpret)
+    elif mode == x.ndim - 1:
+        bm = _stream(a, _round_up(max(bi, br), _LANE), _SUBLANE)
+        y = matmul(x.reshape(a, i), u.T, bm=bm, bn=br, bk=bi,
+                   interpret=interpret)
     else:
-        x3 = _as3(x, mode)
-        a, _, b = x3.shape
-        br = _tile(r, 128, 8)
-        bi = _tile(i, 128, 8)
-        bb = _tile(b, 256, 128)
-        up = _pad_to(u, (_round_up(r, br), _round_up(i, bi)))
-        xp = _pad_to(x3, (a, _round_up(i, bi), _round_up(b, bb)))
-        y = ttm_interior(up, xp, br=br, bb=bb, bi=bi, interpret=interpret)
-        y = y[:, :r, :b]
+        bb = _stream(b, _round_up(max(bi, br), _SUBLANE), _LANE)
+        ba = _batch(a, _round_up(max(bi, br), _SUBLANE)
+                    * _round_up(bb, _LANE))
+        y = ttm_interior(u, x.reshape(a, i, b), br=br, bb=bb, bi=bi, ba=ba,
+                         interpret=interpret)
     return y.reshape(out_shape)
 
 
@@ -102,28 +128,27 @@ def ttm(x: jax.Array, u: jax.Array, mode: int, *, interpret: bool | None = None)
 def ttt(x: jax.Array, y: jax.Array, mode: int, *, interpret: bool | None = None) -> jax.Array:
     """z (I_mode, R_mode) = contraction of x, y over all modes but ``mode``."""
     interpret = _default_interpret() if interpret is None else interpret
-    x3 = _as3(x, mode)
-    y3 = _as3(y, mode)
-    a, i, b = x3.shape
-    _, r, _ = y3.shape
-    bi = _tile(i, 128, 8)
-    br = _tile(r, 128, 128)   # r is the lane dim of the output
-    bb = _tile(b, 256, 128)
-    xp = _pad_to(x3, (a, _round_up(i, bi), _round_up(b, bb)))
-    yp = _pad_to(y3, (a, _round_up(r, br), _round_up(b, bb)))
-    z = ttt_pallas3(xp, yp, bi=bi, br=br, bb=bb, interpret=interpret)
-    return z[:i, :r]
+    order = _lane_order(x.shape, mode)
+    if order is not None:
+        return ttt(x.transpose(order), y.transpose(order), order.index(mode),
+                   interpret=interpret)
+    a, i, b = _split(x.shape, mode)
+    r = y.shape[mode]
+    bi, br = _fit(i), _fit(r)
+    if mode == x.ndim - 1:
+        ba = _stream(a, _round_up(max(bi, br), _LANE), _SUBLANE)
+        return ttt_tn(x.reshape(a, i), y.reshape(a, r), bi=bi, br=br, ba=ba,
+                      interpret=interpret)
+    rows = _round_up(max(bi, br), _SUBLANE)
+    bb = _stream(b, rows, _LANE)
+    if mode == 0:
+        return ttt_nt(x.reshape(i, b), y.reshape(r, b), bi=bi, br=br, bb=bb,
+                      interpret=interpret)
+    ba = _batch(a, rows * _round_up(bb, _LANE))
+    return ttt_nt(x.reshape(a, i, b), y.reshape(a, r, b), bi=bi, br=br,
+                  bb=bb, ba=ba, interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("mode", "interpret"))
 def gram(x: jax.Array, mode: int, *, interpret: bool | None = None) -> jax.Array:
-    """S (I_mode, I_mode) = Y_(n) Y_(n)ᵀ without unfolding."""
-    interpret = _default_interpret() if interpret is None else interpret
-    x3 = _as3(x, mode)
-    a, i, b = x3.shape
-    # one tile size for both output axes (the padded I must tile both ways)
-    bi = br = _tile(i, 128, 128)
-    bb = _tile(b, 256, 128)
-    xp = _pad_to(x3, (a, _round_up(i, bi), _round_up(b, bb)))
-    z = ttt_pallas3(xp, xp, bi=bi, br=br, bb=bb, interpret=interpret)
-    return z[:i, :i]
+    """S (I_mode, I_mode) = Y_(n) Y_(n)ᵀ without unfolding: TTT with y ≡ x."""
+    return ttt(x, x, mode, interpret=interpret)

@@ -29,8 +29,8 @@ from .. import configs
 from ..models import build, input_specs
 from ..models.config import SHAPES
 from ..models.registry import cache_specs
-from ..models.shardings import (batch_specs, cache_specs_tree, param_specs,
-                                to_shardings)
+from ..models.shardings import (auto_mesh, batch_specs, cache_specs_tree,
+                                param_specs, to_shardings)
 from ..models import shardings as shardings_mod
 from ..optim.adamw import AdamW
 from ..roofline import hlo_walk
@@ -56,6 +56,7 @@ def build_cell(arch: str, shape_name: str, mesh, *, smoke: bool = False,
     """
     cfg = cfg_override or (configs.get_smoke(arch) if smoke else configs.get(arch))
     shape = shape_override or SHAPES[shape_name]
+    mesh = auto_mesh(mesh)
     bundle = build(cfg)
     optimizer = AdamW(lr=1e-4)
 

@@ -20,7 +20,7 @@ import re
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
@@ -69,6 +69,13 @@ def _leaf_spec(path: str, shape: tuple[int, ...], mesh: Mesh, *,
                 break
 
     return P(*parts)
+
+
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  The model code shards through
+    GSPMD sharding constraints, which the ``Explicit`` axes that
+    ``jax.make_mesh`` builds by default refuse."""
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def _axes_size(mesh: Mesh, axis) -> int:
@@ -160,7 +167,7 @@ _ACT_BATCH_AXES: tuple[str, ...] = ("pod", "data")
 
 def set_activation_mesh(mesh: Mesh | None, axes: tuple[str, ...] = ("pod", "data")):
     global _ACT_MESH, _ACT_BATCH_AXES
-    _ACT_MESH = mesh
+    _ACT_MESH = None if mesh is None else auto_mesh(mesh)
     _ACT_BATCH_AXES = tuple(axes)
 
 

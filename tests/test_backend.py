@@ -64,7 +64,7 @@ class TestRegistry:
         assert get_backend("explicit").matricizes
         assert not get_backend("matfree").matricizes
         p = get_backend("pallas")
-        assert p.tile_align == 128 and p.interpret_fallback
+        assert p.interpret_fallback and p.native_on("tpu")
         assert not p.supports_dtype(jnp.float64)
         assert p.supports_dtype(jnp.bfloat16)
 

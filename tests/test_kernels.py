@@ -90,6 +90,37 @@ class TestGramTTTOps:
                                    rtol=3e-4, atol=3e-4)
 
 
+class TestRaggedTiles:
+    """Tiles shrunk so small shapes hit what only large ones hit at the
+    real tile sizes: several output tiles, ragged edge blocks on every kind
+    of axis (masked in-kernel, never padded), and the transposed view of a
+    tensor whose last axis is narrower than a lane tile."""
+
+    @pytest.fixture(autouse=True)
+    def small_tiles(self, monkeypatch):
+        monkeypatch.setattr(ops, "_TILE", 128)
+        monkeypatch.setattr(ops, "_BLOCK_BYTES", 16 << 10)
+
+    @pytest.mark.parametrize("shape,mode,r", [
+        ((300, 7, 150), 0, 9), ((7, 300, 150), 1, 130), ((9, 150, 300), 2, 5),
+        ((150, 300, 6), 2, 4), ((300, 150, 5), 0, 3), ((6, 140, 5, 3), 1, 7),
+    ])
+    def test_ops_vs_oracle(self, shape, mode, r):
+        x = arr(shape, seed=11)
+        u = arr((r, shape[mode]), seed=12)
+        y = arr(shape[:mode] + (r,) + shape[mode + 1:], seed=13)
+        np.testing.assert_allclose(np.asarray(ops.ttm(x, u, mode)),
+                                   np.asarray(ref.ttm_full_ref(x, u, mode)),
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(ops.gram(x, mode)),
+                                   np.asarray(ref.gram_full_ref(x, mode)),
+                                   rtol=3e-4, atol=3e-4)
+        a = int(np.prod(shape[:mode]))
+        want = ref.ttt_ref(x.reshape(a, shape[mode], -1), y.reshape(a, r, -1))
+        np.testing.assert_allclose(np.asarray(ops.ttt(x, y, mode)),
+                                   np.asarray(want), rtol=3e-4, atol=3e-4)
+
+
 class TestKernelInSolver:
     def test_sthosvd_with_pallas_gram(self):
         """The kernel path plugs into the real algorithm: one EIG mode solve

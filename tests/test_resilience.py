@@ -151,6 +151,27 @@ class TestFallbackLadder:
             p.execute(x)
         assert sum(chaos.fired().values()) <= 4   # one attempt per rung, no retry storms
 
+    def test_kernel_lowering_error_raises_without_hop(self, monkeypatch):
+        # compiled (not interpreted) Pallas kernels cannot lower on CPU: a
+        # real lowering failure, which must surface as KernelError instead
+        # of silently re-planning the sweep onto matfree
+        from repro import obs
+        from repro.core import KernelError
+        from repro.kernels import ops
+        from repro.obs import metrics
+        assert isinstance(classify_exception(RuntimeError(
+            "INTERNAL: Mosaic failed to compile TPU kernel")), KernelError)
+        monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+        hops = metrics.REGISTRY.counter("atucker_fallback_hops_total", "")
+        before = hops.value(hop="pallas_to_matfree", backend="pallas")
+        x = _rand((11, 13, 9), seed=6)
+        p = plan(x.shape, F32, TuckerConfig(ranks=(3, 3, 3), impl="pallas",
+                                            methods="eig"))
+        with obs.capture() as buf, pytest.raises(KernelError):
+            p.execute(x)
+        assert not [e for e in buf.events() if e.get("kind") == "fallback"]
+        assert hops.value(hop="pallas_to_matfree", backend="pallas") == before
+
     def test_nan_input_rejected_by_validate(self):
         x = _rand((8, 8, 8), seed=4)
         x[2, :, :] = np.inf
