@@ -806,17 +806,15 @@ class TuckerPlan:
                 for m, u in enumerate(factors):
                     if m != step.mode:
                         y = T.ttm(y, u.T, m)
-                wall0 = _time.time()
-                t0 = _time.perf_counter()
-                res = solve_step(y, step, als_iters=cfg.als_iters)
-                jax.block_until_ready(res.u)
-                dt = _time.perf_counter() - t0
+                with _obs.span("solve", mode=step.mode, solver=step.method,
+                               backend=step.backend, platform=platform,
+                               rank=step.r_n, i_n=step.i_n, j_n=step.j_n,
+                               predicted_s=step.predicted_s):
+                    t0 = _time.perf_counter()
+                    res = solve_step(y, step, als_iters=cfg.als_iters)
+                    jax.block_until_ready(res.u)
+                    dt = _time.perf_counter() - t0
                 seconds.append(dt)
-                _obs.event("span", t=wall0, name="solve", dur_s=dt,
-                           mode=step.mode, solver=step.method,
-                           backend=step.backend, platform=platform,
-                           rank=step.r_n, i_n=step.i_n, j_n=step.j_n,
-                           predicted_s=step.predicted_s)
                 _drift.MONITOR.observe(platform=platform,
                                        backend=step.backend,
                                        solver=step.method,
@@ -901,57 +899,63 @@ class TuckerPlan:
         missed: list[int] = []
         platform = jax.default_backend()
         for s in self.schedule:
-            wall0 = _time.time()
-            t0 = _time.perf_counter()
-            _chaos.fire("sketch", mode=s.mode)
-            js.append(int(y.size // y.shape[s.mode]))
-            width_cap = min(s.i_n, s.rank_grid[-1] + cfg.oversample)
-            width = min(width_cap, max(16, 2 * cfg.oversample,
-                                       s.rank_grid[0] + cfg.oversample))
-            while True:
-                q, b, evals, vecs, energy = rand_sketch(
-                    y, s.mode, width, power_iters=cfg.power_iters,
-                    impl=s.backend)
-                ev = np.maximum(np.asarray(evals, dtype=np.float64), 0.0)
-                energy = float(energy)
-                if total is None:
-                    total = energy or 1.0  # step 0: ||X||², the budget basis
-                csum = np.cumsum(ev[::-1])  # csum[r-1] = top-r captured
-                budget = s.tau * total
-                r = tail = None
-                for cand in s.rank_grid:    # ascending: smallest fit wins
-                    if cand > width:
+            with _obs.span("sketch", mode=s.mode, solver="rand",
+                           backend=s.backend, platform=platform, i_n=s.i_n,
+                           predicted_s=s.predicted_s) as sp:
+                t0 = _time.perf_counter()
+                _chaos.fire("sketch", mode=s.mode)
+                js.append(int(y.size // y.shape[s.mode]))
+                width_cap = min(s.i_n, s.rank_grid[-1] + cfg.oversample)
+                width = min(width_cap, max(16, 2 * cfg.oversample,
+                                           s.rank_grid[0] + cfg.oversample))
+                widths = 0
+                while True:
+                    q, b, evals, vecs, energy = rand_sketch(
+                        y, s.mode, width, power_iters=cfg.power_iters,
+                        impl=s.backend)
+                    widths += 1
+                    # the host waits here for the sketch it reads
+                    with _obs.span("sketch.readback", mode=s.mode,
+                                   width=int(width)):
+                        ev = np.maximum(np.asarray(evals, dtype=np.float64),
+                                        0.0)
+                        energy = float(energy)
+                    if total is None:
+                        # step 0: ||X||², the budget basis
+                        total = energy or 1.0
+                    csum = np.cumsum(ev[::-1])  # csum[r-1] = top-r captured
+                    budget = s.tau * total
+                    r = tail = None
+                    for cand in s.rank_grid:    # ascending: smallest fit wins
+                        if cand > width:
+                            break
+                        t = max(energy - float(csum[cand - 1]), 0.0)
+                        if t <= budget:
+                            r, tail = cand, t
+                            break
+                    if r is not None or width >= width_cap:
                         break
-                    t = max(energy - float(csum[cand - 1]), 0.0)
-                    if t <= budget:
-                        r, tail = cand, t
-                        break
-                if r is not None or width >= width_cap:
-                    break
-                width = min(2 * width, width_cap)
-            if r is None:   # no candidate fits even at the cap width: take
-                            # the largest grid rank the sketch can express
-                r = max(g for g in s.rank_grid if g <= width)
-                tail = max(energy - float(csum[r - 1]), 0.0)
-                missed.append(s.mode)
-            chosen[s.mode], tails[s.mode] = int(r), tail / total
-            # top-r Ritz rotation of the range basis; shrink via the
-            # already-projected b — no second pass over the input
-            v = vecs[:, -r:][:, ::-1].astype(q.dtype)
-            u = jnp.dot(q, v, precision=jax.lax.Precision.HIGHEST)
-            factors[s.mode] = u.astype(wdtype)
-            ttm = backend_ops(s.backend)[0]
-            y = ttm(b, v.T, s.mode).astype(wdtype)
-            jax.block_until_ready(y)
-            dt = _time.perf_counter() - t0
+                    width = min(2 * width, width_cap)
+                if r is None:   # no candidate fits even at the cap width: take
+                                # the largest grid rank the sketch can express
+                    r = max(g for g in s.rank_grid if g <= width)
+                    tail = max(energy - float(csum[r - 1]), 0.0)
+                    missed.append(s.mode)
+                chosen[s.mode], tails[s.mode] = int(r), tail / total
+                # top-r Ritz rotation of the range basis; shrink via the
+                # already-projected b — no second pass over the input
+                v = vecs[:, -r:][:, ::-1].astype(q.dtype)
+                u = jnp.dot(q, v, precision=jax.lax.Precision.HIGHEST)
+                factors[s.mode] = u.astype(wdtype)
+                ttm = backend_ops(s.backend)[0]
+                y = ttm(b, v.T, s.mode).astype(wdtype)
+                with _obs.span("sketch.readback", mode=s.mode,
+                               width=int(width)):
+                    jax.block_until_ready(y)
+                dt = _time.perf_counter() - t0
+                sp.set(rank=int(r), tail_err=tail / total, width=int(width),
+                       j_n=js[-1], widths=widths)
             seconds.append(dt)
-            # retroactive span (no enter/exit to leak on solver errors):
-            # same shape a live Span emits, parented under the execute span
-            _obs.event("span", t=wall0, name="sketch", dur_s=dt,
-                       mode=s.mode, solver="rand", backend=s.backend,
-                       platform=platform, i_n=s.i_n, rank=int(r),
-                       tail_err=tail / total, width=int(width), j_n=js[-1],
-                       predicted_s=s.predicted_s)
             _drift.MONITOR.observe(platform=platform, backend=s.backend,
                                    solver="rand",
                                    predicted_s=s.predicted_s, actual_s=dt,
@@ -1003,7 +1007,8 @@ class TuckerPlan:
                            mode_order=tuple(s.mode for s in self.schedule))
             if hop_methods is not None:
                 rcfg = replace(rcfg, methods=hop_methods)
-            res = plan(self.shape, self.dtype, rcfg).execute(
+            res = _spanned_plan(self.shape, self.dtype, rcfg,
+                                refine=True).execute(
                 xa, record=record, donate=False)
             for t in res.trace:
                 t.tail_err = tails[t.mode]
@@ -1289,13 +1294,22 @@ def plan(shape: Sequence[int], dtype, config: TuckerConfig, *,
     (:func:`_plan_adaptive`): the plan freezes a rank policy and sweep
     order; per-mode ranks resolve per input at execute time.
     """
+    return _spanned_plan(shape, dtype, config, selector=selector)
+
+
+def _spanned_plan(shape: Sequence[int], dtype, config: TuckerConfig, *,
+                  selector: Callable[..., str] | None = None,
+                  refine: bool = False) -> TuckerPlan:
+    """:func:`plan` inside a ``plan`` span; ``refine`` marks the re-plan at
+    the resolved ranks that a rank-adaptive execute makes."""
     if not _obs.enabled():
         return _plan(shape, dtype, config, selector=selector)
     with _obs.span("plan", shape=[int(s) for s in shape],
                    dtype=str(jnp.dtype(dtype)), impl=config.impl,
                    variant=config.variant,
                    mode_order=str(config.mode_order),
-                   adaptive=config.error_target is not None) as sp:
+                   adaptive=config.error_target is not None,
+                   refine=refine) as sp:
         p = _plan(shape, dtype, config, selector=selector)
         sp.set(backend=p.backend, n_steps=len(p.schedule),
                methods=list(p.methods), select_s=p.select_seconds,

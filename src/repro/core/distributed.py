@@ -39,6 +39,7 @@ module only executes frozen :class:`~repro.core.plan.ModeStep` schedules:
 from __future__ import annotations
 
 import time
+from contextlib import nullcontext
 from functools import lru_cache
 
 import jax
@@ -50,7 +51,7 @@ from jax.sharding import PartitionSpec as P
 from ..obs import drift as _drift
 from ..obs import trace as _obs
 from . import tensor_ops as T
-from .plan import ModeStep, solve_step
+from .plan import ModeStep, solve_step, step_scope
 from .solvers import DEFAULT_ALS_ITERS, als_solve
 from .sthosvd import ModeTrace, SthosvdResult, TuckerTensor
 
@@ -286,6 +287,22 @@ def solve_group_sharded(y: jax.Array, group, mesh: Mesh, axis: str, *,
     return factors, y
 
 
+def _solve_attrs(batch, platform: str) -> dict:
+    """Attributes of the ``solve`` span around one sharded step, or around
+    a mode-parallel group as a whole (its modes, their shared solver or
+    ``"mixed"``, and the sum of their predictions)."""
+    s = batch[0]
+    attrs = dict(solver=s.method, backend="sharded", platform=platform,
+                 n_shards=s.n_shards, group=s.group)
+    if len(batch) == 1:
+        return dict(attrs, mode=s.mode, rank=s.r_n, i_n=s.i_n, j_n=s.j_n,
+                    predicted_s=s.predicted_s)
+    methods = {b.method for b in batch}
+    return dict(attrs, modes=[b.mode for b in batch],
+                solver=methods.pop() if len(methods) == 1 else "mixed",
+                predicted_s=sum(b.predicted_s for b in batch))
+
+
 def run_sharded_schedule(x: jax.Array, steps, mesh: Mesh, axis: str, *,
                          als_iters: int = DEFAULT_ALS_ITERS,
                          block_until_ready: bool = True):
@@ -302,29 +319,24 @@ def run_sharded_schedule(x: jax.Array, steps, mesh: Mesh, axis: str, *,
     seconds: list[float] = []
     platform = jax.default_backend()
     for batch in iter_groups(steps):
-        wall0 = time.time()
-        t0 = time.perf_counter()
-        if len(batch) == 1:
-            u, y = solve_step_sharded(y, batch[0], mesh, axis,
-                                      als_iters=als_iters)
-            factors[batch[0].mode] = u
-        else:
-            fs, y = solve_group_sharded(y, batch, mesh, axis,
-                                        als_iters=als_iters)
-            factors.update(fs)
-        if block_until_ready:
-            jax.block_until_ready(y)
-        dt = time.perf_counter() - t0
+        with (_obs.span("solve", **_solve_attrs(batch, platform))
+              if block_until_ready else nullcontext()):
+            t0 = time.perf_counter()
+            if len(batch) == 1:
+                u, y = solve_step_sharded(y, batch[0], mesh, axis,
+                                          als_iters=als_iters)
+                factors[batch[0].mode] = u
+            else:
+                fs, y = solve_group_sharded(y, batch, mesh, axis,
+                                            als_iters=als_iters)
+                factors.update(fs)
+            if block_until_ready:
+                jax.block_until_ready(y)
+            dt = time.perf_counter() - t0
         seconds.extend([dt / len(batch)] * len(batch))
         if block_until_ready:
             for s in batch:
                 # group wall-clock attributed evenly, matching ``seconds``
-                _obs.event("span", t=wall0, name="solve",
-                           dur_s=dt / len(batch), mode=s.mode,
-                           solver=s.method, backend="sharded",
-                           platform=platform, rank=s.r_n, i_n=s.i_n,
-                           j_n=s.j_n, n_shards=s.n_shards,
-                           group=s.group, predicted_s=s.predicted_s)
                 _drift.MONITOR.observe(platform=platform, backend="sharded",
                                        solver=s.method,
                                        predicted_s=s.predicted_s,
@@ -340,7 +352,9 @@ def sweep_sharded(x, steps, *, mesh: Mesh, axis: str, als_iters: int):
     y = x
     factors: dict[int, jax.Array] = {}
     for step in steps:
-        u, y = solve_step_sharded(y, step, mesh, axis, als_iters=als_iters)
+        with step_scope(step):
+            u, y = solve_step_sharded(y, step, mesh, axis,
+                                      als_iters=als_iters)
         factors[step.mode] = u
     return y, [factors[m] for m in range(x.ndim)]
 
@@ -356,12 +370,14 @@ def sweep_mode_parallel(x, steps, *, mesh: Mesh, axis: str, als_iters: int):
     factors: dict[int, jax.Array] = {}
     for batch in iter_groups(steps):
         if len(batch) == 1:
-            u, y = solve_step_sharded(y, batch[0], mesh, axis,
-                                      als_iters=als_iters)
+            with step_scope(batch[0]):
+                u, y = solve_step_sharded(y, batch[0], mesh, axis,
+                                          als_iters=als_iters)
             factors[batch[0].mode] = u
         else:
-            fs, y = solve_group_sharded(y, batch, mesh, axis,
-                                        als_iters=als_iters)
+            with jax.named_scope(f"group{batch[0].group}"):
+                fs, y = solve_group_sharded(y, batch, mesh, axis,
+                                            als_iters=als_iters)
             factors.update(fs)
     return y, [factors[m] for m in range(x.ndim)]
 

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import math
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Callable, Sequence
 
@@ -625,17 +626,25 @@ def run_schedule(x: jax.Array, steps: Sequence[ModeStep], *,
     seconds: list[float] = []
     platform = jax.default_backend()
     for step in steps:
-        wall0 = time.time()
-        t0 = time.perf_counter()
-        _chaos.fire("solve", mode=step.mode, method=step.method)
-        res = solve_step(y if sequential else x, step,
-                         als_iters=als_iters, oversample=oversample,
-                         power_iters=power_iters, impl=impl)
-        if _chaos.active() and _chaos.poison("solve_out", mode=step.mode):
-            res = res._replace(u=res.u * float("nan"))
-        if block_until_ready:
-            jax.block_until_ready(res.y_new)
+        # the eager per-step path is the only place a mode solve has real
+        # wall-clock: a blocking run spans each solve and feeds
+        # predicted-vs-actual drift
+        with (_obs.span("solve", mode=step.mode, solver=step.method,
+                        backend=impl or step.backend, platform=platform,
+                        rank=step.r_n, i_n=step.i_n, j_n=step.j_n,
+                        predicted_s=step.predicted_s)
+              if block_until_ready else nullcontext()):
+            t0 = time.perf_counter()
+            _chaos.fire("solve", mode=step.mode, method=step.method)
+            res = solve_step(y if sequential else x, step,
+                             als_iters=als_iters, oversample=oversample,
+                             power_iters=power_iters, impl=impl)
+            if _chaos.active() and _chaos.poison("solve_out", mode=step.mode):
+                res = res._replace(u=res.u * float("nan"))
+            if block_until_ready:
+                jax.block_until_ready(res.y_new)
             dt = time.perf_counter() - t0
+        if block_until_ready:
             # a breakdown that slipped past the in-solver guards (e.g. a
             # non-finite Gram) shows up here as NaN factors — surface it
             # as a classified error naming the step, not as silent poison
@@ -643,21 +652,11 @@ def run_schedule(x: jax.Array, steps: Sequence[ModeStep], *,
                 raise NumericalError(
                     f"{step.method} solve on mode {step.mode} produced a "
                     "non-finite factor (numerical breakdown)")
-            # the eager per-step path is the only place a mode solve has
-            # real wall-clock: span it retroactively (no enter/exit to
-            # leak on solver errors) and feed predicted-vs-actual drift
-            _obs.event("span", t=wall0, name="solve", dur_s=dt,
-                       mode=step.mode, solver=step.method,
-                       backend=impl or step.backend, platform=platform,
-                       rank=step.r_n, i_n=step.i_n, j_n=step.j_n,
-                       predicted_s=step.predicted_s)
             _drift.MONITOR.observe(platform=platform,
                                    backend=impl or step.backend,
                                    solver=step.method,
                                    predicted_s=step.predicted_s,
                                    actual_s=dt, source="execute")
-        else:
-            dt = time.perf_counter() - t0
         seconds.append(dt)
         factors[step.mode] = res.u
         if sequential:
@@ -669,6 +668,12 @@ def run_schedule(x: jax.Array, steps: Sequence[ModeStep], *,
 # Whole-sweep pure functions (compiled as ONE program by api.TuckerPlan)
 # ---------------------------------------------------------------------------
 
+def step_scope(step: ModeStep):
+    """The named scope ``mode{m}.{method}`` a compiled sweep traces one
+    schedule step under, so the step's ops carry it in a profile."""
+    return jax.named_scope(f"mode{step.mode}.{step.method}")
+
+
 def sweep_sthosvd(x, steps: Sequence[ModeStep], *, als_iters: int,
                   oversample: int = DEFAULT_OVERSAMPLE,
                   power_iters: int = DEFAULT_POWER_ITERS,
@@ -676,8 +681,10 @@ def sweep_sthosvd(x, steps: Sequence[ModeStep], *, als_iters: int,
     y = x
     factors: dict[int, jax.Array] = {}
     for step in steps:
-        res = solve_step(y, step, als_iters=als_iters, oversample=oversample,
-                         power_iters=power_iters, impl=impl)
+        with step_scope(step):
+            res = solve_step(y, step, als_iters=als_iters,
+                             oversample=oversample, power_iters=power_iters,
+                             impl=impl)
         factors[step.mode] = res.u
         y = res.y_new
     return y, [factors[m] for m in range(x.ndim)]
@@ -687,9 +694,12 @@ def sweep_thosvd(x, steps: Sequence[ModeStep], *, als_iters: int,
                  oversample: int = DEFAULT_OVERSAMPLE,
                  power_iters: int = DEFAULT_POWER_ITERS,
                  impl: str | None = None):
-    factors = [solve_step(x, step, als_iters=als_iters, oversample=oversample,
-                          power_iters=power_iters, impl=impl).u
-               for step in steps]
+    factors = []
+    for step in steps:
+        with step_scope(step):
+            factors.append(solve_step(
+                x, step, als_iters=als_iters, oversample=oversample,
+                power_iters=power_iters, impl=impl).u)
     core = x
     for mode, u in enumerate(factors):
         core = T.ttm(core, u.T, mode)
@@ -707,14 +717,15 @@ def sweep_hooi(x, steps: Sequence[ModeStep], *, als_iters: int, n_init: int,
                                oversample=oversample, power_iters=power_iters,
                                impl=impl)
     for step in steps[n_init:]:
-        y = x
-        for m, u in enumerate(factors):
-            if m != step.mode:
-                y = T.ttm(y, u.T, m)
-        factors[step.mode] = solve_step(y, step, als_iters=als_iters,
-                                        oversample=oversample,
-                                        power_iters=power_iters,
-                                        impl=impl).u
+        with step_scope(step):
+            y = x
+            for m, u in enumerate(factors):
+                if m != step.mode:
+                    y = T.ttm(y, u.T, m)
+            factors[step.mode] = solve_step(y, step, als_iters=als_iters,
+                                            oversample=oversample,
+                                            power_iters=power_iters,
+                                            impl=impl).u
     core = x
     for mode, u in enumerate(factors):
         core = T.ttm(core, u.T, mode)

@@ -84,7 +84,6 @@ from ``TuckerConfig``.
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass
 from itertools import combinations, product
 from typing import Sequence
@@ -296,94 +295,95 @@ def optimize_schedule(
     message names the cheapest-memory step (or group) that still exceeds it
     at the deepest reachable state (the *binding* step).
     """
-    wall0, t0 = time.time(), time.perf_counter()
     shape = tuple(int(s) for s in shape)
-    ranks = tuple(int(r) for r in ranks)
-    n = len(shape)
-    cm = cost_model if cost_model is not None else DEFAULT_COST_MODEL
-    full = (1 << n) - 1
-    max_group = max(1, min(int(max_group), n))
-    search = tuple(search_methods)
-    if rank_grid is not None:
-        rank_grid = tuple(tuple(int(r) for r in g) for g in rank_grid)
-        if len(rank_grid) != n or any(not g for g in rank_grid):
-            raise ValueError(f"rank_grid needs a non-empty candidate tuple "
-                             f"per mode ({n} modes), got {rank_grid}")
-        if max_group > 1:
-            raise ValueError("the rank axis (rank_grid) applies to "
-                             "sequential schedules only; groups are "
-                             "rank-fixed — use max_group=1")
+    with _obs.span("plan.dp_search", shape=list(shape)) as sp:
+        ranks = tuple(int(r) for r in ranks)
+        n = len(shape)
+        cm = cost_model if cost_model is not None else DEFAULT_COST_MODEL
+        full = (1 << n) - 1
+        max_group = max(1, min(int(max_group), n))
+        search = tuple(search_methods)
+        if rank_grid is not None:
+            rank_grid = tuple(tuple(int(r) for r in g) for g in rank_grid)
+            if len(rank_grid) != n or any(not g for g in rank_grid):
+                raise ValueError(f"rank_grid needs a non-empty candidate "
+                                 f"tuple per mode ({n} modes), got "
+                                 f"{rank_grid}")
+            if max_group > 1:
+                raise ValueError("the rank axis (rank_grid) applies to "
+                                 "sequential schedules only; groups are "
+                                 "rank-fixed — use max_group=1")
 
-    # best[mask] = (cost, flops, prev_mask, group, assign, rks, cur); see
-    # the module docstring for the full state encoding.  Transitions only
-    # ever set bits, so ascending-mask iteration is a valid topological
-    # order.  cost is the latency objective, flops the lexicographic
-    # tie-break (see _relax); cur carries the chosen-rank dims forward.
-    best: dict[int, tuple[float, float, int, tuple, tuple, tuple, tuple]] = {
-        0: (0.0, 0.0, -1, (), (), (), shape)}
-    for mask in range(full):
-        state = best.get(mask)
-        if state is None:
-            continue
-        cur = list(state[6])
-        rem = [m for m in range(n) if not mask >> m & 1]
-        for m in rem:   # sequential edges, exactly the max_group=1 DP
-            for meth, peak, i_n, r_n, j_n in _priced_candidates(
-                    shape, ranks, methods, itemsize, n_shards, cur, m,
-                    search, rank_grid):
-                if memory_cap_bytes is not None and peak > memory_cap_bytes:
-                    continue
-                c = step_cost(cm, meth, i_n, r_n, j_n, als_iters)
-                nxt_cur = list(cur)
-                nxt_cur[m] = r_n
-                _relax(best, mask | (1 << m), state[0] + c, state[1] + c,
-                       mask, (m,), (meth,), (r_n,), nxt_cur)
-        for size in range(2, min(max_group, len(rem)) + 1):
-            for g in combinations(rem, size):
-                nxt = mask
-                for m in g:
-                    nxt |= 1 << m
-                for assign, lat, fl, peak in _price_group(
-                        shape, ranks, methods, als_iters, itemsize,
-                        n_shards, cur, g, cm):
+        # best[mask] = (cost, flops, prev_mask, group, assign, rks, cur); see
+        # the module docstring for the full state encoding.  Transitions only
+        # ever set bits, so ascending-mask iteration is a valid topological
+        # order.  cost is the latency objective, flops the lexicographic
+        # tie-break (see _relax); cur carries the chosen-rank dims forward.
+        best: dict[int, tuple[float, float, int, tuple, tuple, tuple,
+                              tuple]] = {
+            0: (0.0, 0.0, -1, (), (), (), shape)}
+        for mask in range(full):
+            state = best.get(mask)
+            if state is None:
+                continue
+            cur = list(state[6])
+            rem = [m for m in range(n) if not mask >> m & 1]
+            for m in rem:   # sequential edges, exactly the max_group=1 DP
+                for meth, peak, i_n, r_n, j_n in _priced_candidates(
+                        shape, ranks, methods, itemsize, n_shards, cur, m,
+                        search, rank_grid):
                     if memory_cap_bytes is not None \
                             and peak > memory_cap_bytes:
                         continue
+                    c = step_cost(cm, meth, i_n, r_n, j_n, als_iters)
                     nxt_cur = list(cur)
+                    nxt_cur[m] = r_n
+                    _relax(best, mask | (1 << m), state[0] + c, state[1] + c,
+                           mask, (m,), (meth,), (r_n,), nxt_cur)
+            for size in range(2, min(max_group, len(rem)) + 1):
+                for g in combinations(rem, size):
+                    nxt = mask
                     for m in g:
-                        nxt_cur[m] = ranks[m]
-                    _relax(best, nxt, state[0] + lat, state[1] + fl,
-                           mask, g, assign, tuple(ranks[m] for m in g),
-                           nxt_cur)
+                        nxt |= 1 << m
+                    for assign, lat, fl, peak in _price_group(
+                            shape, ranks, methods, als_iters, itemsize,
+                            n_shards, cur, g, cm):
+                        if memory_cap_bytes is not None \
+                                and peak > memory_cap_bytes:
+                            continue
+                        nxt_cur = list(cur)
+                        for m in g:
+                            nxt_cur[m] = ranks[m]
+                        _relax(best, nxt, state[0] + lat, state[1] + fl,
+                               mask, g, assign, tuple(ranks[m] for m in g),
+                               nxt_cur)
 
-    if full not in best:
-        raise MemoryCapError(_infeasible_message(
-            shape, ranks, methods, als_iters, itemsize, n_shards,
-            memory_cap_bytes, best, max_group=max_group, cost_model=cm,
-            search=search, rank_grid=rank_grid))
+        if full not in best:
+            raise MemoryCapError(_infeasible_message(
+                shape, ranks, methods, als_iters, itemsize, n_shards,
+                memory_cap_bytes, best, max_group=max_group, cost_model=cm,
+                search=search, rank_grid=rank_grid))
 
-    groups: list[tuple[int, ...]] = []
-    meths: list[tuple[str, ...]] = []
-    rkss: list[tuple[int, ...]] = []
-    mask = full
-    while mask:
-        _, _, prev, g, assign, rks, _cur = best[mask]
-        groups.append(g)
-        meths.append(assign)
-        rkss.append(rks)
-        mask = prev
-    groups.reverse()
-    meths.reverse()
-    rkss.reverse()
-    result = ScheduleSearch(
-        order=tuple(m for g in groups for m in g),
-        methods=tuple(q for a in meths for q in a),
-        total_cost=best[full][0], calibrated=cm.calibrated,
-        n_states=len(best), groups=tuple(groups),
-        ranks=tuple(r for rks in rkss for r in rks))
-    _obs.event("span", t=wall0, name="plan.dp_search",
-               dur_s=time.perf_counter() - t0, shape=list(shape),
-               n_states=result.n_states, order=list(result.order),
+        groups: list[tuple[int, ...]] = []
+        meths: list[tuple[str, ...]] = []
+        rkss: list[tuple[int, ...]] = []
+        mask = full
+        while mask:
+            _, _, prev, g, assign, rks, _cur = best[mask]
+            groups.append(g)
+            meths.append(assign)
+            rkss.append(rks)
+            mask = prev
+        groups.reverse()
+        meths.reverse()
+        rkss.reverse()
+        result = ScheduleSearch(
+            order=tuple(m for g in groups for m in g),
+            methods=tuple(q for a in meths for q in a),
+            total_cost=best[full][0], calibrated=cm.calibrated,
+            n_states=len(best), groups=tuple(groups),
+            ranks=tuple(r for rks in rkss for r in rks))
+        sp.set(n_states=result.n_states, order=list(result.order),
                methods=list(result.methods), max_group=max_group,
                calibrated=result.calibrated, total_cost=result.total_cost)
     return result
@@ -410,80 +410,80 @@ def optimize_grouping(
     sequential step).  Solver choice per member follows the same rules as
     :func:`optimize_schedule`.  ``max_group=None`` allows groups up to the
     full tensor order."""
-    wall0, t0 = time.time(), time.perf_counter()
     shape = tuple(int(s) for s in shape)
-    ranks = tuple(int(r) for r in ranks)
-    order = tuple(int(m) for m in order)
-    n = len(order)
-    cm = cost_model if cost_model is not None else DEFAULT_COST_MODEL
-    max_group = n if max_group is None else max(1, min(int(max_group), n))
+    with _obs.span("plan.dp_grouping", shape=list(shape)) as sp:
+        ranks = tuple(int(r) for r in ranks)
+        order = tuple(int(m) for m in order)
+        n = len(order)
+        cm = cost_model if cost_model is not None else DEFAULT_COST_MODEL
+        max_group = n if max_group is None else max(1, min(int(max_group), n))
 
-    dp: dict[int, tuple[float, float, int, tuple, tuple, tuple, tuple]] = {
-        0: (0.0, 0.0, -1, (), (), (), shape)}
-    for k in range(n):
-        state = dp.get(k)
-        if state is None:
-            continue
-        done = set(order[:k])
-        cur = [ranks[i] if i in done else shape[i]
-               for i in range(len(shape))]
-        m = order[k]
-        for meth, peak, i_n, r_n, j_n in _priced_candidates(
-                shape, ranks, methods, itemsize, n_shards, cur, m):
-            if memory_cap_bytes is not None and peak > memory_cap_bytes:
+        dp: dict[int, tuple[float, float, int, tuple, tuple, tuple, tuple]] = {
+            0: (0.0, 0.0, -1, (), (), (), shape)}
+        for k in range(n):
+            state = dp.get(k)
+            if state is None:
                 continue
-            c = step_cost(cm, meth, i_n, r_n, j_n, als_iters)
-            nxt_cur = list(cur)
-            nxt_cur[m] = r_n
-            _relax(dp, k + 1, state[0] + c, state[1] + c, k, (m,), (meth,),
-                   (r_n,), nxt_cur)
-        for size in range(2, min(max_group, n - k) + 1):
-            g = order[k:k + size]
-            for assign, lat, fl, peak in _price_group(
-                    shape, ranks, methods, als_iters, itemsize, n_shards,
-                    cur, g, cm):
+            done = set(order[:k])
+            cur = [ranks[i] if i in done else shape[i]
+                   for i in range(len(shape))]
+            m = order[k]
+            for meth, peak, i_n, r_n, j_n in _priced_candidates(
+                    shape, ranks, methods, itemsize, n_shards, cur, m):
                 if memory_cap_bytes is not None and peak > memory_cap_bytes:
                     continue
+                c = step_cost(cm, meth, i_n, r_n, j_n, als_iters)
                 nxt_cur = list(cur)
-                for gm in g:
-                    nxt_cur[gm] = ranks[gm]
-                _relax(dp, k + size, state[0] + lat, state[1] + fl,
-                       k, g, assign, tuple(ranks[gm] for gm in g), nxt_cur)
+                nxt_cur[m] = r_n
+                _relax(dp, k + 1, state[0] + c, state[1] + c, k, (m,), (meth,),
+                       (r_n,), nxt_cur)
+            for size in range(2, min(max_group, n - k) + 1):
+                g = order[k:k + size]
+                for assign, lat, fl, peak in _price_group(
+                        shape, ranks, methods, als_iters, itemsize, n_shards,
+                        cur, g, cm):
+                    if memory_cap_bytes is not None \
+                            and peak > memory_cap_bytes:
+                        continue
+                    nxt_cur = list(cur)
+                    for gm in g:
+                        nxt_cur[gm] = ranks[gm]
+                    _relax(dp, k + size, state[0] + lat, state[1] + fl,
+                           k, g, assign, tuple(ranks[gm] for gm in g), nxt_cur)
 
-    if n not in dp:
-        deepest = max(dp)
-        done = set(order[:deepest])
-        cur = [ranks[i] if i in done else shape[i]
-               for i in range(len(shape))]
-        cands = [(order[deepest],)] + [
-            order[deepest:deepest + size]
-            for size in range(2, min(max_group, n - deepest) + 1)]
-        binding = _min_peak_binding(shape, ranks, methods, als_iters,
-                                    itemsize, n_shards, cur, cands, cm)
-        raise MemoryCapError(_format_binding(
-            shape, ranks, memory_cap_bytes, sorted(done), binding, n_shards))
+        if n not in dp:
+            deepest = max(dp)
+            done = set(order[:deepest])
+            cur = [ranks[i] if i in done else shape[i]
+                   for i in range(len(shape))]
+            cands = [(order[deepest],)] + [
+                order[deepest:deepest + size]
+                for size in range(2, min(max_group, n - deepest) + 1)]
+            binding = _min_peak_binding(shape, ranks, methods, als_iters,
+                                        itemsize, n_shards, cur, cands, cm)
+            raise MemoryCapError(_format_binding(
+                shape, ranks, memory_cap_bytes, sorted(done), binding,
+                n_shards))
 
-    groups: list[tuple[int, ...]] = []
-    meths: list[tuple[str, ...]] = []
-    rkss: list[tuple[int, ...]] = []
-    k = n
-    while k:
-        _, _, prev, g, assign, rks, _cur = dp[k]
-        groups.append(g)
-        meths.append(assign)
-        rkss.append(rks)
-        k = prev
-    groups.reverse()
-    meths.reverse()
-    rkss.reverse()
-    result = ScheduleSearch(
-        order=order, methods=tuple(q for a in meths for q in a),
-        total_cost=dp[n][0], calibrated=cm.calibrated,
-        n_states=len(dp), groups=tuple(groups),
-        ranks=tuple(r for rks in rkss for r in rks))
-    _obs.event("span", t=wall0, name="plan.dp_grouping",
-               dur_s=time.perf_counter() - t0, shape=list(shape),
-               order=list(order), groups=[list(g) for g in result.groups],
+        groups: list[tuple[int, ...]] = []
+        meths: list[tuple[str, ...]] = []
+        rkss: list[tuple[int, ...]] = []
+        k = n
+        while k:
+            _, _, prev, g, assign, rks, _cur = dp[k]
+            groups.append(g)
+            meths.append(assign)
+            rkss.append(rks)
+            k = prev
+        groups.reverse()
+        meths.reverse()
+        rkss.reverse()
+        result = ScheduleSearch(
+            order=order, methods=tuple(q for a in meths for q in a),
+            total_cost=dp[n][0], calibrated=cm.calibrated,
+            n_states=len(dp), groups=tuple(groups),
+            ranks=tuple(r for rks in rkss for r in rks))
+        sp.set(order=list(order), groups=[list(g) for g in result.groups],
                calibrated=result.calibrated, total_cost=result.total_cost)
     return result
 

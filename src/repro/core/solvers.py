@@ -45,15 +45,33 @@ class SolveResult(NamedTuple):
     y_new: jax.Array   # tensor with mode shrunk to R_n
 
 
+def _scoped(name: str, f):
+    """``f``, traced under the named scope ``name``."""
+    def g(*args, **kw):
+        with jax.named_scope(name):
+            return f(*args, **kw)
+    return g
+
+
+def _scoped_ops(impl: str):
+    """The backend's ``(ttm, gram, ttt)``, each traced under a named scope
+    of its own name, so a compiled solve's profile names its contractions
+    (the eigen, QR and inverse steps run under ``solve``)."""
+    ttm, gram, ttt = backend_ops(impl)
+    return _scoped("ttm", ttm), _scoped("gram", gram), _scoped("ttt", ttt)
+
+
 # ---------------------------------------------------------------------------
 # EIG solver
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("mode", "rank", "impl"))
 def eig_solve(y: jax.Array, mode: int, rank: int, *, impl: str = "matfree") -> SolveResult:
-    ttm, gram, _ = backend_ops(impl)
+    ttm, gram, _ = _scoped_ops(impl)
     s = gram(y, mode)                                   # (I_n, I_n), fp32+ accum
-    _, vecs = jnp.linalg.eigh(s.astype(jnp.promote_types(s.dtype, jnp.float32)))
+    with jax.named_scope("solve"):
+        _, vecs = jnp.linalg.eigh(
+            s.astype(jnp.promote_types(s.dtype, jnp.float32)))
     u = vecs[:, -rank:][:, ::-1].astype(y.dtype)        # leading R_n eigvecs
     y_new = ttm(y, u.T, mode)                           # core update
     return SolveResult(u, y_new)
@@ -72,7 +90,7 @@ def als_solve(y: jax.Array, mode: int, rank: int, *,
         # the loop must run at least once: the R-tensor carry is only
         # written inside the body (zero iterations would return a zero core)
         raise ValueError(f"als_solve needs num_iters >= 1, got {num_iters}")
-    ttm, gram, ttt = backend_ops(impl)
+    ttm, gram, ttt = _scoped_ops(impl)
     i_n = y.shape[mode]
     # sub-fp32 inputs (bf16/fp16) iterate in fp32 (the peak_bytes model in
     # plan.py assumes exactly this); fp32/fp64 keep their own precision
@@ -104,7 +122,8 @@ def als_solve(y: jax.Array, mode: int, rank: int, *,
     l, r_t = jax.lax.fori_loop(
         0, num_iters, body, (l0, jnp.zeros(r_shape, cdtype)))
     # orthonormalize:  L = Q̂ R̂,  U ← Q̂,  core ← TTM(R-tensor, R̂)
-    q, rhat = jnp.linalg.qr(l)
+    with jax.named_scope("solve"):
+        q, rhat = jnp.linalg.qr(l)
     y_new = ttm(r_t, rhat, mode).astype(y.dtype)
     return SolveResult(q.astype(y.dtype), y_new)
 
@@ -126,18 +145,19 @@ def _spd_inverse(a: jax.Array) -> jax.Array:
     the whole sweep.  Because selection is by ``jnp.where`` on the FIRST
     finite factorization, well-posed solves keep their historical bitwise
     results."""
-    eye = jnp.eye(a.shape[0], dtype=a.dtype)
-    scale = jnp.trace(a)
-    inv = jnp.full_like(a, jnp.nan)
-    for i, jitter in enumerate(_SPD_JITTERS):
-        reg = jitter * scale
-        if i == len(_SPD_JITTERS) - 1:
-            reg = reg + jnp.asarray(1e-6, a.dtype)   # absolute floor
-        c = jax.scipy.linalg.cho_factor(a + reg * eye)
-        cand = jax.scipy.linalg.cho_solve(c, eye)
-        ok = jnp.all(jnp.isfinite(inv))
-        inv = jnp.where(ok, inv, cand)
-    return inv
+    with jax.named_scope("solve"):
+        eye = jnp.eye(a.shape[0], dtype=a.dtype)
+        scale = jnp.trace(a)
+        inv = jnp.full_like(a, jnp.nan)
+        for i, jitter in enumerate(_SPD_JITTERS):
+            reg = jitter * scale
+            if i == len(_SPD_JITTERS) - 1:
+                reg = reg + jnp.asarray(1e-6, a.dtype)   # absolute floor
+            c = jax.scipy.linalg.cho_factor(a + reg * eye)
+            cand = jax.scipy.linalg.cho_solve(c, eye)
+            ok = jnp.all(jnp.isfinite(inv))
+            inv = jnp.where(ok, inv, cand)
+        return inv
 
 
 # ---------------------------------------------------------------------------
@@ -159,7 +179,8 @@ def svd_solve(y: jax.Array, mode: int, rank: int, *, impl: str = "matfree") -> S
     get_backend(impl)  # reject unknown backends; ops themselves unused
     y2 = T.unfold(y, mode)
     cdtype = jnp.promote_types(y.dtype, jnp.float32)
-    u, s, vt = jnp.linalg.svd(y2.astype(cdtype), full_matrices=False)
+    with jax.named_scope("solve"):
+        u, s, vt = jnp.linalg.svd(y2.astype(cdtype), full_matrices=False)
     u = u[:, :rank]
     core2 = s[:rank, None] * vt[:rank]                  # Σ V^T
     out_shape = y.shape[:mode] + (rank,) + y.shape[mode + 1:]
@@ -202,21 +223,34 @@ def rand_sketch(y: jax.Array, mode: int, width: int, *,
     really be used, which is what makes the per-mode HOSVD error budget
     check in rank-adaptive execution a guarantee rather than an estimate.
     """
-    ttm, gram, ttt = backend_ops(impl)
+    with jax.named_scope(f"mode{mode}.rand"):
+        return _sketch(y, mode, width, power_iters=power_iters, seed=seed,
+                       impl=impl)
+
+
+def _sketch(y, mode: int, width: int, *, power_iters: int, seed: int,
+            impl: str):
+    """The body of :func:`rand_sketch`, traced inline by :func:`rand_solve`
+    (whose sweep step already carries the mode's scope)."""
+    ttm, gram, ttt = _scoped_ops(impl)
     cdtype = jnp.promote_types(y.dtype, jnp.float32)
     yc = y.astype(cdtype)
     energy = jnp.sum(jnp.square(yc))
     w_shape = y.shape[:mode] + (width,) + y.shape[mode + 1:]
     w = jax.random.normal(jax.random.PRNGKey(seed), w_shape, dtype=cdtype)
     ym = ttt(yc, w, mode)                                # (I_n, ℓ) range sample
-    q, _ = jnp.linalg.qr(ym)
+    with jax.named_scope("solve"):
+        q, _ = jnp.linalg.qr(ym)
     for _ in range(power_iters):
         b = ttm(yc, q.T, mode)                           # project: mode → ℓ
         ym = ttt(yc, b, mode)                            # expand: Y_(n)Y_(n)ᵀ Q
-        q, _ = jnp.linalg.qr(ym)
+        with jax.named_scope("solve"):
+            q, _ = jnp.linalg.qr(ym)
     b = ttm(yc, q.T, mode)
     gb = gram(b, mode)                                   # (ℓ, ℓ) sketched Gram
-    evals, vecs = jnp.linalg.eigh(gb.astype(jnp.promote_types(gb.dtype, jnp.float32)))
+    with jax.named_scope("solve"):
+        evals, vecs = jnp.linalg.eigh(
+            gb.astype(jnp.promote_types(gb.dtype, jnp.float32)))
     return q, b, evals, vecs, energy
 
 
@@ -231,10 +265,10 @@ def rand_solve(y: jax.Array, mode: int, rank: int, *,
     existing eig machinery refines within the sketch — the Rayleigh–Ritz
     rotation *is* an eig step on the ℓ×ℓ sketched Gram, truncated to R_n."""
     width = min(y.shape[mode], rank + oversample)
-    q, b, _, vecs, _ = rand_sketch(
+    q, b, _, vecs, _ = _sketch(
         y, mode, width, power_iters=power_iters, seed=seed, impl=impl)
     v = vecs[:, -rank:][:, ::-1].astype(q.dtype)         # leading R_n Ritz vecs
-    ttm, _, _ = backend_ops(impl)
+    ttm, _, _ = _scoped_ops(impl)
     u = jnp.dot(q, v, precision=jax.lax.Precision.HIGHEST)
     y_new = ttm(b, v.T, mode)                            # rotate core: ℓ → R_n
     return SolveResult(u.astype(y.dtype), y_new.astype(y.dtype))

@@ -70,6 +70,7 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
     return pl.pallas_call(
         functools.partial(_matmul_kernel, k_total=k, bk=bk),
+        name="matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -66,6 +66,7 @@ def ttm_interior(u: jax.Array, x3: jax.Array, *, br: int = 128, bb: int = 128,
     grid = (a // ba, pl.cdiv(r, br), pl.cdiv(b, bb), pl.cdiv(i, bi))
     return pl.pallas_call(
         functools.partial(_ttm_kernel, ba=ba, i_total=i, bi=bi),
+        name="ttm_interior",
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, bi), lambda aa, rr, bbb, ii: (rr, ii)),

@@ -92,6 +92,7 @@ def ttt_nt(x: jax.Array, y: jax.Array, *, bi: int, br: int, bb: int,
         os = pl.BlockSpec((bi, br), lambda ii, rr, aa, kb: (ii, rr))
     return pl.pallas_call(
         functools.partial(_nt_kernel, ba=ba, b_total=b, bb=bb),
+        name="ttt_nt",
         grid=grid,
         in_specs=[xs, ys],
         out_specs=os,
@@ -125,6 +126,7 @@ def ttt_tn(x: jax.Array, y: jax.Array, *, bi: int, br: int, ba: int,
     assert a == a2, (x.shape, y.shape)
     return pl.pallas_call(
         functools.partial(_tn_kernel, a_total=a, ba=ba),
+        name="ttt_tn",
         grid=(pl.cdiv(i, bi), pl.cdiv(r, br), pl.cdiv(a, ba)),
         in_specs=[pl.BlockSpec((ba, bi), lambda ii, rr, ka: (ka, ii)),
                   pl.BlockSpec((ba, br), lambda ii, rr, ka: (ka, rr))],

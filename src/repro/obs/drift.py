@@ -87,7 +87,7 @@ class DriftCell:
             # significant; cap so reports stay finite
             return 0.0 if self.mean_log == 0.0 else \
                 math.copysign(99.0, self.mean_log)
-        # near-identical observations (e.g. one wave's amortized shares)
+        # near-identical observations (e.g. repeated identical steps)
         # make se vanishingly small; clamp so reports stay readable
         return max(-99.0, min(99.0, self.mean_log / se))
 
@@ -125,8 +125,8 @@ class DriftMonitor:
                        source: str = "execute") -> int:
         """Feed a sequence of :class:`ModeTrace`-likes (needs ``method``,
         ``predicted_s``, ``seconds``).  Fused sweeps record ``seconds=0``
-        per step and are skipped here — the serve layer attributes wave
-        wall-clock instead.  Returns the number of pairs recorded."""
+        per step and are skipped here: a fused sweep has no per-step
+        wall-clock.  Returns the number of pairs recorded."""
         n = 0
         for t in traces:
             pred = getattr(t, "predicted_s", 0.0) or 0.0

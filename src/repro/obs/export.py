@@ -53,7 +53,9 @@ def to_chrome(events: Iterable[dict]) -> dict:
             })
         elif kind == "wave" and "wall_s" in e:
             # TraceWriter logs waves at completion; rewind the start so
-            # the slice lands where the work actually ran.
+            # the slice lands where the work actually ran.  Its files hold
+            # no spans, so this slice is all they show of a wave; a bus
+            # capture also carries the wave's serve.wave* spans.
             wall = max(float(e["wall_s"]), 0.0)
             out.append({
                 "name": f"wave {e.get('bucket', '')}".strip(),

@@ -21,8 +21,14 @@ Design constraints, in priority order:
 2. **Plain dicts, stdlib only.**  An event is ``{"t": unix_seconds,
    "kind": str, ...fields}`` — the exact shape the serve TraceWriter has
    always written — plus, for spans, ``name`` / ``dur_s`` / ``span`` /
-   ``parent`` / ``tid`` / ``pid``.  No jax import, no device touch.
-3. **Context propagation.**  Span parentage rides a :mod:`contextvars`
+   ``parent`` / ``tid`` / ``pid``.  No jax import at module load, no
+   device touch.
+3. **One clock with the profiler.**  An enabled span also opens a
+   ``jax.profiler.TraceAnnotation`` of the same name for its region
+   (jax is imported on the first enabled span), so inside a
+   ``jax.profiler.trace`` every span lands on the ``/host:CPU`` plane
+   beside the device's ops, on the device trace's clock.
+4. **Context propagation.**  Span parentage rides a :mod:`contextvars`
    ContextVar, so nesting works across the serve worker thread and any
    executor the caller brings, without threading span objects through
    call signatures.
@@ -135,12 +141,30 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+#: ``jax.profiler.TraceAnnotation``, imported by the first enabled span
+#: (None until then, False where jax cannot be imported)
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """A profiler annotation named ``name``, or None without jax."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        try:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        except ImportError:
+            _TraceAnnotation = False
+    return _TraceAnnotation(name) if _TraceAnnotation else None
+
+
 class Span:
     """One timed region, emitted as a single ``kind="span"`` event at exit
     (so a crashed region simply leaves no event — the JSONL stays whole).
     ``set(**attrs)`` adds attributes any time before exit; an exception
-    escaping the region stamps ``error=repr(exc)``."""
-    __slots__ = ("name", "attrs", "id", "parent", "_t0", "_wall", "_tok")
+    escaping the region stamps ``error=repr(exc)``.  The region is also a
+    profiler annotation of the same name (enter and exit on one thread)."""
+    __slots__ = ("name", "attrs", "id", "parent", "_t0", "_wall", "_tok",
+                 "_ann")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -150,16 +174,22 @@ class Span:
         self._t0 = 0.0
         self._wall = 0.0
         self._tok = None
+        self._ann = None
 
     def __enter__(self) -> "Span":
         self.parent = _current.get()
         self._tok = _current.set(self.id)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         _current.reset(self._tok)
         if exc is not None:
             self.attrs["error"] = repr(exc)

@@ -86,6 +86,10 @@ from .buckets import BucketPolicy, pad_block, pad_waste, slice_valid, trim_resul
 from .metrics import BucketMetrics, LatencyWindow, TraceWriter
 
 BACKPRESSURE_MODES = ("reject", "block")
+#: the per-bucket counters :meth:`TuckerService.stats` also sums into
+#: ``"totals"`` (lifetime raw counts, never windowed)
+TOTALS = ("waves", "lanes", "lanes_filled", "true_elems", "slot_elems",
+          "completed", "padded", "failed")
 VALIDATE_MODES = ("finite", "none")
 
 #: errors that a retry budget never retries: the request itself is the
@@ -412,6 +416,17 @@ class TuckerService:
             raise ValueError("deadline_s must be > 0 (or None)")
         if retries < 0:
             raise ValueError("retries must be >= 0")
+        with self._lock:   # the request's id, before any span needs it
+            if rid is None:
+                rid = self._next_rid
+            self._next_rid = max(self._next_rid, rid) + 1
+        with _obs.span("serve.submit", rid=rid):
+            return self._admit(x, config, rid, deadline_s, retries, validate)
+
+    def _admit(self, x, config: TuckerConfig, rid: int,
+               deadline_s: float | None, retries: int,
+               validate: str) -> Ticket:
+        """The body of :meth:`submit`, inside its ``serve.submit`` span."""
         t_adm = time.perf_counter()
         if not hasattr(x, "shape"):
             x = jnp.asarray(x)
@@ -422,54 +437,58 @@ class TuckerService:
         # validate here: per-mode ranks resolve per input at execute time,
         # and the config's own __post_init__ already validated the target
         if validate == "finite":
-            check_finite(x, name="request input")
+            with _obs.span("serve.validate", rid=rid):
+                check_finite(x, name="request input")
         pinned = self._pinned(config)
         dtype = str(jnp.dtype(x.dtype))
         bshape = self._policy.bucket_shape(shape)
         key = (bshape, dtype, pinned)
         deadline = t_adm + deadline_s if deadline_s is not None else None
-        while True:
-            with self._lock:
-                if self._closed:
-                    raise ServiceClosed("service is closed to new submissions")
-                bs = self._buckets.get(key)
-                if bs is None:
-                    bs = self._buckets[key] = _BucketState(
-                        key, self._breaker_threshold, self._breaker_cooldown)
-                if self._max_queue is None or self._pending < self._max_queue:
-                    if rid is None:
-                        rid = self._next_rid
-                    self._next_rid = max(self._next_rid, rid) + 1
-                    job = _Job(rid, x, pinned, shape, key,
-                               deadline=deadline, retries=retries)
-                    bs.queue.append(job)
-                    bs.metrics.submitted += 1
-                    self._pending += 1
-                    self._counters["submitted"] += 1
-                    self._work.notify_all()
-                    break
-                if self._backpressure == "reject":
-                    bs.metrics.rejected += 1
-                    self._counters["rejected"] += 1
-                    self._emit("reject", rid=rid, shape=list(shape),
-                               bucket=list(bshape))
+        with _obs.span("serve.admit", rid=rid):
+            while True:
+                with self._lock:
+                    if self._closed:
+                        raise ServiceClosed(
+                            "service is closed to new submissions")
+                    bs = self._buckets.get(key)
+                    if bs is None:
+                        bs = self._buckets[key] = _BucketState(
+                            key, self._breaker_threshold,
+                            self._breaker_cooldown)
+                    if self._max_queue is None or \
+                            self._pending < self._max_queue:
+                        job = _Job(rid, x, pinned, shape, key,
+                                   deadline=deadline, retries=retries)
+                        bs.queue.append(job)
+                        bs.metrics.submitted += 1
+                        self._pending += 1
+                        self._counters["submitted"] += 1
+                        self._work.notify_all()
+                        break
+                    if self._backpressure == "reject":
+                        bs.metrics.rejected += 1
+                        self._counters["rejected"] += 1
+                        self._emit("reject", rid=rid, shape=list(shape),
+                                   bucket=list(bshape))
+                        raise RejectedError(
+                            f"admission queue full ({self._max_queue} "
+                            "pending); retry later or use "
+                            "backpressure='block'")
+                    if deadline is not None and \
+                            time.perf_counter() >= deadline:
+                        bs.metrics.rejected += 1
+                        self._counters["rejected"] += 1
+                        raise DeadlineError(
+                            f"request missed its {deadline_s}s deadline "
+                            "while blocked on admission (queue full)")
+                    if self._running:
+                        self._space.wait(timeout=0.1)
+                        continue
+                # block policy, no worker: free space by running a wave here
+                if not self._pump_once():
                     raise RejectedError(
-                        f"admission queue full ({self._max_queue} pending); "
-                        "retry later or use backpressure='block'")
-                if deadline is not None and time.perf_counter() >= deadline:
-                    bs.metrics.rejected += 1
-                    self._counters["rejected"] += 1
-                    raise DeadlineError(
-                        f"request missed its {deadline_s}s deadline while "
-                        "blocked on admission (queue full)")
-                if self._running:
-                    self._space.wait(timeout=0.1)
-                    continue
-            # block policy, no worker: free space by running a wave here
-            if not self._pump_once():
-                raise RejectedError(
-                    "queue full under backpressure='block' with no worker "
-                    "running and no runnable wave")
+                        "queue full under backpressure='block' with no "
+                        "worker running and no runnable wave")
         self._emit("submit", rid=job.rid, shape=list(shape),
                    bucket=list(bshape), padded=shape != bshape)
         return Ticket(rid=job.rid, shape=shape, bucket=bshape,
@@ -573,6 +592,14 @@ class TuckerService:
         bisection at the original lane count, everything else by an exact
         isolated re-run — and whatever still fails comes back as a
         *classified* error."""
+        rids = [j.rid for j in jobs]
+        with _obs.span("serve.wave", rids=rids) as wave_span:
+            return self._launch_wave(bs, jobs, inflight, rids, wave_span)
+
+    def _launch_wave(self, bs: _BucketState, jobs: list[_Job],
+                     inflight: int, rids: list[int], wave_span):
+        """The body of :meth:`_dispatch_wave`, inside its ``serve.wave``
+        span (``wave_span``)."""
         bshape, dtype, cfg = bs.key
         t_start = time.perf_counter()
         done: list[tuple[_Job, SthosvdResult | None, TuckerPlan | None,
@@ -602,75 +629,80 @@ class TuckerService:
                 self._res["isolated_waves"] += 1
             elif route == "probe":
                 self._res["probe_waves"] += 1
-        try:
-            if not live:
-                pass
-            elif record:
-                for j in live:
-                    done.append(self._run_recorded(j, bshape, dtype, cfg))
-            elif route == "isolated":
-                # breaker open: exact item-by-item execution at each
-                # request's true shape — no fused wave left to poison
-                for j in live:
-                    done.append(self._run_isolated(j, bs))
-            elif self._policy.pad_mode == "mask" and \
-                    any(j.shape != bshape for j in live):
-                # mask mode: mixed true shapes fuse into ONE vmapped wave at
-                # the bucket shape; zero slack is arithmetically inert and
-                # the factors' slack rows come back exactly zero, so each
-                # lane trims to its true shape afterwards
-                p = self._plan_cached(bshape, dtype, cfg)
-                _chaos.fire("wave", bucket=bshape, n=len(live))
-                fused_group = list(live)
-                stack = jnp.stack([self._job_block(j, bshape) for j in live])
-                stack, lanes = self._lane_fill(stack, len(live), p)
-                fused_lanes = lanes
-                results = p.execute_batch(stack, donate=True)[:len(live)]
-                for j, r in zip(live, results):
-                    r = trim_result(r, j.shape) if j.shape != bshape else r
-                    done.append((j, r, p, None))
-            else:
-                exact = [j for j in live if j.shape == bshape]
-                padded = [j for j in live if j.shape != bshape]
-                if exact:
+        with _obs.span("serve.wave.dispatch", rids=rids):
+            try:
+                if not live:
+                    pass
+                elif record:
+                    for j in live:
+                        done.append(self._run_recorded(j, bshape, dtype, cfg))
+                elif route == "isolated":
+                    # breaker open: exact item-by-item execution at each
+                    # request's true shape — no fused wave left to poison
+                    for j in live:
+                        done.append(self._run_isolated(j, bs))
+                elif self._policy.pad_mode == "mask" and \
+                        any(j.shape != bshape for j in live):
+                    # mask mode: mixed true shapes fuse into ONE vmapped
+                    # wave at the bucket shape; zero slack is arithmetically
+                    # inert and the factors' slack rows come back exactly
+                    # zero, so each lane trims to its true shape afterwards
                     p = self._plan_cached(bshape, dtype, cfg)
-                    _chaos.fire("wave", bucket=bshape, n=len(exact))
-                    if len(exact) == 1 and self._policy.lanes_for(1) == 1:
-                        # singleton: share the unbatched compiled sweep
-                        _chaos.fire("wave_job", rid=exact[0].rid)
-                        res = p.execute(jnp.asarray(exact[0].x))
-                        done.append((exact[0], res, p, None))
-                    else:
-                        fused_group = list(exact)
-                        stack = jnp.stack([self._job_block(j, bshape)
-                                           for j in exact])
-                        stack, lanes_e = self._lane_fill(stack, len(exact), p)
-                        fused_lanes = lanes_e
-                        lanes = lanes_e + len(padded)
-                        results = p.execute_batch(stack, donate=True)
-                        for j, r in zip(exact, results):
-                            done.append((j, r, p, None))
-                if padded:
-                    # the admission slot buffer: every padded member lands in
-                    # a bucket-shaped slot; exact mode then slices the valid
-                    # block back out (bitwise-lossless) and runs it through
-                    # the plan its TRUE shape resolves to — the identical
-                    # cached program a direct decompose() would run, which
-                    # is what makes padded results bitwise-equal to
-                    # unpadded execution
-                    base = self._plans.get((bshape, dtype, cfg))
-                    slots = jnp.stack([pad_block(jnp.asarray(j.x), bshape)
-                                       for j in padded])
-                    for i, j in enumerate(padded):
-                        _chaos.fire("wave_job", rid=j.rid)
-                        tp = self._plan_cached(j.shape, dtype, cfg, base=base)
-                        res = tp.execute(slice_valid(slots[i], j.shape),
-                                         donate=True)
-                        done.append((j, res, tp, None))
-        except Exception as e:  # noqa: BLE001 - recovered in finish(), not here
-            wave_exc = e
+                    _chaos.fire("wave", bucket=bshape, n=len(live))
+                    fused_group = list(live)
+                    stack = jnp.stack([self._job_block(j, bshape)
+                                       for j in live])
+                    stack, lanes = self._lane_fill(stack, len(live), p)
+                    fused_lanes = lanes
+                    results = p.execute_batch(stack, donate=True)[:len(live)]
+                    for j, r in zip(live, results):
+                        r = trim_result(r, j.shape) if j.shape != bshape else r
+                        done.append((j, r, p, None))
+                else:
+                    exact = [j for j in live if j.shape == bshape]
+                    padded = [j for j in live if j.shape != bshape]
+                    if exact:
+                        p = self._plan_cached(bshape, dtype, cfg)
+                        _chaos.fire("wave", bucket=bshape, n=len(exact))
+                        if len(exact) == 1 and self._policy.lanes_for(1) == 1:
+                            # singleton: share the unbatched compiled sweep
+                            _chaos.fire("wave_job", rid=exact[0].rid)
+                            res = p.execute(jnp.asarray(exact[0].x))
+                            done.append((exact[0], res, p, None))
+                        else:
+                            fused_group = list(exact)
+                            stack = jnp.stack([self._job_block(j, bshape)
+                                               for j in exact])
+                            stack, lanes_e = self._lane_fill(
+                                stack, len(exact), p)
+                            fused_lanes = lanes_e
+                            lanes = lanes_e + len(padded)
+                            results = p.execute_batch(stack, donate=True)
+                            for j, r in zip(exact, results):
+                                done.append((j, r, p, None))
+                    if padded:
+                        # the admission slot buffer: every padded member
+                        # lands in a bucket-shaped slot; exact mode then
+                        # slices the valid block back out (bitwise-lossless)
+                        # and runs it through the plan its TRUE shape
+                        # resolves to — the identical cached program a direct
+                        # decompose() would run, which is what makes padded
+                        # results bitwise-equal to unpadded execution
+                        base = self._plans.get((bshape, dtype, cfg))
+                        slots = jnp.stack([pad_block(jnp.asarray(j.x), bshape)
+                                           for j in padded])
+                        for i, j in enumerate(padded):
+                            _chaos.fire("wave_job", rid=j.rid)
+                            tp = self._plan_cached(j.shape, dtype, cfg,
+                                                   base=base)
+                            res = tp.execute(slice_valid(slots[i], j.shape),
+                                             donate=True)
+                            done.append((j, res, tp, None))
+            except Exception as e:  # noqa: BLE001 - recovered in finish()
+                wave_exc = e
+        wave_span.set(lanes=lanes, filled=len(live), route=route)
 
-        def finish():
+        def complete():
             # 1) collect what needs recovery: jobs the wave never produced a
             #    result for, async device failures, and poisoned fused lanes
             fused_ids = {id(j) for j in fused_group}
@@ -784,8 +816,9 @@ class TuckerService:
                         m.padded += j.shape != bshape
                         m.true_elems += math.prod(j.shape)
                         m.slot_elems += math.prod(bshape)
+                        queue_s = t_start - j.t_submit
                         m.latency.add(lat)
-                        m.queue_wait.add(t_start - j.t_submit)
+                        m.queue_wait.add(queue_s)
                         m.backends[p.backend] = m.backends.get(p.backend, 0) + 1
                         for meth in p.methods:
                             m.solvers[meth] = m.solvers.get(meth, 0) + 1
@@ -797,6 +830,7 @@ class TuckerService:
                         events.append(("done", {
                             "rid": j.rid, "bucket": list(bshape),
                             "latency_s": round(lat, 6),
+                            "queue_s": round(queue_s, 6),
                             "backend": p.backend,
                             "pad_waste": round(pad_waste(j.shape, bshape), 6)}))
                     self._pending -= 1
@@ -813,14 +847,10 @@ class TuckerService:
                 self._emit(kind, **fields)
             for kind, fields in events:
                 self._emit(kind, **fields)
-            if not record:
-                # recorded waves fed drift per step (source="execute")
-                # inside plan.execute already; here the only measurement
-                # is the wave wall-clock, so amortize it across the wave's
-                # completed jobs and attribute each job's share across its
-                # plan's steps proportionally to their predictions — the
-                # serve-traffic view of predicted-vs-actual calibration
-                self._observe_wave_drift(completed, t_done - t_start)
+
+        def finish():
+            with _obs.span("serve.wave.finish", rids=rids):
+                complete()
 
         return finish
 
@@ -887,25 +917,6 @@ class TuckerService:
             return (j, res, tp, None)
         except Exception as e:  # noqa: BLE001 - per-job failure isolation
             return (j, None, None, coerce_exception(e))
-
-    @staticmethod
-    def _observe_wave_drift(done, wall_s: float) -> None:
-        ok = [(j, p) for j, res, p, err in done
-              if err is None and p is not None]
-        if not ok or wall_s <= 0.0:
-            return
-        per_job = wall_s / len(ok)
-        platform = jax.default_backend()
-        for _, p in ok:
-            total_pred = p.total_predicted_s
-            if total_pred <= 0.0:
-                continue
-            for s in p.schedule:
-                _drift.MONITOR.observe(
-                    platform=platform, backend=s.backend, solver=s.method,
-                    predicted_s=s.predicted_s,
-                    actual_s=per_job * (s.predicted_s / total_pred),
-                    source="serve")
 
     def _lane_fill(self, stack, n: int, p: TuckerPlan):
         """Round the wave's batch up to the policy's lane count with
@@ -1148,13 +1159,15 @@ class TuckerService:
         ``backends`` keep the batch engine's historical meanings;
         ``resilience`` aggregates the failure-isolation machinery
         (deadlines, cancels, retries, bisections, quarantines, breaker
-        trips) and each bucket snapshot carries its breaker state."""
+        trips) and each bucket snapshot carries its breaker state.
+        ``totals`` sums the raw bucket counters named in :data:`TOTALS`
+        over every bucket, for the service's lifetime."""
         with self._lock:
             taken: set = set()
             buckets = {}
+            totals = dict.fromkeys(TOTALS, 0)
             backends: dict = {}
             solvers: dict = {}
-            true_elems = slot_elems = 0
             trips = reopens = open_count = 0
             for key, bs in self._buckets.items():
                 snap = bs.metrics.snapshot(queue_depth=len(bs.queue))
@@ -1167,8 +1180,8 @@ class TuckerService:
                     backends[k] = backends.get(k, 0) + v
                 for k, v in bs.metrics.solvers.items():
                     solvers[k] = solvers.get(k, 0) + v
-                true_elems += bs.metrics.true_elems
-                slot_elems += bs.metrics.slot_elems
+                for f in TOTALS:
+                    totals[f] += getattr(bs.metrics, f)
             elapsed = time.perf_counter() - self._t0
             return {
                 **self._counters,
@@ -1177,12 +1190,14 @@ class TuckerService:
                 "n_buckets": len(self._buckets),
                 "backends": backends,
                 "solvers": solvers,
-                "pad_waste": round(1.0 - true_elems / slot_elems, 6)
-                             if slot_elems else 0.0,
+                "pad_waste": round(1.0 - totals["true_elems"]
+                                   / totals["slot_elems"], 6)
+                             if totals["slot_elems"] else 0.0,
                 "throughput_rps": self._counters["requests"] / elapsed
                                   if elapsed > 0 else 0.0,
                 "latency": self._latency.snapshot_ms(),
                 "buckets": buckets,
+                "totals": totals,
                 "resilience": {
                     **self._res,
                     "breaker_trips": trips,

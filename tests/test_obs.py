@@ -388,6 +388,86 @@ class TestCoreSpans:
             assert e["rank"] == 4 and e["dur_s"] > 0.0
             assert e["platform"] == jax.default_backend()
 
+    def test_disabled_span_is_the_shared_null_span(self):
+        from repro.obs import trace as trace_mod
+        assert not obs.enabled()
+        assert obs.span("sketch", mode=0) is trace_mod._NULL_SPAN
+        with obs.capture():
+            assert obs.span("sketch") is not trace_mod._NULL_SPAN
+        assert obs.span("sketch") is trace_mod._NULL_SPAN
+
+    def test_importing_obs_and_enabling_it_loads_no_jax(self):
+        """The bus is stdlib only at import; jax arrives with the first
+        enabled span (its profiler annotation), not before."""
+        import subprocess
+        import sys
+        code = ("import sys; from repro import obs; "
+                "assert 'jax' not in sys.modules, 'jax at import'; "
+                "obs.enable(); obs.event('x'); "
+                "assert 'jax' not in sys.modules, 'jax on an event'; "
+                "sp = obs.span('s'); "
+                "assert 'jax' not in sys.modules, 'jax on a span object'")
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+
+    def test_every_span_is_live(self):
+        """No span is written after the fact: each carries its own id, so
+        the DP searches, solves and sketches can hold children."""
+        cfg = TuckerConfig(ranks=RANKS, methods="eig", mode_order="opt")
+        with obs.capture() as buf:
+            p = make_plan(SHAPE, jnp.float32, cfg)
+            p.execute(_x(), record=True)
+        spans = list(obs.iter_spans(buf.events()))
+        names = {e["name"] for e in spans}
+        assert {"plan", "plan.dp_search", "execute", "solve"} <= names
+        assert all(isinstance(e.get("span"), int) for e in spans)
+        by_id = {e["span"]: e for e in spans}
+        (search,) = [e for e in spans if e["name"] == "plan.dp_search"]
+        assert by_id[search["parent"]]["name"] == "plan"
+        assert search["order"] and search["n_states"] > 0
+
+    def test_adaptive_execute_nests_readbacks_and_marks_the_refine_plan(
+            self):
+        cfg = TuckerConfig(error_target=0.5)
+        x = _x()
+        make_plan(SHAPE, jnp.float32, cfg).execute(x)
+        with obs.capture() as buf:
+            make_plan(SHAPE, jnp.float32, cfg).execute(x)
+        spans = list(obs.iter_spans(buf.events()))
+        by_id = {e["span"]: e for e in spans}
+        sketches = [e for e in spans if e["name"] == "sketch"]
+        readbacks = [e for e in spans if e["name"] == "sketch.readback"]
+        # one read per width tried, and one wait for each shrunk tensor
+        assert len(readbacks) == sum(e["widths"] + 1 for e in sketches)
+        for e in readbacks:
+            parent = by_id[e["parent"]]
+            assert parent["name"] == "sketch"
+            assert parent["mode"] == e["mode"]
+        for e in sketches:
+            kids = [r["dur_s"] for r in readbacks if r["parent"] == e["span"]]
+            assert sum(kids) <= e["dur_s"]
+        plans = [e for e in spans if e["name"] == "plan"]
+        front = [e for e in plans if e["parent"] is None]
+        refine = [e for e in plans if e["refine"]]
+        assert len(front) == 1 and not front[0]["refine"]
+        assert len(refine) == 1
+        assert by_id[refine[0]["parent"]]["name"] == "execute"
+
+    def test_fixed_rank_sweep_hlo_carries_step_scopes(self):
+        """The compiled sweep names each schedule step and the backend call
+        inside it, so a device profile's op name stack says which mode's
+        Gram, TTM or solve an op belongs to."""
+        from repro.core.api import _make_sweep
+        cfg = TuckerConfig(ranks=RANKS, methods="eig")
+        p = make_plan(SHAPE, jnp.float32, cfg)
+        hlo = _make_sweep(p, batched=False).lower(
+            jax.ShapeDtypeStruct(SHAPE, jnp.float32)).compile().as_text()
+        for m in range(3):
+            assert f"mode{m}.eig/" in hlo
+        assert "mode0.eig/jit(eig_solve)/gram/" in hlo
+        assert "/ttm/" in hlo and "/solve/" in hlo
+
     def test_adaptive_execute_emits_sketch_spans(self):
         cfg = TuckerConfig(error_target=0.5)
         p = make_plan(SHAPE, jnp.float32, cfg)
@@ -474,6 +554,68 @@ class TestServiceObservability:
         finally:
             svc.stop()
 
+    def test_served_run_spans_share_request_ids(self):
+        """Each request's admission and each wave's dispatch and finish are
+        spans carrying the request ids, and every completion stamps its own
+        queue wait."""
+        cfg = TuckerConfig(ranks=RANKS, methods="eig")
+        policy = BucketPolicy(grid=8, wave_slots=2)
+        with obs.capture() as buf:
+            with TuckerService(policy=policy) as svc:
+                tickets = [svc.submit(_x(seed=k), cfg) for k in range(3)]
+                svc.drain()
+        events = buf.events()
+        spans = list(obs.iter_spans(events))
+        by_id = {e["span"]: e for e in spans}
+
+        def named(name):
+            return [e for e in spans if e["name"] == name]
+
+        rids = [t.rid for t in tickets]
+        submits = named("serve.submit")
+        assert sorted(e["rid"] for e in submits) == rids
+        for name in ("serve.validate", "serve.admit"):
+            kids = named(name)
+            assert len(kids) == 3
+            for e in kids:
+                parent = by_id[e["parent"]]
+                assert parent["name"] == "serve.submit"
+                assert parent["rid"] == e["rid"]
+        waves = named("serve.wave")
+        assert sorted(r for w in waves for r in w["rids"]) == rids
+        assert {(w["lanes"], w["filled"], w["route"]) for w in waves} == \
+            {(2, 2, "fused"), (1, 1, "fused")}
+        for e in named("serve.wave.dispatch"):
+            assert by_id[e["parent"]]["name"] == "serve.wave"
+            assert by_id[e["parent"]]["rids"] == e["rids"]
+        assert sorted(tuple(e["rids"]) for e in named("serve.wave.finish")) \
+            == sorted(tuple(w["rids"]) for w in waves)
+        done = [e for e in events if e["kind"] == "done"]
+        assert sorted(e["rid"] for e in done) == rids
+        assert all(0.0 <= e["queue_s"] <= e["latency_s"] for e in done)
+        # the JSONL schema operators read keeps its wave events
+        assert len([e for e in events if e["kind"] == "wave"]) == 2
+
+    def test_stats_totals_sum_the_bucket_counters(self):
+        cfg = TuckerConfig(ranks=RANKS, methods="eig")
+        with TuckerService(policy=BucketPolicy(grid=8, wave_slots=2)) as svc:
+            for k, shape in enumerate([(16, 16, 16), (16, 16, 16),
+                                       (16, 16, 13)]):
+                svc.submit(_x(shape, seed=k), cfg)
+            svc.drain()
+            stats = svc.stats()
+        tot = stats["totals"]
+        assert set(tot) == {"waves", "lanes", "lanes_filled", "true_elems",
+                            "slot_elems", "completed", "padded", "failed"}
+        assert tot["completed"] == 3 and tot["failed"] == 0
+        assert tot["padded"] == 1 and tot["lanes_filled"] == 3
+        assert tot["waves"] == sum(b["waves"]
+                                   for b in stats["buckets"].values())
+        assert tot["true_elems"] == 16 * 16 * (16 + 16 + 13)
+        assert tot["slot_elems"] == 3 * 16 ** 3
+        assert stats["pad_waste"] == pytest.approx(
+            1 - tot["true_elems"] / tot["slot_elems"], abs=1e-6)
+
     def test_serve_slice_yields_one_perfetto_trace(self, tmp_path):
         """One traced serve slice ties the whole story together: submit →
         wave → done around plan/compile/execute, with per-mode solve spans
@@ -491,34 +633,12 @@ class TestServiceObservability:
         doc = export_mod.write_chrome(buf.events(), path)
         names = {e["name"].split(" ")[0] for e in doc["traceEvents"]}
         assert {"submit", "wave", "solve", "compile", "plan",
-                "execute", "done"} <= names
+                "execute", "done", "serve.submit", "serve.wave",
+                "serve.wave.dispatch", "serve.wave.finish"} <= names
         json.loads(path.read_text())   # loadable
         solves = [e for e in doc["traceEvents"] if e["name"] == "solve"]
         assert all(e["args"]["solver"] == "eig" and "rank" in e["args"]
                    for e in solves)
-
-    def test_wave_drift_attribution_from_fused_serve(self):
-        """Un-recorded waves amortize wave wall-clock over their jobs and
-        feed the drift monitor with source="serve" when plans carry a
-        calibrated prediction."""
-        class BogusSelector:
-            cost_model = CostModel(eig_scale=1.0, source="calibrated")
-
-        drift_mod.MONITOR.reset()
-        try:
-            cfg = TuckerConfig(ranks=RANKS, methods="eig")
-            with TuckerService(selector=BogusSelector(),
-                               policy=BucketPolicy(grid=8,
-                                                   wave_slots=2)) as svc:
-                for seed in range(3):
-                    svc.submit(_x(seed=seed), cfg)
-                svc.drain()
-            cells = drift_mod.MONITOR.cells()
-            assert cells, "fused serve waves fed no drift observations"
-            cell = next(iter(cells.values()))
-            assert cell.sources.get("serve", 0) > 0
-        finally:
-            drift_mod.MONITOR.reset()
 
     def test_concurrent_submit_and_stats(self):
         """Hammer submit() and stats() from threads: no torn reads, no

@@ -29,6 +29,8 @@ TENSORS = {
 }
 CASES = [("Cavity", 0), ("Cavity", 1), ("Cavity", 2), ("Boats", 2),
          ("Air", 2), ("MNIST", 1)]
+#: the ``name=`` of each ``pallas_call`` in ``repro.kernels``
+KERNEL_NAMES = ("ttm_interior", "ttt_nt", "ttt_tn", "matmul")
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +73,8 @@ def test_kernel_compiles_and_fits(one_chip, name, mode, op):
                          (x, _f32(small, one_chip)), (shape[mode], r))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # every kernel carries a stable name, for a profile to find it by
+    assert any(k in compiled.as_text() for k in KERNEL_NAMES)
     mem = compiled.memory_analysis()
     io = 4 * (sum(math.prod(a.shape) for a in args) + math.prod(out))
     assert mem.temp_size_in_bytes <= 2 * io, (mem, io)
