@@ -872,6 +872,19 @@ class TuckerPlan:
         linear-in-I_n advantage).  Doubling keeps total sketch work within
         2× of the final width's.
 
+        The host waits on the device exactly once per sketch width tried:
+        one read of that width's eigenvalues and energy, the inputs of the
+        rank decision (a ``sketch.readback`` span; the ``sketch`` span's
+        ``syncs`` counts them).  The Ritz rotation and shrink that finish a
+        mode are one compiled program (:func:`repro.core.solvers.ritz_shrink`)
+        dispatched without a wait, so the next mode's sketch queues behind
+        it while the device is still busy.  A step's wall-clock therefore
+        ends at the read that settles its rank; the device time of its
+        shrink is counted in the next step, whose first read waits for it.
+        The last shrink is waited on by whoever reads the result: the
+        caller on the ``methods="rand"`` path, the refine sweep (queued
+        behind it) otherwise.
+
         Returns ``(ranks, tails, factors, core, seconds, js, missed)``:
         per-mode chosen ranks and fractional tails, the sketch's own
         orthonormal factors, the shrunk core, per-step wall-clock, the
@@ -883,8 +896,7 @@ class TuckerPlan:
 
         import numpy as np
 
-        from .backend import backend_ops
-        from .solvers import rand_sketch
+        from .solvers import rand_sketch, ritz_shrink, sketch_readout
         cfg = self.config
         if cfg.compute_dtype:
             x = x.astype(jnp.dtype(cfg.compute_dtype))
@@ -908,18 +920,20 @@ class TuckerPlan:
                 width_cap = min(s.i_n, s.rank_grid[-1] + cfg.oversample)
                 width = min(width_cap, max(16, 2 * cfg.oversample,
                                            s.rank_grid[0] + cfg.oversample))
-                widths = 0
+                widths = syncs = 0
                 while True:
                     q, b, evals, vecs, energy = rand_sketch(
                         y, s.mode, width, power_iters=cfg.power_iters,
                         impl=s.backend)
                     widths += 1
-                    # the host waits here for the sketch it reads
+                    packed = sketch_readout(evals, energy)
+                    # the host waits here for the sketch it reads, and for
+                    # all queued before it (the previous mode's shrink)
                     with _obs.span("sketch.readback", mode=s.mode,
                                    width=int(width)):
-                        ev = np.maximum(np.asarray(evals, dtype=np.float64),
-                                        0.0)
-                        energy = float(energy)
+                        read = np.asarray(packed, dtype=np.float64)
+                    syncs += 1
+                    ev, energy = np.maximum(read[:-1], 0.0), float(read[-1])
                     if total is None:
                         # step 0: ||X||², the budget basis
                         total = energy or 1.0
@@ -942,19 +956,13 @@ class TuckerPlan:
                     tail = max(energy - float(csum[r - 1]), 0.0)
                     missed.append(s.mode)
                 chosen[s.mode], tails[s.mode] = int(r), tail / total
+                dt = _time.perf_counter() - t0
                 # top-r Ritz rotation of the range basis; shrink via the
                 # already-projected b — no second pass over the input
-                v = vecs[:, -r:][:, ::-1].astype(q.dtype)
-                u = jnp.dot(q, v, precision=jax.lax.Precision.HIGHEST)
-                factors[s.mode] = u.astype(wdtype)
-                ttm = backend_ops(s.backend)[0]
-                y = ttm(b, v.T, s.mode).astype(wdtype)
-                with _obs.span("sketch.readback", mode=s.mode,
-                               width=int(width)):
-                    jax.block_until_ready(y)
-                dt = _time.perf_counter() - t0
+                factors[s.mode], y = ritz_shrink(
+                    q, b, vecs, s.mode, int(r), dtype=wdtype, impl=s.backend)
                 sp.set(rank=int(r), tail_err=tail / total, width=int(width),
-                       j_n=js[-1], widths=widths)
+                       j_n=js[-1], widths=widths, syncs=syncs)
             seconds.append(dt)
             _drift.MONITOR.observe(platform=platform, backend=s.backend,
                                    solver="rand",

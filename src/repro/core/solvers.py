@@ -267,11 +267,39 @@ def rand_solve(y: jax.Array, mode: int, rank: int, *,
     width = min(y.shape[mode], rank + oversample)
     q, b, _, vecs, _ = _sketch(
         y, mode, width, power_iters=power_iters, seed=seed, impl=impl)
+    u, y_new = _ritz_rotate(q, b, vecs, mode, rank, impl)
+    return SolveResult(u.astype(y.dtype), y_new.astype(y.dtype))
+
+
+def _ritz_rotate(q, b, vecs, mode: int, rank: int, impl: str):
+    """Top-``rank`` Rayleigh–Ritz rotation of a sketch: the factor
+    ``u = q·v`` and ``b`` with mode ``mode`` rotated from ℓ to ``rank``
+    (``v`` the leading Ritz vectors), both in ``q``'s dtype."""
     v = vecs[:, -rank:][:, ::-1].astype(q.dtype)         # leading R_n Ritz vecs
     ttm, _, _ = _scoped_ops(impl)
     u = jnp.dot(q, v, precision=jax.lax.Precision.HIGHEST)
-    y_new = ttm(b, v.T, mode)                            # rotate core: ℓ → R_n
-    return SolveResult(u.astype(y.dtype), y_new.astype(y.dtype))
+    return u, ttm(b, v.T, mode)                          # rotate core: ℓ → R_n
+
+
+@partial(jax.jit, static_argnames=("mode", "rank", "dtype", "impl"))
+def ritz_shrink(q: jax.Array, b: jax.Array, vecs: jax.Array, mode: int,
+                rank: int, *, dtype, impl: str = "matfree") -> SolveResult:
+    """The finish of one sketched mode, as one program: the top-``rank``
+    Ritz factor and the tensor shrunk to ``rank`` along ``mode``, both cast
+    to ``dtype``, from a :func:`rand_sketch`'s ``(q, b, vecs)``.  Compiled
+    once per mode, rank, sketch shape and backend, so the rank-adaptive
+    pass dispatches it without waiting on the device."""
+    with jax.named_scope(f"mode{mode}.rand"):
+        u, y_new = _ritz_rotate(q, b, vecs, mode, rank, impl)
+    return SolveResult(u.astype(dtype), y_new.astype(dtype))
+
+
+@jax.jit
+def sketch_readout(evals: jax.Array, energy: jax.Array) -> jax.Array:
+    """A :func:`rand_sketch`'s ``evals`` followed by its ``energy``, in one
+    ``(ℓ+1,)`` array: everything a rank decision reads, in one transfer."""
+    dtype = jnp.promote_types(evals.dtype, energy.dtype)
+    return jnp.concatenate([evals.astype(dtype), energy[None].astype(dtype)])
 
 
 SOLVERS = {"eig": eig_solve, "als": als_solve, "svd": svd_solve, "rand": rand_solve}

@@ -6,9 +6,11 @@ and adaptive configs flowing through serving."""
 from dataclasses import replace
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.core import (TuckerConfig, TuckerPlan, plan, rand_sketch,
                         rand_solve, tensor_ops as T)
 from repro.core.backend import backend_ops
@@ -168,6 +170,173 @@ class TestAdaptiveExecution:
         assert len(out) == 2
         for r, xi in zip(out, xs):
             assert float(r.tucker.rel_error(xi)) <= EPS
+
+
+def old_ladder(p, x):
+    """The sketch pass as it ran op by op, reading the eigenvalues and the
+    energy separately and waiting for each shrunk tensor: the reference
+    the one-read-per-width pass must reproduce.  Returns ``(ranks, tails,
+    widths, missed, factors, core)`` with ``widths`` per step as
+    ``(final width, widths tried)``."""
+    cfg = p.config
+    y, total = x, None
+    ranks, tails, factors, widths, missed = {}, {}, {}, [], []
+    for s in p.schedule:
+        cap = min(s.i_n, s.rank_grid[-1] + cfg.oversample)
+        width = min(cap, max(16, 2 * cfg.oversample,
+                             s.rank_grid[0] + cfg.oversample))
+        tried = 0
+        while True:
+            q, b, evals, vecs, energy = rand_sketch(
+                y, s.mode, width, power_iters=cfg.power_iters,
+                impl=s.backend)
+            tried += 1
+            ev = np.maximum(np.asarray(evals, dtype=np.float64), 0.0)
+            energy = float(energy)
+            if total is None:
+                total = energy or 1.0
+            csum = np.cumsum(ev[::-1])
+            r = tail = None
+            for cand in s.rank_grid:
+                if cand > width:
+                    break
+                t = max(energy - float(csum[cand - 1]), 0.0)
+                if t <= s.tau * total:
+                    r, tail = cand, t
+                    break
+            if r is not None or width >= cap:
+                break
+            width = min(2 * width, cap)
+        if r is None:
+            r = max(g for g in s.rank_grid if g <= width)
+            tail = max(energy - float(csum[r - 1]), 0.0)
+            missed.append(s.mode)
+        ranks[s.mode], tails[s.mode] = r, tail / total
+        widths.append((width, tried))
+        v = vecs[:, -r:][:, ::-1].astype(q.dtype)
+        factors[s.mode] = jnp.dot(q, v, precision=jax.lax.Precision.HIGHEST)
+        y = backend_ops(s.backend)[0](b, v.T, s.mode).astype(x.dtype)
+        jax.block_until_ready(y)
+    n = len(p.shape)
+    return (tuple(ranks[m] for m in range(n)), tails, widths, missed,
+            [factors[m] for m in range(n)], y)
+
+
+#: (dims, true ranks, seed, noise, error target, rank grid): the ladder
+#: staying narrow on three seeds, widening to the cap (I_n) on every mode,
+#: widening once, and a rank grid met and missed
+LADDERS = [
+    (DIMS, TRUE_RANKS, 0, 0.01, EPS, None),
+    (DIMS, TRUE_RANKS, 1, 0.01, EPS, None),
+    (DIMS, TRUE_RANKS, 2, 0.01, EPS, None),
+    ((60, 40, 24), (30, 25, 20), 1, 0.2, 0.02, None),
+    ((60, 40, 24), (30, 25, 20), 1, 0.05, EPS, None),
+    ((48, 40, 36), (20, 22, 18), 1, 0.02, 0.03, None),
+    (DIMS, TRUE_RANKS, 1, 0.01, EPS, (4, 8)),
+    (DIMS, TRUE_RANKS, 1, 0.3, 0.02, (4, 8)),
+]
+
+
+def _sketch_spans(events):
+    return [e for e in obs.iter_spans(events) if e["name"] == "sketch"]
+
+
+class TestSketchPassEquivalence:
+    """The pass that reads each sketch width once and shrinks in one
+    compiled program decides exactly as the op-by-op ladder did.  Tails
+    are fractions of ||X||²: each is a difference of float32 eigenvalue
+    sums, good to about 1e-7·||X||², and the compiled shrink may change
+    the last bits of the tensor the next mode sketches, so tails (and the
+    bound's square, their sum) agree to 1e-6 of ||X||²."""
+
+    @pytest.mark.parametrize("dims, true_ranks, seed, noise, eps, grid",
+                             LADDERS)
+    def test_same_ranks_widths_and_tails(self, dims, true_ranks, seed,
+                                         noise, eps, grid):
+        x = lowrank(dims, true_ranks, seed=seed, noise=noise)
+        p = plan(dims, jnp.float32,
+                 TuckerConfig(error_target=eps, rank_grid=grid))
+        ranks, tails, widths, missed, _, _ = old_ladder(p, x)
+        with obs.capture() as buf:
+            got_ranks, got_tails, *_, got_missed = p._sketch_pass(x)
+        got_widths = [(e["width"], e["widths"])
+                      for e in _sketch_spans(buf.events())]
+        assert got_ranks == ranks
+        assert got_missed == missed
+        assert got_widths == widths
+        for m in tails:
+            assert got_tails[m] == pytest.approx(tails[m], rel=1e-6,
+                                                 abs=1e-6)
+        _, bound = p.resolve_ranks(x)
+        assert bound ** 2 == pytest.approx(sum(tails.values()), rel=1e-6,
+                                           abs=1e-6)
+
+    def test_the_ladder_cases_cover_widening_and_misses(self):
+        widened = capped = missed = False
+        for dims, true_ranks, seed, noise, eps, grid in LADDERS:
+            x = lowrank(dims, true_ranks, seed=seed, noise=noise)
+            p = plan(dims, jnp.float32,
+                     TuckerConfig(error_target=eps, rank_grid=grid))
+            _, _, widths, miss, _, _ = old_ladder(p, x)
+            widened |= any(tried > 1 for _, tried in widths)
+            capped |= any(w == s.i_n and tried > 1
+                          for (w, tried), s in zip(widths, p.schedule))
+            missed |= bool(miss)
+        assert widened and capped and missed
+
+    @pytest.mark.parametrize("dims, true_ranks, seed, noise, eps, grid",
+                             LADDERS)
+    def test_sketch_only_factors_and_core(self, dims, true_ranks, seed,
+                                          noise, eps, grid):
+        x = lowrank(dims, true_ranks, seed=seed, noise=noise)
+        p = plan(dims, jnp.float32,
+                 TuckerConfig(error_target=eps, rank_grid=grid,
+                              methods="rand"))
+        ranks, _, _, missed, factors, core = old_ladder(p, x)
+        res = p.execute(x)
+        assert res.tucker.ranks == ranks
+        if missed:      # the rand→eig hop refined: no sketch factors
+            assert all(t.method == "eig" for t in res.trace)
+            return
+        # an eigenvector's sign is free: align each column before comparing
+        got_core = np.asarray(res.tucker.core)
+        for m, (u, ref) in enumerate(zip(res.tucker.factors, factors)):
+            u, ref = np.asarray(u), np.asarray(ref)
+            sign = np.sign(np.sum(u * ref, axis=0))
+            np.testing.assert_allclose(u * sign, ref, atol=1e-4)
+            shape = [1] * got_core.ndim
+            shape[m] = -1
+            got_core = got_core * sign.reshape(shape)
+        scale = float(np.max(np.abs(np.asarray(core))))
+        np.testing.assert_allclose(got_core, np.asarray(core),
+                                   atol=1e-4 * scale)
+
+
+class TestSketchPassSyncs:
+    def test_one_blocking_read_per_width(self):
+        x = lowrank((60, 40, 24), (30, 25, 20), seed=1, noise=0.05)
+        p = plan((60, 40, 24), jnp.float32, TuckerConfig(error_target=EPS))
+        with obs.capture() as buf:
+            p._sketch_pass(x)
+        spans = list(obs.iter_spans(buf.events()))
+        sketches = [e for e in spans if e["name"] == "sketch"]
+        readbacks = [e for e in spans if e["name"] == "sketch.readback"]
+        assert len(sketches) == 3
+        assert sum(e["widths"] for e in sketches) > 3   # the ladder widened
+        for e in sketches:
+            assert e["syncs"] == e["widths"]
+        assert len(readbacks) == sum(e["widths"] for e in sketches)
+
+    def test_same_shape_same_ranks_compiles_no_new_shrink(self):
+        from repro.core.solvers import ritz_shrink, sketch_readout
+        p = plan(DIMS, jnp.float32, TuckerConfig(error_target=EPS))
+        first = p.execute(lowrank(DIMS, TRUE_RANKS, seed=0))
+        shrinks, readouts = (ritz_shrink._cache_size(),
+                             sketch_readout._cache_size())
+        second = p.execute(lowrank(DIMS, TRUE_RANKS, seed=1))
+        assert second.tucker.ranks == first.tucker.ranks
+        assert ritz_shrink._cache_size() == shrinks
+        assert sketch_readout._cache_size() == readouts
 
 
 class TestAdaptivePlanJSON:

@@ -438,8 +438,8 @@ class TestCoreSpans:
         by_id = {e["span"]: e for e in spans}
         sketches = [e for e in spans if e["name"] == "sketch"]
         readbacks = [e for e in spans if e["name"] == "sketch.readback"]
-        # one read per width tried, and one wait for each shrunk tensor
-        assert len(readbacks) == sum(e["widths"] + 1 for e in sketches)
+        # one read per width tried, and no other wait
+        assert len(readbacks) == sum(e["widths"] for e in sketches)
         for e in readbacks:
             parent = by_id[e["parent"]]
             assert parent["name"] == "sketch"
