@@ -529,6 +529,16 @@ class TuckerPlan:
         return sum(s.predicted_s for s in self.schedule)
 
     @property
+    def als_passes(self) -> float:
+        """Full reads that the ALS steps make of their own inputs (per
+        iteration one TTM and one TTT, then the closing projection), each
+        weighted by that input's elements over the decomposition's input:
+        the input traffic of the ALS solver, from the plan alone."""
+        reads = 2 * self.config.als_iters + 1
+        return sum(reads * s.i_n * s.j_n for s in self.schedule
+                   if s.method == "als") / math.prod(self.shape)
+
+    @property
     def input_bytes(self) -> int:
         """Per-device bytes of the caller's input buffer — the plan's
         STORAGE dtype, not the compute dtype (the cast happens inside the
@@ -692,7 +702,7 @@ class TuckerPlan:
                 backend=self.backend, variant=self.config.variant,
                 adaptive=self.is_adaptive,
                 predicted_s=self.total_predicted_s,
-                peak_bytes=self.peak_bytes)
+                peak_bytes=self.peak_bytes, als_passes=self.als_passes)
         with _obs.span("execute", record=record, **attrs):
             return self._execute(x, record=record, donate=donate,
                                  validate=validate)

@@ -2,8 +2,8 @@
 
 Used (a) as the analytic fallback of the adaptive selector when no trained
 decision tree is available for the current platform, and (b) to derive the
-Table-I features.  The paper leaves the LAPACK-kernel constants f_eig/f_qr/
-f_inv symbolic; :class:`CostModel` makes them *data*: the textbook defaults
+Table-I features.  The paper leaves the LAPACK-kernel constants f_eig/f_qr
+symbolic; :class:`CostModel` makes them *data*: the textbook defaults
 (Golub & Van Loan operation counts) ship as ``DEFAULT_COST_MODEL``, and
 :mod:`repro.tune.calibrate` fits hardware-specific constants — plus a
 seconds-per-FLOP scale per solver — from measured records, so the same
@@ -32,9 +32,6 @@ class CostModel:
         (textbook tridiagonalization + QL: 9).
     c_qr
         Scale on the Householder QR count 2mn² − (2/3)n³ (textbook: 1).
-    c_inv
-        SPD inverse constant: f_inv(n) = c_inv·n³ (textbook Cholesky +
-        triangular solves: 2).
     eig_scale / als_scale
         Seconds per modeled FLOP for each solver, fitted by calibration.
         At the textbook default (1.0) the "seconds" methods return plain
@@ -53,7 +50,6 @@ class CostModel:
     """
     c_eig: float = 9.0
     c_qr: float = 1.0
-    c_inv: float = 2.0
     eig_scale: float = 1.0
     als_scale: float = 1.0
     rand_scale: float | None = None
@@ -79,9 +75,6 @@ class CostModel:
     def f_qr(self, m: int, n: int) -> float:
         return self.c_qr * (2.0 * m * float(n) * n - (2.0 / 3.0) * float(n) ** 3)
 
-    def f_inv(self, n: int) -> float:
-        return self.c_inv * float(n) ** 3
-
     # -- Eq. 4/5 -------------------------------------------------------------
     def eig_flops(self, i_n: int, r_n: int, j_n: int) -> float:
         """Eq. (4): Gram (I_n² J_n) + TTM (2 I_n R_n J_n) + eig."""
@@ -89,16 +82,12 @@ class CostModel:
 
     def als_flops(self, i_n: int, r_n: int, j_n: int,
                   num_iters: int = DEFAULT_ALS_ITERS) -> float:
-        """Eq. (5): per-iteration 2 TTM + 2 TTT + 2 GEMM + 2 inversions,
-        plus the closing TTM and QR."""
-        per_iter = (
-            2.0 * i_n * j_n * r_n + 2.0 * j_n * r_n * r_n   # R-update TTM + scale
-            + 2.0 * i_n * j_n * r_n + 2.0 * j_n * r_n * r_n  # L-update TTT + scale
-            + 4.0 * i_n * r_n * r_n                          # GEMMs with inverses
-            + 2.0 * self.f_inv(r_n)
-        )
-        return per_iter * num_iters + 2.0 * j_n * r_n * r_n \
-            + self.f_qr(i_n, r_n)
+        """Eq. (5) for :func:`~repro.core.solvers.als_solve`'s
+        orthonormalized iteration: per iteration the R-update TTM
+        (2 I_n R_n J_n), the L-update TTT (2 I_n R_n J_n) and the QR of the
+        I_n×R_n block; then the closing projection TTM (2 I_n R_n J_n)."""
+        per_iter = 4.0 * i_n * j_n * r_n + self.f_qr(i_n, r_n)
+        return per_iter * num_iters + 2.0 * i_n * j_n * r_n
 
     def rand_flops(self, i_n: int, r_n: int, j_n: int,
                    oversample: int = DEFAULT_OVERSAMPLE,
@@ -158,7 +147,7 @@ class CostModel:
     # -- persistence ---------------------------------------------------------
     def to_dict(self) -> dict:
         return {"version": COST_MODEL_VERSION, "c_eig": self.c_eig,
-                "c_qr": self.c_qr, "c_inv": self.c_inv,
+                "c_qr": self.c_qr,
                 "eig_scale": self.eig_scale, "als_scale": self.als_scale,
                 "rand_scale": self.rand_scale,
                 "eig_overhead_s": self.eig_overhead_s,
@@ -170,7 +159,6 @@ class CostModel:
     def from_dict(cls, d: dict) -> "CostModel":
         return cls(c_eig=float(d.get("c_eig", 9.0)),
                    c_qr=float(d.get("c_qr", 1.0)),
-                   c_inv=float(d.get("c_inv", 2.0)),
                    eig_scale=float(d.get("eig_scale", 1.0)),
                    als_scale=float(d.get("als_scale", 1.0)),
                    rand_scale=(None if d.get("rand_scale") is None
@@ -199,11 +187,6 @@ def f_eig(n: int) -> float:
 def f_qr(m: int, n: int) -> float:
     """Householder QR of an m×n (m ≥ n) matrix: 2mn² − (2/3)n³."""
     return DEFAULT_COST_MODEL.f_qr(m, n)
-
-
-def f_inv(n: int) -> float:
-    """Inverse of an n×n SPD matrix (Cholesky + triangular solves): 2n³."""
-    return DEFAULT_COST_MODEL.f_inv(n)
 
 
 def eig_flops(i_n: int, r_n: int, j_n: int) -> float:

@@ -7,8 +7,8 @@ pattern-matching XLA message strings:
 
   * :class:`InputError`       — the caller's tensor/config is bad (NaN/Inf
     inputs, shape/dtype mismatch).  Subclasses ``ValueError``.
-  * :class:`NumericalError`   — the computation broke down (Cholesky
-    failure in ALS, non-finite solver outputs).  Subclasses
+  * :class:`NumericalError`   — the computation broke down (non-finite
+    solver outputs, a failed factorization).  Subclasses
     ``FloatingPointError``.
   * :class:`ResourceError`    — the runtime ran out of something (XLA
     ``RESOURCE_EXHAUSTED`` / OOM, a dead or abandoned worker).
@@ -61,9 +61,8 @@ class InputError(TuckerError, ValueError):
 
 
 class NumericalError(TuckerError, FloatingPointError):
-    """The computation broke down numerically: a Cholesky factorization
-    failed past its re-regularization ladder, or a solver produced
-    non-finite factors from a finite input."""
+    """The computation broke down numerically: a factorization failed,
+    or a solver produced non-finite factors from a finite input."""
 
 
 class ResourceError(TuckerError):

@@ -201,19 +201,20 @@ def _step_cost(method: str, i_n: int, r_n: int, j_n: int,
 def _solver_scratch_bytes(method: str, i_n: int, r_n: int, j_n: int,
                           itemsize: int, n_shards: int = 1) -> int:
     """Modeled solver scratch only (no I/O tensors): EIG's I_n×I_n Gram,
-    ALS's L/R iterates (+ fp32 input cast for sub-fp32 dtypes), SVD's
+    ALS's iterates (+ fp32 input cast for sub-fp32 dtypes), SVD's
     explicit unfolding plus its left singular block.  Scratch lives in the
     *accumulation* dtype; sharded parts (ALS's R-tensor and cast, which
     stay with the input) divide by ``n_shards`` while replicated scratch
-    (EIG's psum'd Gram, ALS's L factor and R^T R) does not."""
+    (EIG's psum'd Gram, ALS's basis Q, its L block and QR's R_n×R_n
+    triangle) does not."""
     accum = max(itemsize, 4)   # bf16/fp16 accumulate in fp32; fp64 stays 8
     if method == "eig":
         return i_n * i_n * accum               # replicated psum'd Gram
     if method == "als":
-        scratch = (2 * i_n * r_n + 2 * r_n * r_n) * accum \
-            + 2 * r_n * j_n * accum // n_shards   # R-tensor stays sharded
+        scratch = (2 * i_n * r_n + r_n * r_n) * accum \
+            + r_n * j_n * accum // n_shards   # R-tensor stays sharded
         if accum != itemsize:
-            scratch += i_n * j_n * accum // n_shards  # yc: fp32 input cast
+            scratch += i_n * j_n * accum // n_shards  # the fp32 input cast
         return scratch
     if method == "rand":
         # Gaussian test tensor Ω (ℓ·J) + range sample / Q (I·ℓ) + the ℓ-wide

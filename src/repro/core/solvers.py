@@ -6,7 +6,15 @@ and ``y_new`` is the tensor with mode ``n`` shrunk to R_n:
 
   EIG  (paper Alg. 2 lines 6–8):  S = Y_(n)Y_(n)^T  → leading eigvecs → TTM.
   ALS  (paper Alg. 2 lines 10–13 + Alg. 3): rank-R_n alternating LS on
-       Y_(n) ≈ L R^T, then QR(L) for orthonormality, core = TTM(R-tensor, R̂).
+       Y_(n) ≈ L R^T, core = TTM(y, Qᵀ) for the final basis Q (the last
+       R-update).  Departure from Alg. 3: L is orthonormalized (QR) every
+       iteration, so the L-update is QR(TTT(y, R-tensor)) and the
+       R-update TTM(y, Qᵀ), with no (LᵀL)⁻¹ or (RᵀR)⁻¹.  In exact
+       arithmetic this is the same subspace sequence (span L_{k+1} =
+       span Y_(n)Y_(n)ᵀL_k either way, i.e. orthogonal iteration); in
+       float32 the normal-equation inverses of an L whose columns align
+       with the leading direction lose the weak directions to rounding,
+       a floor that more iterations do not lower.
   SVD  (paper Alg. 1; baseline only — always slowest, kept for Fig. 2).
   RAND (randomized range finder / sketched Gram, Minster–Saibaba–Kilmer
        [1905.07311]): Y_(n) Ω for a Gaussian test tensor Ω with
@@ -56,7 +64,7 @@ def _scoped(name: str, f):
 def _scoped_ops(impl: str):
     """The backend's ``(ttm, gram, ttt)``, each traced under a named scope
     of its own name, so a compiled solve's profile names its contractions
-    (the eigen, QR and inverse steps run under ``solve``)."""
+    (the eigen and QR steps run under ``solve``)."""
     ttm, gram, ttt = backend_ops(impl)
     return _scoped("ttm", ttm), _scoped("gram", gram), _scoped("ttt", ttt)
 
@@ -87,77 +95,38 @@ def als_solve(y: jax.Array, mode: int, rank: int, *,
               seed: int = 0,
               impl: str = "matfree") -> SolveResult:
     if num_iters < 1:
-        # the loop must run at least once: the R-tensor carry is only
-        # written inside the body (zero iterations would return a zero core)
+        # the starting block is random: zero iterations would return a
+        # random subspace as the factor
         raise ValueError(f"als_solve needs num_iters >= 1, got {num_iters}")
-    ttm, gram, ttt = _scoped_ops(impl)
+    ttm, _, ttt = _scoped_ops(impl)
     i_n = y.shape[mode]
     # sub-fp32 inputs (bf16/fp16) iterate in fp32 (the peak_bytes model in
     # plan.py assumes exactly this); fp32/fp64 keep their own precision
     cdtype = jnp.promote_types(y.dtype, jnp.float32)
-    key = jax.random.PRNGKey(seed)
-    l0 = jax.random.normal(key, (i_n, rank), dtype=cdtype)
-
-    yc = y.astype(cdtype)
+    l0 = jax.random.normal(jax.random.PRNGKey(seed), (i_n, rank), dtype=cdtype)
+    # every contraction below reads the (before, mode, after) view, made
+    # once: on a tiled TPU layout that reshape copies the whole input, and
+    # XLA repeats a copy made inside the loop in every iteration.  The last
+    # mode's view is (before, mode), the plain GEMM operand: a unit "after"
+    # axis would leave each row in a tile of its own there
+    a, n, b = T.split_dims(y.shape, mode)
+    y3 = y.astype(cdtype).reshape((a, n) if b == 1 else (a, n, b))
 
     def body(_, carry):
-        l, _ = carry
-        # R_k ← (Y_(n)^T L)(L^T L)^{-1}; tensorized: R-tensor = TTM(y, L^T, n) ×_n (LᵀL)^{-1}
-        r_t = ttm(yc, l.T, mode)
-        ltl = jnp.dot(l.T, l, precision=jax.lax.Precision.HIGHEST)
-        r_t = ttm(r_t, _spd_inverse(ltl), mode)
-        # L_{k+1} ← (Y_(n) R)(RᵀR)^{-1};  Y_(n) R = TTT(y, R-tensor, n)
-        yr = ttt(yc, r_t, mode)                          # (I_n, R_n)
-        rtr = gram(r_t, mode)                            # (R_n, R_n)
-        l_new = jnp.dot(yr, _spd_inverse(rtr),
-                        precision=jax.lax.Precision.HIGHEST)
-        return l_new, r_t
+        _, r_t = carry
+        # L ← Y_(n) R = TTT(y, R-tensor), then Q from a Householder QR of
+        # L, which stays orthonormal on a rank-deficient L (a low-rank input)
+        l_k = ttt(y3, r_t, 1)
+        with jax.named_scope("solve"):
+            q = jnp.linalg.qr(l_k)[0]
+        # R ← Y_(n)ᵀ Q: with QᵀQ = I the (LᵀL)⁻¹ of Alg. 3 is the identity;
+        # after the last iteration this is the core, y projected onto Q
+        return q, ttm(y3, q.T, 1)
 
-    # carrying the R-tensor out of the loop skips the closing "recompute R
-    # for the final L" (one extra TTM + Cholesky solve per solve): the loop
-    # exits with (L_k, R_{k-1}), a consistent ALS pair — L_k is the exact LS
-    # optimum FOR R_{k-1} — so the sweep ends on an L-update instead of
-    # paying an extra R-update of negligible accuracy benefit.
-    r_shape = y.shape[:mode] + (rank,) + y.shape[mode + 1:]
-    l, r_t = jax.lax.fori_loop(
-        0, num_iters, body, (l0, jnp.zeros(r_shape, cdtype)))
-    # orthonormalize:  L = Q̂ R̂,  U ← Q̂,  core ← TTM(R-tensor, R̂)
-    with jax.named_scope("solve"):
-        q, rhat = jnp.linalg.qr(l)
-    y_new = ttm(r_t, rhat, mode).astype(y.dtype)
-    return SolveResult(q.astype(y.dtype), y_new)
-
-
-#: escalating relative re-regularization ladder: the baseline 1e-12·tr(A)
-#: jitter first (bitwise-identical to the historical behaviour whenever it
-#: succeeds), then two stronger rungs for genuinely ill-conditioned Grams
-_SPD_JITTERS = (1e-12, 1e-8, 1e-4)
-
-
-def _spd_inverse(a: jax.Array) -> jax.Array:
-    """Inverse of a small SPD matrix via Cholesky (paper uses explicit inverse;
-    Cholesky is the numerically robust equivalent at identical O(R³) cost).
-
-    Cholesky breakdown on a rank-deficient/ill-conditioned Gram (which XLA
-    reports as NaNs, not an exception) is detected in-jit and retried with
-    escalating jitter; the last rung adds an absolute floor so even an
-    exactly-zero Gram yields a finite (pseudo-)inverse instead of poisoning
-    the whole sweep.  Because selection is by ``jnp.where`` on the FIRST
-    finite factorization, well-posed solves keep their historical bitwise
-    results."""
-    with jax.named_scope("solve"):
-        eye = jnp.eye(a.shape[0], dtype=a.dtype)
-        scale = jnp.trace(a)
-        inv = jnp.full_like(a, jnp.nan)
-        for i, jitter in enumerate(_SPD_JITTERS):
-            reg = jitter * scale
-            if i == len(_SPD_JITTERS) - 1:
-                reg = reg + jnp.asarray(1e-6, a.dtype)   # absolute floor
-            c = jax.scipy.linalg.cho_factor(a + reg * eye)
-            cand = jax.scipy.linalg.cho_solve(c, eye)
-            ok = jnp.all(jnp.isfinite(inv))
-            inv = jnp.where(ok, inv, cand)
-        return inv
+    # the unnormalized Gaussian start only scales R_0, not span(Y_(n) R_0)
+    q, r_t = jax.lax.fori_loop(0, num_iters, body, (l0, ttm(y3, l0.T, 1)))
+    out_shape = y.shape[:mode] + (rank,) + y.shape[mode + 1:]
+    return SolveResult(q.astype(y.dtype), r_t.reshape(out_shape).astype(y.dtype))
 
 
 # ---------------------------------------------------------------------------

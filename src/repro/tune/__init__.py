@@ -15,7 +15,7 @@ turns that into a loop:
     ``repro.core.selector.default_selector`` per (platform, backend) with
     graceful fallback.
   * :mod:`repro.tune.calibrate` — least-squares fit of the symbolic
-    f_eig/f_qr/f_inv constants (and seconds-per-FLOP scales) of the Eq. 4/5
+    f_eig/f_qr constants (and seconds-per-FLOP scales) of the Eq. 4/5
     cost model per backend, hardware-calibrating the selector's
     out-of-range guardrail.
 

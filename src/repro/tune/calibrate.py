@@ -1,16 +1,16 @@
 """Hardware calibration of the Eq. 4/5 cost model from measured records.
 
-The paper leaves the LAPACK-kernel constants f_eig/f_qr/f_inv symbolic;
-the textbook values (9n³, 2mn²−(2/3)n³, 2n³) assume every FLOP costs the
+The paper leaves the LAPACK-kernel constants f_eig/f_qr symbolic; the
+textbook values (9n³, 2mn²−(2/3)n³) assume every FLOP costs the
 same, which no real BLAS does — eigendecomposition FLOPs on a 1-core CPU
 are far slower than GEMM FLOPs, and each ops backend shifts the balance
 again.  This module fits, per (platform, backend), a least-squares
 decomposition of measured seconds onto the model's term structure:
 
     eig seconds ≈ o_e + α_e·(I²J + 2IRJ)       + β_e·I³
-    als seconds ≈ o_a + α_a·(GEMM-family terms) + β_a·(iters·R³) + γ_a·QR(I,R)
+    als seconds ≈ o_a + α_a·(GEMM-family terms) + γ_a·(iters·QR(I,R))
 
-which recovers c_eig = β_e/α_e, c_inv = β_a/(2α_a), c_qr = γ_a/α_a and —
+which recovers c_eig = β_e/α_e, c_qr = γ_a/α_a and —
 because the fit is against *seconds* — the per-FLOP scales α_e, α_a and
 per-solve dispatch overheads o_e, o_a that make
 ``CostModel.predict_seconds`` real wall-clock and ``predicted_best`` a
@@ -49,15 +49,13 @@ def _eig_basis(i, r, j):
 
 
 def _als_basis(i, r, j, iters):
-    """(intercept, GEMM-family, iters·R³, QR-count) columns of the Eq. 5
-    decomposition — the iters·R³ column carries the inversions (textbook
-    contribution 2·c_inv·iters·R³) and the QR column the Householder count
-    at c_qr = 1."""
+    """(intercept, GEMM-family, QR-count) columns of the Eq. 5
+    decomposition — the QR column carries one Householder count at
+    c_qr = 1 per iteration."""
     i, r, j = float(i), float(r), float(j)
-    gemm = (4.0 * i * j * r + 4.0 * j * r * r + 4.0 * i * r * r) * iters \
-        + 2.0 * j * r * r
-    return np.array([1.0, gemm, iters * r ** 3,
-                     2.0 * i * r * r - (2.0 / 3.0) * r ** 3])
+    gemm = 4.0 * i * j * r * iters + 2.0 * i * j * r
+    return np.array([1.0, gemm,
+                     iters * (2.0 * i * r * r - (2.0 / 3.0) * r ** 3)])
 
 
 def _nonneg_lstsq(a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -108,17 +106,15 @@ def fit_cost_model(measurements: Iterable[Measurement],
     ca = _nonneg_lstsq(a_a, b_a)
 
     o_e, a_e1, b_e1 = ce
-    o_a, a_a1, b_a1, g_a1 = ca
+    o_a, a_a1, g_a1 = ca
     if a_e1 <= 0 and a_a1 <= 0:
         return None   # no usable per-FLOP signal — not a calibration
     default = CostModel()
     # constants are RATIOS to the GEMM coefficient; a zeroed GEMM column
     # (degenerate fit) keeps every dependent constant at textbook
     c_eig = b_e1 / a_e1 if a_e1 > 0 and b_e1 > 0 else default.c_eig
-    c_inv = b_a1 / (2.0 * a_a1) if a_a1 > 0 and b_a1 > 0 else default.c_inv
     c_qr = g_a1 / a_a1 if a_a1 > 0 and g_a1 > 0 else default.c_qr
     return CostModel(c_eig=float(c_eig), c_qr=float(c_qr),
-                     c_inv=float(c_inv),
                      eig_scale=float(a_e1) if a_e1 > 0 else 1.0,
                      als_scale=float(a_a1) if a_a1 > 0 else 1.0,
                      eig_overhead_s=float(max(o_e, 0.0)),

@@ -92,7 +92,7 @@ def cmd_calibrate(args) -> int:
         return 1
     for path, doc in written.items():
         print(f"wrote {path}: c_eig={doc['c_eig']:.2f} "
-              f"c_qr={doc['c_qr']:.2f} c_inv={doc['c_inv']:.2f} "
+              f"c_qr={doc['c_qr']:.2f} "
               f"eig_scale={doc['eig_scale']:.3g} "
               f"als_scale={doc['als_scale']:.3g}")
     return 0

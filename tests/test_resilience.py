@@ -98,8 +98,8 @@ class TestTaxonomy:
 
 class TestSolverGuards:
     def test_als_survives_rank_deficient_gram(self):
-        # an exactly rank-1 tensor makes every Gram singular; the jittered
-        # re-regularization ladder in _spd_inverse must keep ALS finite
+        # an exactly rank-1 tensor leaves ALS's iterate rank-deficient on
+        # every mode; whatever the reason, ALS itself must stay finite
         a, b, c = _rand(12, 1), _rand(10, 2), _rand(8, 3)
         x = np.einsum("i,j,k->ijk", a, b, c)
         cfg = TuckerConfig(ranks=(3, 3, 3), methods="als")

@@ -432,9 +432,13 @@ class TestReviewRegressions:
     def test_undonated_plan_cap_counts_held_input(self):
         # every step fits the cap, but an UNDONATED sweep also keeps the
         # dead input copy alive through steps 1..N-1 — the plan must refuse
-        shape, ranks = (32, 24, 20), (4, 4, 4)
+        shape, ranks = (24, 32, 20), (4, 4, 4)
         donated = plan(shape, jnp.float32,
                        TuckerConfig(ranks=ranks, donate_input=True))
+        # the premise: a later step plus the held input outgrows step 0
+        assert plan(shape, jnp.float32,
+                    TuckerConfig(ranks=ranks, donate_input=False)
+                    ).peak_bytes > donated.peak_bytes
         cap = donated.peak_bytes + 1   # fits per step and when donated
         assert plan(shape, jnp.float32,
                     TuckerConfig(ranks=ranks, donate_input=True,

@@ -43,7 +43,9 @@ def model_env(tmp_path, monkeypatch):
 def synthetic_records(*, backend="matfree", platform="cpu", als_faster_above=64,
                       n=40, seed=0):
     """Labeled-by-construction records: als wins iff i_n > threshold.
-    Seconds are flop-proportional + overhead so calibration fits cleanly."""
+    Seconds are overhead + a flop-proportional term, so calibration fits
+    cleanly; the term stays under the 1e-4 s overhead gap at every size
+    here, so the overhead alone decides each label."""
     rng = np.random.default_rng(seed)
     out = []
     for i in np.unique(np.geomspace(4, 256, n).astype(int)):
@@ -52,8 +54,8 @@ def synthetic_records(*, backend="matfree", platform="cpu", als_faster_above=64,
         slow, fast = 2e-4, 1e-4
         te = slow if i > als_faster_above else fast
         ta = fast if i > als_faster_above else slow
-        te += 1e-10 * cm_mod.eig_flops(i, r, j)
-        ta += 1e-10 * cm_mod.als_flops(i, r, j)
+        te += 1e-13 * cm_mod.eig_flops(i, r, j)
+        ta += 1e-13 * cm_mod.als_flops(i, r, j)
         out.append(M(int(i), r, j, "eig", te, backend=backend,
                      platform=platform))
         out.append(M(int(i), r, j, "als", ta, backend=backend,
@@ -275,7 +277,7 @@ class TestCalibration:
     def test_fit_recovers_scales_and_constants(self):
         """Synthetic seconds generated FROM the model → fit recovers it."""
         rng = np.random.default_rng(3)
-        truth = CostModel(c_eig=40.0, c_inv=2.0, c_qr=1.0,
+        truth = CostModel(c_eig=40.0, c_qr=1.0,
                           eig_scale=2e-10, als_scale=1e-10,
                           eig_overhead_s=3e-4, als_overhead_s=8e-4,
                           source="calibrated")
