@@ -11,8 +11,8 @@ an ``impl`` string.
 
 Built-in backends:
 
-  ``matfree``   jnp contractions on the (A, I_n, B) view — no unfold copy
-                (tensor_ops; the paper's Fig. 4 structure via XLA).
+  ``matfree``   one ``lax.dot_general`` per primitive over the tensor's own
+                axes — no unfold and no merged view (tensor_ops).
   ``explicit``  unfold → GEMM → fold baseline (paper Fig. 3 / Fig. 8).
   ``pallas``    hand-written Pallas TPU kernels (kernels/ops.py): tiled
                 matmul / batched-TTM / TTT that mask ragged edge blocks
@@ -72,6 +72,12 @@ class OpsBackend:
     cost_scale
         Relative per-FLOP cost hint vs ``matfree`` on this backend's native
         platform; the selector/cost model may scale Eq. 4/5 estimates by it.
+    native_axes
+        True if the primitives contract over the tensor's own axes and
+        take it as it is.  False: they reshape to merged ``(A, I_n, B)``
+        views, which on a tiled TPU layout copies the tensor, so a solver
+        that contracts one tensor many times makes that view once itself
+        (:func:`repro.core.solvers.als_solve`).
     requires_mesh
         True if the backend executes across a jax mesh: plans must carry one
         (``TuckerConfig(mesh=...)``), ``auto`` only selects it when a mesh is
@@ -91,6 +97,7 @@ class OpsBackend:
     matricizes: bool = False
     cost_scale: float = 1.0
     interpret_fallback: bool = False
+    native_axes: bool = False
     requires_mesh: bool = False
     solvers: tuple[str, ...] = ("eig", "als", "svd", "rand")
     _ops: list = field(default_factory=list, repr=False, compare=False)
@@ -225,7 +232,8 @@ def _load_pallas() -> OpsTriple:
 
 register_backend(OpsBackend(
     name="matfree", loader=_load_matfree,
-    dtypes=("*",), platforms=("*",), matricizes=False, cost_scale=1.0))
+    dtypes=("*",), platforms=("*",), matricizes=False, native_axes=True,
+    cost_scale=1.0))
 
 register_backend(OpsBackend(
     name="explicit", loader=_load_explicit,
@@ -249,7 +257,7 @@ register_backend(OpsBackend(
     # plumbing (partial-Gram psum, local TTM, resharding) lives in
     # core/distributed.py and is wired in by the plan layer
     name="sharded", loader=_load_matfree,
-    dtypes=("*",), platforms=("*",), matricizes=False,
+    dtypes=("*",), platforms=("*",), matricizes=False, native_axes=True,
     requires_mesh=True, cost_scale=1.0))
 
 

@@ -104,27 +104,31 @@ def als_solve(y: jax.Array, mode: int, rank: int, *,
     # plan.py assumes exactly this); fp32/fp64 keep their own precision
     cdtype = jnp.promote_types(y.dtype, jnp.float32)
     l0 = jax.random.normal(jax.random.PRNGKey(seed), (i_n, rank), dtype=cdtype)
-    # every contraction below reads the (before, mode, after) view, made
-    # once: on a tiled TPU layout that reshape copies the whole input, and
-    # XLA repeats a copy made inside the loop in every iteration.  The last
-    # mode's view is (before, mode), the plain GEMM operand: a unit "after"
-    # axis would leave each row in a tile of its own there
-    a, n, b = T.split_dims(y.shape, mode)
-    y3 = y.astype(cdtype).reshape((a, n) if b == 1 else (a, n, b))
+    # the loop contracts y 2·num_iters + 1 times.  Matfree's ops contract
+    # over y's own axes; a backend that takes merged views gets the
+    # (before, mode, after) view, made once: on a tiled TPU layout that
+    # reshape copies the whole input, and XLA repeats a copy made inside
+    # the loop in every iteration.  The last mode's view is (before, mode),
+    # the plain GEMM operand: a unit "after" axis would leave each row in a
+    # tile of its own there
+    yc, m = y.astype(cdtype), mode
+    if not get_backend(impl).native_axes:
+        a, n, b = T.split_dims(y.shape, mode)
+        yc, m = yc.reshape((a, n) if b == 1 else (a, n, b)), 1
 
     def body(_, carry):
         _, r_t = carry
         # L ← Y_(n) R = TTT(y, R-tensor), then Q from a Householder QR of
         # L, which stays orthonormal on a rank-deficient L (a low-rank input)
-        l_k = ttt(y3, r_t, 1)
+        l_k = ttt(yc, r_t, m)
         with jax.named_scope("solve"):
             q = jnp.linalg.qr(l_k)[0]
         # R ← Y_(n)ᵀ Q: with QᵀQ = I the (LᵀL)⁻¹ of Alg. 3 is the identity;
         # after the last iteration this is the core, y projected onto Q
-        return q, ttm(y3, q.T, 1)
+        return q, ttm(yc, q.T, m)
 
     # the unnormalized Gaussian start only scales R_0, not span(Y_(n) R_0)
-    q, r_t = jax.lax.fori_loop(0, num_iters, body, (l0, ttm(y3, l0.T, 1)))
+    q, r_t = jax.lax.fori_loop(0, num_iters, body, (l0, ttm(yc, l0.T, m)))
     out_shape = y.shape[:mode] + (rank,) + y.shape[mode + 1:]
     return SolveResult(q.astype(y.dtype), r_t.reshape(out_shape).astype(y.dtype))
 

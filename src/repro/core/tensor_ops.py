@@ -1,15 +1,17 @@
 """Matricization-free dense tensor operations (a-Tucker, Sec. V).
 
 The paper's insight: TTM / TTT / Gram on mode ``n`` never need an explicit
-unfold.  Split the loop nest into (outer, along, inner) the target mode and
-merge outer/inner — the computation becomes a single GEMM when ``n`` is the
-first or last mode and a batched GEMM for interior modes (paper Fig. 4).
+unfold.  Each is one contraction over the tensor's own axes: TTM contracts
+``U``'s columns with axis ``n`` and leaves every other axis where it is;
+TTT and Gram contract every axis but ``n``.  One ``lax.dot_general`` each,
+with the original axes as its contracting or free dimensions, so XLA
+chooses the layouts: a relayout, where one is needed, fuses into the dot's
+operand read, or is made once for a loop that contracts one tensor often.
 
-In C-order (row-major) JAX the *last* axis is contiguous, so the roles of
-"first" and "last" are mirrored w.r.t. the paper's column-major layout; the
-structure is identical.  ``jnp.reshape`` that only merges adjacent axes is
-free (no data movement), so the 3-way view ``(A, I_n, B)`` below costs
-nothing; the contraction then runs directly on native storage.
+No op reshapes to a merged ``(A, I_n, B)`` or ``(I_n, J_n)`` view.  Such a
+reshape is free in row-major memory, but not on a TPU, whose arrays are
+tiled in ``(8, 128)`` blocks over the two minor axes: a merge across the
+minor axis moves every element, a copy of the whole tensor.
 
 ``*_explicit`` variants materialize the mode-n unfolding first (moveaxis →
 copy → GEMM → fold) and exist as the paper's explicit-matricization baseline
@@ -19,7 +21,6 @@ copy → GEMM → fold) and exist as the paper's explicit-matricization baseline
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -36,12 +37,6 @@ def split_dims(shape: tuple[int, ...], mode: int) -> tuple[int, int, int]:
     return a, shape[mode], b
 
 
-def _as3(x: jax.Array, mode: int) -> jax.Array:
-    """Free (adjacent-merge) reshape to the (A, I_n, B) view."""
-    a, i, b = split_dims(x.shape, mode)
-    return x.reshape(a, i, b)
-
-
 # ---------------------------------------------------------------------------
 # Matricization-free ops
 # ---------------------------------------------------------------------------
@@ -50,27 +45,19 @@ def ttm(x: jax.Array, u: jax.Array, mode: int, *,
         precision=jax.lax.Precision.HIGHEST) -> jax.Array:
     """Mode-``mode`` tensor-times-matrix:  Y = X ×_mode U,  U: (R, I_mode).
 
-    Matricization-free: contracts directly on the (A, I_n, B) view.
-    mode == 0      → one GEMM   (R,I) @ (I, B)        -> (R, B)
-    mode == N-1    → one GEMM   (A, I) @ (I, R)       -> (A, R)
-    interior       → batched GEMM over A: (R,I)@(I,B) -> (A, R, B)
+    Matricization-free: one contraction of ``u``'s columns with axis
+    ``mode`` of ``x``.  Its result leads with R, which then moves to
+    ``mode`` (nothing moves for mode 0); for the last mode ``x`` leads, so
+    R comes out last, where it belongs.
     """
     if u.ndim != 2 or u.shape[1] != x.shape[mode]:
         raise ValueError(f"ttm: U {u.shape} incompatible with mode {mode} of {x.shape}")
-    r = u.shape[0]
-    out_shape = x.shape[:mode] + (r,) + x.shape[mode + 1:]
-    n = x.ndim
-    if mode == 0:
-        x2 = x.reshape(x.shape[0], -1)
-        y = jnp.dot(u, x2, precision=precision)
-    elif mode == n - 1:
-        x2 = x.reshape(-1, x.shape[-1])
-        y = jnp.dot(x2, u.T, precision=precision)
-    else:
-        x3 = _as3(x, mode)
-        # einsum 'anb,rn->arb' — XLA lowers to a batched GEMM; no unfold copy.
-        y = jnp.einsum("anb,rn->arb", x3, u, precision=precision)
-    return y.reshape(out_shape)
+    if mode == x.ndim - 1:
+        return jax.lax.dot_general(x, u, (((mode,), (1,)), ((), ())),
+                                   precision=precision)
+    y = jax.lax.dot_general(u, x, (((1,), (mode,)), ((), ())),
+                            precision=precision)
+    return jnp.moveaxis(y, 0, mode)
 
 
 def ttm_chain(x: jax.Array, us: dict[int, jax.Array] | list, *,
@@ -86,18 +73,9 @@ def ttm_chain(x: jax.Array, us: dict[int, jax.Array] | list, *,
 
 def gram(x: jax.Array, mode: int, *,
          precision=jax.lax.Precision.HIGHEST) -> jax.Array:
-    """S = Y_(n) Y_(n)^T  (I_n × I_n) without forming Y_(n).
-
-    Special case of TTT with both inputs equal (paper Sec. V).  Contracts the
-    merged outer and inner axes directly: einsum 'anb,amb->nm'.
-    """
-    x3 = _as3(x, mode)
-    return jax.lax.dot_general(
-        x3, x3,
-        dimension_numbers=(((0, 2), (0, 2)), ((), ())),
-        precision=precision,
-        preferred_element_type=jnp.float32 if x.dtype != jnp.float64 else None,
-    ).astype(jnp.promote_types(x.dtype, jnp.float32))
+    """S = Y_(n) Y_(n)^T  (I_n × I_n) without forming Y_(n): TTT with y ≡ x
+    (paper Sec. V)."""
+    return ttt(x, x, mode, precision=precision)
 
 
 def ttt(x: jax.Array, y: jax.Array, mode: int, *,
@@ -112,11 +90,10 @@ def ttt(x: jax.Array, y: jax.Array, mode: int, *,
     for m in range(x.ndim):
         if m != mode and x.shape[m] != y.shape[m]:
             raise ValueError(f"ttt: common mode {m} differs: {x.shape} vs {y.shape}")
-    x3 = _as3(x, mode)
-    y3 = _as3(y, mode)
+    other = tuple(m for m in range(x.ndim) if m != mode)
     return jax.lax.dot_general(
-        x3, y3,
-        dimension_numbers=(((0, 2), (0, 2)), ((), ())),
+        x, y,
+        dimension_numbers=((other, other), ((), ())),
         precision=precision,
         preferred_element_type=jnp.float32 if x.dtype != jnp.float64 else None,
     ).astype(jnp.promote_types(x.dtype, jnp.float32))

@@ -12,12 +12,14 @@ refuse."""
 
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import TuckerConfig, decompose, plan
+from repro.core import TuckerConfig, als_solve, decompose, plan
+from repro.core import tensor_ops as T
 from repro.core.selector import default_selector
 
 SHAPE = (64, 48, 2000)
@@ -127,3 +129,64 @@ def test_execute_and_plan_spans_carry_methods_and_als_passes():
     want = 11 * (3 * 10 * 40 + 3 * 3 * 40) / (12 * 10 * 40)
     assert spans["execute"]["als_passes"] == pytest.approx(want)
     assert p.als_passes == pytest.approx(want)
+
+
+def _input_reshapes(fn, y):
+    """``(inside a loop, new shape)`` for each ``reshape`` of an array of
+    ``y``'s size anywhere in ``fn``'s jaxpr: the views of the whole input."""
+    found = []
+
+    def walk(jaxpr, in_loop):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "reshape" and \
+                    math.prod(eqn.invars[0].aval.shape) == y.size:
+                found.append((in_loop, tuple(eqn.params["new_sizes"])))
+            loop = in_loop or eqn.primitive.name in ("scan", "while")
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, tuple) else (v,):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner, loop)
+
+    walk(jax.make_jaxpr(fn)(y).jaxpr, False)
+    return found
+
+
+#: axes that fill no (8, 128) tile
+VIEW_SHAPE = (6, 7, 45)
+
+
+@pytest.mark.parametrize("impl", ["matfree", "explicit", "pallas"])
+@pytest.mark.parametrize("mode", [0, 1, 2])
+def test_als_solve_factor_orthonormal_and_core_is_projection(impl, mode):
+    x = lowrank(2, VIEW_SHAPE, (3, 3, 3), NOISE)
+    u, core = als_solve(jnp.asarray(x), mode, 3, impl=impl)
+    u64 = np.asarray(u, np.float64)
+    np.testing.assert_allclose(u64.T @ u64, np.eye(3), atol=1e-5)
+    want = _ttm_t(np.asarray(x, np.float64), u64, mode)
+    assert np.linalg.norm(np.asarray(core, np.float64) - want) \
+        <= 1e-5 * np.linalg.norm(np.asarray(x, np.float64))
+
+
+@pytest.mark.parametrize("impl, mode", [
+    ("matfree", 0), ("matfree", 1), ("matfree", 2),
+    ("pallas", 0), ("pallas", 2), ("explicit", 0), ("explicit", 2),
+])
+def test_als_solve_makes_the_input_view_once_before_its_loop(impl, mode):
+    """Matfree contracts over the input's own axes and reshapes it
+    nowhere.  The Pallas kernels and the unfold baseline take the
+    (before, mode, after) view ((before, mode) for the last mode), made
+    once, before the loop; the kernels reshape the input nowhere else,
+    while the baseline unfolds in every contraction.  (Mode 1's view is
+    the input itself.)"""
+    y = jnp.zeros(VIEW_SHAPE, jnp.float32)
+    found = _input_reshapes(lambda y: als_solve(y, mode, 3, impl=impl), y)
+    if impl == "matfree":
+        assert found == []
+        return
+    a, n, b = T.split_dims(VIEW_SHAPE, mode)
+    view = (a, n) if b == 1 else (a, n, b)
+    assert [in_loop for in_loop, shape in found if shape == view] \
+        == [False], found
+    if impl == "pallas":
+        assert len(found) == 1, found

@@ -84,6 +84,38 @@ class TestGramTTT:
         np.testing.assert_allclose(T.gram(x, 1), T.ttt(x, x, 1), rtol=1e-5)
 
 
+#: axes that fill no (8, 128) tile, orders 3 and 4
+NATIVE_SHAPES = [(5, 7, 9), (5, 7, 9, 11)]
+NATIVE_CASES = [(shape, mode, op, dtype)
+                for shape in NATIVE_SHAPES for mode in range(len(shape))
+                for op in ("ttm", "ttt") for dtype in ("float32", "bfloat16")]
+
+
+@pytest.mark.parametrize("shape, mode, op, dtype", NATIVE_CASES)
+def test_native_axes_equal_explicit_unfold(shape, mode, op, dtype):
+    """TTM and TTT contract over the tensor's own axes, with no reshape to
+    a merged view, and equal the explicit unfold → GEMM → fold baseline."""
+    dtype = jnp.dtype(dtype)
+    x = rand(shape, 0, dtype)
+    if op == "ttm":
+        other = rand((4, shape[mode]), 1, dtype)
+        fn, ref = T.ttm, T.ttm_explicit
+    else:
+        other = rand(shape[:mode] + (4,) + shape[mode + 1:], 1, dtype)
+        fn, ref = T.ttt, T.ttt_explicit
+    got = fn(x, other, mode)
+    want = ref(x, other, mode)
+    assert got.shape == want.shape
+    assert "reshape" not in str(
+        jax.make_jaxpr(lambda a, b: fn(a, b, mode))(x, other))
+    # bf16 outputs round to 8 bits; the sums' order differs
+    tol = 2e-4 if dtype == jnp.float32 else 2e-2
+    scale = float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol * scale)
+
+
 class TestFoldReconstruct:
     @given(shape=shapes3, mode=st.integers(0, 2))
     def test_unfold_fold_roundtrip(self, shape, mode):

@@ -1,7 +1,8 @@
-"""The Pallas kernels compile for a TPU v5e chip at the paper's tensor
-sizes, and fit.  The chip is described, not attached (XLA's TPU compiler
-runs on the host), so this guards Mosaic lowering and the kernels' device
-memory on every run at no chip time.  Nothing is executed.
+"""The Pallas kernels and the matfree mode-0 contractions compile for a
+TPU v5e chip at the paper's tensor sizes, and fit.  The chip is
+described, not attached (XLA's TPU compiler runs on the host), so this
+guards Mosaic lowering and the programs' device memory on every run at no
+chip time.  Nothing is executed.
 
 Bound: a kernel's compiled scratch (``temp``) stays within twice the bytes
 it reads and writes, and the whole program fits one chip's 16 GB of HBM.
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import solvers, tensor_ops
 from repro.kernels import ops
 
 HBM_BYTES = 16 << 30
@@ -80,3 +82,25 @@ def test_kernel_compiles_and_fits(one_chip, name, mode, op):
     assert mem.temp_size_in_bytes <= 2 * io, (mem, io)
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES, mem
+
+
+#: (program, input shapes, bound on the compiled temp, in bytes): the
+#: mode-0 contractions on matfree, which read the input on its own axes.
+#: Each bound sits above the reading (2.27 GB: one hoisted relayout of the
+#: Boats input; 0) and below what a reshape to a merged view costs on the
+#: chip's tiled layout, a copy of the whole input (4.30 GB; 0.84 GB).
+MODE0 = {
+    "als_solve Boats": (lambda y: solvers.als_solve(y, 0, 10),
+                        [TENSORS["Boats"][0]], 2.4e9),
+    "ttm Cavity": (lambda x, u: tensor_ops.ttm(x, u, 0),
+                   [TENSORS["Cavity"][0], (32, 100)], 0.05e9),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MODE0))
+def test_matfree_mode0_reads_the_input_on_its_own_axes(one_chip, name):
+    fn, shapes, bound = MODE0[name]
+    compiled = jax.jit(fn).lower(*(_f32(s, one_chip) for s in shapes)) \
+        .compile()
+    assert "dynamic-update-slice" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= bound
