@@ -41,7 +41,7 @@ def fig2_solver_variants(full: bool = False):
         res = {}
         for name, fn in (("eig", sthosvd_eig), ("als", sthosvd_als),
                          ("svd", sthosvd_svd)):
-            t = time_call(lambda: fn(x, ranks, block_until_ready=True), reps=2)
+            t = time_call(lambda: fn(x, ranks), reps=2)
             res[name] = t
             emit(f"fig2/{name}/{'x'.join(map(str, dims))}", t,
                  f"ranks={ranks}")
@@ -74,7 +74,7 @@ def table3_realworld(full: bool = False, factor: float = 0.18):
         for mname, fn in (("eig", sthosvd_eig), ("als", sthosvd_als),
                           ("atucker", lambda x_, r_, **kw: sthosvd(
                               x_, r_, methods="auto", **kw))):
-            t = time_call(lambda: fn(x, r, block_until_ready=True),
+            t = time_call(lambda: fn(x, r),
                           reps=2, warmup=1)
             err = float(fn(x, r).tucker.rel_error(x))
             row[mname] = (t, err)
@@ -118,10 +118,10 @@ def fig5_adaptive_speedup(n_tensors: int = 20, max_dim: int = 200, seed=0):
         ranks = tuple(max(2, min(d // 2, int(np.exp(rng.uniform(np.log(2), np.log(d // 2 + 1))))))
                       for d in dims)
         x = lowrank_tensor(dims, ranks, noise=0.05, seed=i)
-        te = time_call(lambda: sthosvd_eig(x, ranks, block_until_ready=True), reps=2)
-        ta = time_call(lambda: sthosvd_als(x, ranks, block_until_ready=True), reps=2)
-        tad = time_call(lambda: sthosvd(x, ranks, methods="auto", selector=sel,
-                                        block_until_ready=True), reps=2)
+        te = time_call(lambda: sthosvd_eig(x, ranks), reps=2)
+        ta = time_call(lambda: sthosvd_als(x, ranks), reps=2)
+        tad = time_call(
+            lambda: sthosvd(x, ranks, methods="auto", selector=sel), reps=2)
         # beyond-paper: the same adaptive schedule via plan/execute (selector
         # out of the hot path) — tracked separately from the paper metric
         p = plan(x.shape, x.dtype, TuckerConfig(ranks=ranks), selector=sel)
@@ -151,7 +151,7 @@ def fig6_modewise_trace():
     for name, dims, ranks in (("air_like", (2048, 96, 6), (10, 10, 5)),
                               ("boats_like", (96, 72, 1400), (8, 8, 8))):
         x = lowrank_tensor(dims, ranks, noise=0.05)
-        res = sthosvd(x, ranks, methods="auto", block_until_ready=True)
+        res = sthosvd(x, ranks, methods="auto")
         best = []
         for t in res.trace:
             # exhaustive per-mode check is the paper's "Best" column;
@@ -200,8 +200,8 @@ def fig8_matfree(full: bool = False, factor: float = 0.18):
         # the backend axis: one timed row per ops backend (pallas rows join
         # on TPU / when forced — interpret mode isn't a perf signal)
         t_backend = {
-            impl: time_call(lambda: sthosvd(x, r, methods="eig", impl=impl,
-                                            block_until_ready=True), reps=2)
+            impl: time_call(lambda: sthosvd(x, r, methods="eig", impl=impl),
+                            reps=2)
             for impl in _bench_backends()}
         tm, te = t_backend["matfree"], t_backend["explicit"]
         for impl, t in t_backend.items():

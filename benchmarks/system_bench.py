@@ -54,7 +54,7 @@ def plan_bench(n_repeat: int = 8, batch: int = 8):
         p = plan(x.shape, x.dtype, cfg)
 
         t_percall = time_call(
-            lambda: sthosvd(x, ranks, methods="auto", block_until_ready=True),
+            lambda: sthosvd(x, ranks, methods="auto"),
             reps=n_repeat)
         t_plan = time_call(
             lambda: jax.block_until_ready(p.execute(x).tucker.core),

@@ -81,7 +81,7 @@ def main():
                       lambda x_, r_, **kw: sthosvd(x_, r_, methods="auto", **kw))):
         fn(x, ranks)                        # warm-up (compile)
         t0 = time.perf_counter()
-        r = fn(x, ranks, block_until_ready=True)
+        r = fn(x, ranks)
         dt = time.perf_counter() - t0
         print(f"{name:19s} {dt * 1e3:8.1f} ms   "
               f"rel_err={float(r.tucker.rel_error(x)):.4f}   "

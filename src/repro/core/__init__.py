@@ -4,7 +4,7 @@ Public API:
   TuckerConfig / plan / TuckerPlan / decompose — plan/execute front door
       (static solver schedules, cached jitted sweeps, batched execution)
   sthosvd / sthosvd_eig / sthosvd_als / sthosvd_svd — flexible st-HOSVD
-      (legacy per-call wrappers over the same schedule runner)
+      (legacy per-call adapters: plan, then execute(x, record=True))
   TuckerTensor — decomposition result (reconstruct, rel_error, ratio)
   Selector / default_selector — adaptive solver selector, resolved per
       (platform, backend); trained/calibrated by the repro.tune flywheel
@@ -14,7 +14,7 @@ Public API:
       backend_names — pluggable ops-backend registry (matfree | explicit |
       pallas | sharded | custom) behind TuckerConfig.impl
   distributed — mesh execution engine behind the ``sharded`` backend
-      (sthosvd_distributed legacy entry, pick_shard_mode, shard_map sweeps)
+      (sweep_sharded, pick_shard_mode, the sthosvd_distributed adapter)
 """
 
 # NOTE: the attribute ``repro.core.plan`` is the api.plan FUNCTION (the

@@ -56,13 +56,13 @@ from .errors import (CancelledError, DeadlineError, InputError,
                      NumericalError, ResourceError, TuckerError,
                      check_finite, check_result_finite, classify_exception)
 from .plan import (
-    ModeStep,
-    TimedSelector,
+    SWEEPS,
     VARIANTS,
+    ModeStep,
+    StepTimer,
+    TimedSelector,
     resolve_schedule,
-    sweep_hooi,
-    sweep_sthosvd,
-    sweep_thosvd,
+    solve_step,
 )
 from .solvers import DEFAULT_ALS_ITERS, DEFAULT_OVERSAMPLE, DEFAULT_POWER_ITERS
 from .sthosvd import ModeTrace, SthosvdResult, TuckerTensor
@@ -389,47 +389,47 @@ def clear_sweep_cache() -> None:
     CACHE_STATS.update(builds=0, hits=0, traces=0)
 
 
-def _make_sweep(p: "TuckerPlan", batched: bool, donate: bool = False) -> Callable:
-    steps = p.schedule   # each step carries its resolved ops backend
-    cfg = p.config
-    n_init = len(p.shape)  # HOOI: first full sweep is the st-HOSVD init
-    cdtype = jnp.dtype(cfg.compute_dtype) if cfg.compute_dtype else None
-
+def _plan_sweep(p: "TuckerPlan", timer: StepTimer | None = None) -> Callable:
+    """``p``'s sweep as a function of x: the variant's function from
+    :data:`repro.core.plan.SWEEPS` (or the mesh sweep) bound to the plan's
+    frozen schedule and options.  The compiled program traces it as is;
+    the recorded path runs it eagerly with ``timer`` as its solve."""
+    steps, cfg = p.schedule, p.config   # each step carries its ops backend
     if p.backend == "sharded":
-        # donation is guarded off for shard_map sweeps upstream
-        # (_resolve_donate); never build an aliasing program here
-        from .distributed import sweep_mode_parallel, sweep_sharded
+        from .distributed import solve_batch_sharded, sweep_sharded
         if cfg.mesh is None:
             raise RuntimeError(
                 "plan requires a mesh to execute its sharded schedule (the "
                 "loading process has too few devices to rebuild the plan's "
                 "mesh spec, or the config lost its mesh); re-plan with "
                 "TuckerConfig(mesh=...) on a large enough host")
-        if batched:
-            raise RuntimeError("sharded sweeps do not vmap; execute_batch "
-                               "runs sharded plans item by item")
-        mesh, axis = cfg.mesh, cfg.resolved_shard_axis
-        run = sweep_mode_parallel \
-            if any(s.group is not None for s in steps) else sweep_sharded
+        solve = solve_batch_sharded if timer is None else timer.sharded
+        return lambda x: sweep_sharded(
+            x, steps, mesh=cfg.mesh, axis=cfg.resolved_shard_axis,
+            als_iters=cfg.als_iters, solve=solve)
+    run = SWEEPS[cfg.variant]
+    solve = solve_step if timer is None else timer
+    return lambda x: run(x, steps, als_iters=cfg.als_iters, solve=solve)
 
-        def sweep(x):
-            CACHE_STATS["traces"] += 1
-            if cdtype is not None:
-                x = x.astype(cdtype)
-            return run(x, steps, mesh=mesh, axis=axis,
-                       als_iters=cfg.als_iters)
 
-        return jax.jit(sweep)
+def _make_sweep(p: "TuckerPlan", batched: bool, donate: bool = False) -> Callable:
+    cdtype = jnp.dtype(p.config.compute_dtype) if p.config.compute_dtype \
+        else None
+    run = _plan_sweep(p)
 
     def sweep(x):
         CACHE_STATS["traces"] += 1
         if cdtype is not None:
             x = x.astype(cdtype)
-        if cfg.variant == "sthosvd":
-            return sweep_sthosvd(x, steps, als_iters=cfg.als_iters)
-        if cfg.variant == "thosvd":
-            return sweep_thosvd(x, steps, als_iters=cfg.als_iters)
-        return sweep_hooi(x, steps, als_iters=cfg.als_iters, n_init=n_init)
+        return run(x)
+
+    if p.backend == "sharded":
+        # donation is guarded off for shard_map sweeps upstream
+        # (_resolve_donate); never build an aliasing program here
+        if batched:
+            raise RuntimeError("sharded sweeps do not vmap; execute_batch "
+                               "runs sharded plans item by item")
+        return jax.jit(sweep)
 
     jitted = jax.jit(jax.vmap(sweep) if batched else sweep,
                      donate_argnums=(0,) if donate else ())
@@ -661,11 +661,12 @@ class TuckerPlan:
         """Run the frozen schedule on ``x`` as one compiled program.
 
         ``record=True`` (or an active :func:`repro.tune.recording` context)
-        switches to the eager per-step runner so every mode solve gets real
-        wall-clock in its trace — the traces then feed the autotune
-        measurement store (predicted-vs-actual per step, and free training
-        records from production traffic).  Sharded plans have no eager
-        per-step path and reject ``record=True``.
+        runs the same sweep function eagerly, step by step, so every mode
+        solve gets real wall-clock in its trace — the traces then feed the
+        autotune measurement store (predicted-vs-actual per step, and free
+        training records from production traffic).  Sharded plans record
+        too: a mode-parallel group is timed as one unit, its wall-clock
+        split evenly over its members.
 
         ``donate`` overrides ``config.donate_input`` for this call: ``True``
         donates ``x``'s buffer into the sweep (``x`` is CONSUMED — deleted
@@ -750,14 +751,8 @@ class TuckerPlan:
             # sys.modules probe: plans that never meet repro.tune pay nothing
             tune = sys.modules.get("repro.tune")
             sink = tune.active_sink() if tune is not None else None
-            if (record or sink is not None) and p.backend != "sharded":
+            if record or sink is not None:
                 return p._execute_recorded(x, sink)
-            if record:   # sharded + explicit record: fail loud, not silent
-                raise ValueError(
-                    "record=True needs the eager per-step runner, which "
-                    "sharded plans do not have (the shard_map sweep is one "
-                    "program); collect sharded measurements via "
-                    "sthosvd_distributed")
             donate_now = p._resolve_donate(created=created,
                                            override=donate_override)
             _chaos.fire("sweep", backend=p.backend)
@@ -780,69 +775,23 @@ class TuckerPlan:
         return _run_with_fallback(self, can_retry, run, donate)
 
     def _execute_recorded(self, x: jax.Array, sink=None) -> SthosvdResult:
-        """Eager mirror of the fused sweeps with per-step wall-clock; feeds
-        the active tune sink (if any) so executed plans become training
-        records."""
-        from . import tensor_ops as T
-        from .plan import run_schedule, solve_step
+        """The plan's own sweep run eagerly with a :class:`StepTimer` as its
+        solve: per-step wall-clock in the trace; feeds the active tune sink
+        (if any) so executed plans become training records."""
         cfg = self.config
         if cfg.compute_dtype:
             x = x.astype(jnp.dtype(cfg.compute_dtype))
-        steps = self.schedule
-        n = len(self.shape)
-        if cfg.variant == "sthosvd":
-            core, fdict, seconds = run_schedule(
-                x, steps, sequential=True, als_iters=cfg.als_iters,
-                block_until_ready=True)
-            factors = [fdict[m] for m in range(n)]
-        elif cfg.variant == "thosvd":
-            _, fdict, seconds = run_schedule(
-                x, steps, sequential=False, als_iters=cfg.als_iters,
-                block_until_ready=True)
-            factors = [fdict[m] for m in range(n)]
-            core = x
-            for mode, u in enumerate(factors):
-                core = T.ttm(core, u.T, mode)
-        else:  # hooi: timed init sweep, then timed projected refinements
-            import time as _time
-            core, fdict, seconds = run_schedule(
-                x, steps[:n], sequential=True, als_iters=cfg.als_iters,
-                block_until_ready=True)
-            factors = [fdict[m] for m in range(n)]
-            seconds = list(seconds)
-            platform = jax.default_backend()
-            for step in steps[n:]:
-                y = x
-                for m, u in enumerate(factors):
-                    if m != step.mode:
-                        y = T.ttm(y, u.T, m)
-                with _obs.span("solve", mode=step.mode, solver=step.method,
-                               backend=step.backend, platform=platform,
-                               rank=step.r_n, i_n=step.i_n, j_n=step.j_n,
-                               predicted_s=step.predicted_s):
-                    t0 = _time.perf_counter()
-                    res = solve_step(y, step, als_iters=cfg.als_iters)
-                    jax.block_until_ready(res.u)
-                    dt = _time.perf_counter() - t0
-                seconds.append(dt)
-                _drift.MONITOR.observe(platform=platform,
-                                       backend=step.backend,
-                                       solver=step.method,
-                                       predicted_s=step.predicted_s,
-                                       actual_s=dt, source="execute")
-                factors[step.mode] = res.u
-            core = x
-            for mode, u in enumerate(factors):
-                core = T.ttm(core, u.T, mode)
+        timer = StepTimer()
+        core, factors = _plan_sweep(self, timer)(self._place_input(x))
         trace = [ModeTrace(s.mode, s.method, s.i_n, s.r_n, s.j_n, dt,
                            backend=s.backend, predicted_s=s.predicted_s)
-                 for s, dt in zip(steps, seconds)]
+                 for s, dt in zip(self.schedule, timer.seconds)]
         if sink is not None:
-            sink.add_traces(trace, platform=jax.default_backend(),
+            sink.add_traces(trace, platform=timer.platform,
                             dtype=cfg.compute_dtype or self.dtype,
-                            order=n, als_iters=cfg.als_iters)
+                            order=len(self.shape), als_iters=cfg.als_iters)
         return SthosvdResult(
-            tucker=TuckerTensor(core=core, factors=factors),
+            tucker=TuckerTensor(core=core, factors=list(factors)),
             trace=trace, select_overhead_s=0.0)
 
     def resolve_ranks(self, x: jax.Array) -> tuple[tuple[int, ...], float]:

@@ -22,24 +22,22 @@ reshards land) are frozen at plan time by
 :func:`repro.core.plan.resolve_schedule` via :func:`pick_shard_mode`; this
 module only executes frozen :class:`~repro.core.plan.ModeStep` schedules:
 
-  * :func:`run_sharded_schedule` — eager per-step runner with real per-mode
-    wall-clock (the legacy :func:`sthosvd_distributed` entry point).
-  * :func:`sweep_sharded` — the same schedule as one pure function, compiled
+  * :func:`sweep_sharded` — the schedule as one pure function, compiled
     whole by ``TuckerPlan``'s process-wide sweep cache (zero recompiles on
-    plan reuse, exactly like the single-device backends).
-  * :func:`sweep_mode_parallel` — the group-aware sweep for schedules whose
-    steps carry ``group`` ids: every member of a group computes its factor
-    from the SAME un-shrunk tensor (all eig Grams fused into ONE shard_map
-    with one psum each — one mesh barrier for the whole group instead of
-    one per mode), then a single fused multi-TTM truncates all group modes
-    at once.  Lower latency, more FLOPs; the plan-time DP
-    (:mod:`repro.core.schedule_opt`) decides when that trade wins.
+    plan reuse, exactly like the single-device backends), and run eagerly
+    with a :class:`~repro.core.plan.StepTimer` by the recorded path
+    (``execute(record=True)``, :func:`sthosvd_distributed`).  Steps that
+    carry ``group`` ids run as mode-parallel groups: every member of a
+    group computes its factor from the SAME un-shrunk tensor (all eig
+    Grams fused into ONE shard_map with one psum each — one mesh barrier
+    for the whole group instead of one per mode), then a single fused
+    multi-TTM truncates all group modes at once.  Lower latency, more
+    FLOPs; the plan-time DP (:mod:`repro.core.schedule_opt`) decides when
+    that trade wins.
 """
 
 from __future__ import annotations
 
-import time
-from contextlib import nullcontext
 from functools import lru_cache
 
 import jax
@@ -48,12 +46,10 @@ from jax.core import Tracer
 from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..obs import drift as _drift
-from ..obs import trace as _obs
 from . import tensor_ops as T
 from .plan import ModeStep, solve_step, step_scope
 from .solvers import DEFAULT_ALS_ITERS, als_solve
-from .sthosvd import ModeTrace, SthosvdResult, TuckerTensor
+from .sthosvd import SthosvdResult, _run_legacy
 
 
 def _spec_for(ndim: int, mode: int | None, axis: str) -> P:
@@ -196,7 +192,7 @@ def pick_shard_mode_group(shape: tuple[int, ...], exclude,
 
 
 # ---------------------------------------------------------------------------
-# Frozen-schedule execution (shared by the plan layer and the legacy entry)
+# Frozen-schedule execution (the compiled sweep and the recorded path)
 # ---------------------------------------------------------------------------
 
 def _eig_u(s: jax.Array, r_n: int, dtype) -> jax.Array:
@@ -212,8 +208,8 @@ def solve_step_sharded(y: jax.Array, step: ModeStep, mesh: Mesh, axis: str,
     shard mode, then run its solver's collective schedule.  Returns
     ``(u, y_new)`` with ``y_new`` sharded on ``step.shard_mode``.
 
-    Works both eagerly (``run_sharded_schedule``) and under an enclosing jit
-    trace (``sweep_sharded``): resharding becomes a device_put or a GSPMD
+    Works both eagerly (the recorded path) and under an enclosing jit
+    trace (the compiled sweep): resharding becomes a device_put or a GSPMD
     constraint accordingly.
     """
     n = y.ndim
@@ -287,103 +283,43 @@ def solve_group_sharded(y: jax.Array, group, mesh: Mesh, axis: str, *,
     return factors, y
 
 
-def _solve_attrs(batch, platform: str) -> dict:
-    """Attributes of the ``solve`` span around one sharded step, or around
-    a mode-parallel group as a whole (its modes, their shared solver or
-    ``"mixed"``, and the sum of their predictions)."""
-    s = batch[0]
-    attrs = dict(solver=s.method, backend="sharded", platform=platform,
-                 n_shards=s.n_shards, group=s.group)
+def solve_batch_sharded(y: jax.Array, batch, mesh: Mesh, axis: str, *,
+                        als_iters: int = DEFAULT_ALS_ITERS):
+    """One execution group of a sharded schedule (see
+    :func:`repro.core.plan.iter_groups`): a sequential singleton through
+    :func:`solve_step_sharded`, a mode-parallel group through
+    :func:`solve_group_sharded`.  Returns ``(factors, y_new)`` with
+    ``factors`` keyed by mode."""
     if len(batch) == 1:
-        return dict(attrs, mode=s.mode, rank=s.r_n, i_n=s.i_n, j_n=s.j_n,
-                    predicted_s=s.predicted_s)
-    methods = {b.method for b in batch}
-    return dict(attrs, modes=[b.mode for b in batch],
-                solver=methods.pop() if len(methods) == 1 else "mixed",
-                predicted_s=sum(b.predicted_s for b in batch))
+        u, y = solve_step_sharded(y, batch[0], mesh, axis,
+                                  als_iters=als_iters)
+        return {batch[0].mode: u}, y
+    return solve_group_sharded(y, batch, mesh, axis, als_iters=als_iters)
 
 
-def run_sharded_schedule(x: jax.Array, steps, mesh: Mesh, axis: str, *,
-                         als_iters: int = DEFAULT_ALS_ITERS,
-                         block_until_ready: bool = True):
-    """Eager runner: per-step execution with real wall-clock per mode.
-
-    Mode-parallel groups run as one unit; their wall-clock is attributed
-    evenly across the members so ``seconds`` stays index-aligned with
-    ``steps``.  Returns ``(y, factors, seconds)`` like
-    :func:`repro.core.plan.run_schedule` (``factors`` keyed by mode).
-    """
-    from .plan import iter_groups
-    y = x
-    factors: dict[int, jax.Array] = {}
-    seconds: list[float] = []
-    platform = jax.default_backend()
-    for batch in iter_groups(steps):
-        with (_obs.span("solve", **_solve_attrs(batch, platform))
-              if block_until_ready else nullcontext()):
-            t0 = time.perf_counter()
-            if len(batch) == 1:
-                u, y = solve_step_sharded(y, batch[0], mesh, axis,
-                                          als_iters=als_iters)
-                factors[batch[0].mode] = u
-            else:
-                fs, y = solve_group_sharded(y, batch, mesh, axis,
-                                            als_iters=als_iters)
-                factors.update(fs)
-            if block_until_ready:
-                jax.block_until_ready(y)
-            dt = time.perf_counter() - t0
-        seconds.extend([dt / len(batch)] * len(batch))
-        if block_until_ready:
-            for s in batch:
-                # group wall-clock attributed evenly, matching ``seconds``
-                _drift.MONITOR.observe(platform=platform, backend="sharded",
-                                       solver=s.method,
-                                       predicted_s=s.predicted_s,
-                                       actual_s=dt / len(batch),
-                                       source="execute")
-    return y, factors, seconds
-
-
-def sweep_sharded(x, steps, *, mesh: Mesh, axis: str, als_iters: int):
+def sweep_sharded(x, steps, *, mesh: Mesh, axis: str, als_iters: int,
+                  solve=solve_batch_sharded):
     """The whole sharded sweep as one pure function, jit-compiled by
     ``TuckerPlan`` — inner shard_maps and sharding constraints inline into a
-    single XLA program with the reshard collectives at the frozen points."""
-    y = x
-    factors: dict[int, jax.Array] = {}
-    for step in steps:
-        with step_scope(step):
-            u, y = solve_step_sharded(y, step, mesh, axis,
-                                      als_iters=als_iters)
-        factors[step.mode] = u
-    return y, [factors[m] for m in range(x.ndim)]
-
-
-def sweep_mode_parallel(x, steps, *, mesh: Mesh, axis: str, als_iters: int):
-    """Group-aware whole-sweep: like :func:`sweep_sharded` but schedules
-    carrying ``group`` ids run each group through
-    :func:`solve_group_sharded` (concurrent Grams, one fused multi-TTM).
-    Pure — compiled by the same ``TuckerPlan`` sweep cache, so repeated
-    execution of a mode-parallel plan stays zero-recompile."""
+    single XLA program with the reshard collectives at the frozen points.
+    Steps carrying ``group`` ids run each group as one unit (concurrent
+    Grams, one fused multi-TTM).  ``solve`` runs one group; the recorded
+    path passes :meth:`repro.core.plan.StepTimer.sharded` and runs this
+    function eagerly."""
     from .plan import iter_groups
     y = x
     factors: dict[int, jax.Array] = {}
     for batch in iter_groups(steps):
-        if len(batch) == 1:
-            with step_scope(batch[0]):
-                u, y = solve_step_sharded(y, batch[0], mesh, axis,
-                                          als_iters=als_iters)
-            factors[batch[0].mode] = u
-        else:
-            with jax.named_scope(f"group{batch[0].group}"):
-                fs, y = solve_group_sharded(y, batch, mesh, axis,
-                                            als_iters=als_iters)
-            factors.update(fs)
+        scope = step_scope(batch[0]) if len(batch) == 1 \
+            else jax.named_scope(f"group{batch[0].group}")
+        with scope:
+            fs, y = solve(y, batch, mesh, axis, als_iters=als_iters)
+        factors.update(fs)
     return y, [factors[m] for m in range(x.ndim)]
 
 
 # ---------------------------------------------------------------------------
-# Legacy entry point — thin wrapper over the shared schedule machinery
+# Legacy entry point — an adapter over plan/execute
 # ---------------------------------------------------------------------------
 
 def sthosvd_distributed(
@@ -398,7 +334,6 @@ def sthosvd_distributed(
     mode_order=None,
     memory_cap_bytes: int | None = None,
     mode_parallel: str | int = "off",
-    block_until_ready: bool = True,
 ) -> SthosvdResult:
     """Distributed flexible st-HOSVD.  ``methods``: 'eig' | 'als' | 'auto'.
 
@@ -409,35 +344,13 @@ def sthosvd_distributed(
     ``mode_parallel`` ("off" | "auto" | int) opts steps into concurrent
     mode-parallel groups — see :func:`repro.core.plan.resolve_schedule`.
 
-    Thin wrapper over the shared plan machinery: the per-mode solver AND
-    shard-mode schedule is resolved ahead of time
-    (:func:`repro.core.plan.resolve_schedule` with ``backend="sharded"``),
-    then run eagerly with real per-mode wall-clock in the trace — exactly
-    how :func:`repro.core.sthosvd.sthosvd` wraps the single-device runner.
-    For amortized/batched execution build a plan instead:
-    ``plan(shape, dtype, TuckerConfig(..., impl="sharded", mesh=mesh))``.
+    An adapter over ``plan(shape, dtype, TuckerConfig(..., impl="sharded",
+    mesh=mesh)).execute(x, record=True)``: per-mode wall-clock in the
+    trace, selector time as ``select_overhead_s``.
     """
-    from .plan import TimedSelector, resolve_schedule
-
-    timed = None
-    if methods == "auto":
-        if selector is None:
-            from .selector import default_selector
-            selector = default_selector()
-        selector = timed = TimedSelector(selector)
-    schedule = resolve_schedule(
-        x.shape, ranks, variant="sthosvd", methods=methods, selector=selector,
-        mode_order=mode_order, als_iters=als_iters,
-        itemsize=x.dtype.itemsize, backend="sharded",
-        n_shards=mesh.shape[axis], memory_cap_bytes=memory_cap_bytes,
-        mode_parallel=mode_parallel)
-
-    y, factors, seconds = run_sharded_schedule(
-        x, schedule, mesh, axis, als_iters=als_iters,
-        block_until_ready=block_until_ready)
-    trace = [ModeTrace(s.mode, s.method, s.i_n, s.r_n, s.j_n, dt,
-                       backend=s.backend, predicted_s=s.predicted_s)
-             for s, dt in zip(schedule, seconds)]
-    tucker = TuckerTensor(core=y, factors=[factors[m] for m in range(x.ndim)])
-    return SthosvdResult(tucker=tucker, trace=trace,
-                         select_overhead_s=timed.seconds if timed else 0.0)
+    from .api import TuckerConfig
+    return _run_legacy(x, TuckerConfig(
+        ranks=ranks, methods=methods, mode_order=mode_order, impl="sharded",
+        mesh=mesh, shard_axis=axis, als_iters=als_iters,
+        memory_cap_bytes=memory_cap_bytes, mode_parallel=mode_parallel),
+        selector)

@@ -1,7 +1,7 @@
 """Failure taxonomy for the a-Tucker stack: every failure classified.
 
-The execution layers (``plan.execute``, the serve waves, the eager
-runners) raise — or wrap foreign exceptions into — one hierarchy rooted at
+The execution layers (``plan.execute`` compiled or recorded, the serve
+waves) raise — or wrap foreign exceptions into — one hierarchy rooted at
 :class:`TuckerError`, so callers can catch by failure CLASS instead of
 pattern-matching XLA message strings:
 

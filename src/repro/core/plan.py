@@ -13,16 +13,16 @@ modeled FLOPs (cost_model Eq. 4/5) and peak working-set bytes — which is
   * fully static, so an entire sweep can be compiled as ONE jitted program
     and vmapped over a batch axis (see :mod:`repro.core.api`).
 
-``run_schedule`` is the eager per-step runner used by the legacy entry
-points (per-mode wall-clock in the trace); the ``sweep_*`` builders express
-the same schedules as pure functions for whole-program jit.
+Each variant is defined once, as a ``sweep_*`` pure function (the
+:data:`SWEEPS` table).  The plan compiles it whole; the recorded path runs
+the same function unjitted with a :class:`StepTimer` as its per-step solve,
+which gives every mode solve its own span and wall-clock in the trace.
 """
 
 from __future__ import annotations
 
 import math
 import time
-from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Callable, Sequence
 
@@ -36,7 +36,7 @@ from .backend import get_backend
 from .cost_model import als_flops, eig_flops, rand_flops, svd_flops
 from .errors import NumericalError
 from .solvers import (ALS, DEFAULT_ALS_ITERS, DEFAULT_OVERSAMPLE,
-                      DEFAULT_POWER_ITERS, RAND, SOLVERS)
+                      DEFAULT_POWER_ITERS, RAND, SOLVERS, SolveResult)
 
 VARIANTS = ("sthosvd", "thosvd", "hooi")
 
@@ -367,7 +367,6 @@ def resolve_schedule(
     selector: Callable[..., str] | None = None,
     als_iters: int = DEFAULT_ALS_ITERS,
     hooi_iters: int = 3,
-    include_init: bool = True,
     itemsize: int = 4,
     backend: str = "matfree",
     n_shards: int = 1,
@@ -379,8 +378,7 @@ def resolve_schedule(
 
     Every (I_n, R_n, J_n) triple a runtime selector would have seen is
     derived from ``shape``/``ranks`` alone, so selection runs zero times at
-    execute time.  For HOOI, ``include_init=False`` drops the st-HOSVD init
-    sweep (caller supplies its own initial factors).
+    execute time.  A HOOI schedule starts with its st-HOSVD init sweep.
 
     ``itemsize`` is the byte width of the *compute* dtype (callers derive it
     from ``TuckerConfig.compute_dtype`` or the input dtype — never assume 4)
@@ -499,74 +497,73 @@ def resolve_schedule(
 
     # st-HOSVD sweep (also HOOI's init): the tensor shrinks between steps
     # (or between GROUPS when mode_parallel opens one)
-    if variant == "sthosvd" or include_init:
-        if n_shards > 1:
-            from .distributed import pick_shard_mode
-        flat_methods: list | None
-        if mp == "auto":
-            # the planner prices sequential-vs-parallel per input: joint
-            # subset DP when the order is searched too, grouping search
-            # along the fixed order otherwise
-            from .schedule_opt import optimize_grouping, optimize_schedule
-            if mode_order == "opt":
-                search = optimize_schedule(
-                    shape, ranks, methods=fixed, als_iters=als_iters,
-                    itemsize=itemsize, n_shards=n_shards,
-                    cost_model=cost_model,
-                    memory_cap_bytes=memory_cap_bytes, max_group=n)
-            else:
-                search = optimize_grouping(
-                    shape, ranks,
-                    tuple(resolve_mode_order(shape, ranks, mode_order)),
-                    methods=fixed, als_iters=als_iters, itemsize=itemsize,
-                    n_shards=n_shards, cost_model=cost_model,
-                    memory_cap_bytes=memory_cap_bytes)
-            groups = list(search.groups)
-            flat_methods = list(search.methods)
+    if n_shards > 1:
+        from .distributed import pick_shard_mode
+    flat_methods: list | None
+    if mp == "auto":
+        # the planner prices sequential-vs-parallel per input: joint
+        # subset DP when the order is searched too, grouping search
+        # along the fixed order otherwise
+        from .schedule_opt import optimize_grouping, optimize_schedule
+        if mode_order == "opt":
+            search = optimize_schedule(
+                shape, ranks, methods=fixed, als_iters=als_iters,
+                itemsize=itemsize, n_shards=n_shards,
+                cost_model=cost_model,
+                memory_cap_bytes=memory_cap_bytes, max_group=n)
         else:
-            if mode_order == "opt":
-                from .schedule_opt import optimize_schedule
-                search = optimize_schedule(
-                    shape, ranks, methods=fixed, als_iters=als_iters,
-                    itemsize=itemsize, n_shards=n_shards,
-                    cost_model=cost_model,
-                    memory_cap_bytes=memory_cap_bytes)
-                order, flat_methods = list(search.order), list(search.methods)
-            else:
-                order = resolve_mode_order(shape, ranks, mode_order)
-                flat_methods = None
-            if mp == "off":
-                groups = [(m,) for m in order]
-            else:   # int G >= 2: fixed strategy — leading group, rest sequential
-                g_lead = min(int(mp), n)
-                groups = [tuple(order[:g_lead])] + [(m,) for m in order[g_lead:]]
-        cur = list(shape)
-        pos = 0
-        gid = 0
-        for g in groups:
-            if len(g) == 1:
-                mode = g[0]
-                i_n, r_n = cur[mode], ranks[mode]
-                j_n = math.prod(cur) // i_n
-                shard = pick_shard_mode(tuple(cur), mode, n_shards) \
-                    if n_shards > 1 else None
-                method = flat_methods[pos] if flat_methods is not None \
-                    else method_for(mode)
-                steps.append(_make_step(mode, method, selector,
-                                        i_n, r_n, j_n, als_iters, itemsize,
-                                        backend, n_shards, shard,
-                                        cost_model=cost_model))
-                cur[mode] = r_n
-            else:
-                meths_g = [flat_methods[pos + i] if flat_methods is not None
-                           else method_for(m) for i, m in enumerate(g)]
-                steps.extend(_make_group_steps(
-                    g, gid, cur, ranks, meths_g, selector, als_iters,
-                    itemsize, backend, n_shards, cost_model))
-                for m in g:
-                    cur[m] = ranks[m]
-                gid += 1
-            pos += len(g)
+            search = optimize_grouping(
+                shape, ranks,
+                tuple(resolve_mode_order(shape, ranks, mode_order)),
+                methods=fixed, als_iters=als_iters, itemsize=itemsize,
+                n_shards=n_shards, cost_model=cost_model,
+                memory_cap_bytes=memory_cap_bytes)
+        groups = list(search.groups)
+        flat_methods = list(search.methods)
+    else:
+        if mode_order == "opt":
+            from .schedule_opt import optimize_schedule
+            search = optimize_schedule(
+                shape, ranks, methods=fixed, als_iters=als_iters,
+                itemsize=itemsize, n_shards=n_shards,
+                cost_model=cost_model,
+                memory_cap_bytes=memory_cap_bytes)
+            order, flat_methods = list(search.order), list(search.methods)
+        else:
+            order = resolve_mode_order(shape, ranks, mode_order)
+            flat_methods = None
+        if mp == "off":
+            groups = [(m,) for m in order]
+        else:   # int G >= 2: fixed strategy — leading group, rest sequential
+            g_lead = min(int(mp), n)
+            groups = [tuple(order[:g_lead])] + [(m,) for m in order[g_lead:]]
+    cur = list(shape)
+    pos = 0
+    gid = 0
+    for g in groups:
+        if len(g) == 1:
+            mode = g[0]
+            i_n, r_n = cur[mode], ranks[mode]
+            j_n = math.prod(cur) // i_n
+            shard = pick_shard_mode(tuple(cur), mode, n_shards) \
+                if n_shards > 1 else None
+            method = flat_methods[pos] if flat_methods is not None \
+                else method_for(mode)
+            steps.append(_make_step(mode, method, selector,
+                                    i_n, r_n, j_n, als_iters, itemsize,
+                                    backend, n_shards, shard,
+                                    cost_model=cost_model))
+            cur[mode] = r_n
+        else:
+            meths_g = [flat_methods[pos + i] if flat_methods is not None
+                       else method_for(m) for i, m in enumerate(g)]
+            steps.extend(_make_group_steps(
+                g, gid, cur, ranks, meths_g, selector, als_iters,
+                itemsize, backend, n_shards, cost_model))
+            for m in g:
+                cur[m] = ranks[m]
+            gid += 1
+        pos += len(g)
     if variant == "sthosvd":
         return _capped(tuple(steps))
 
@@ -584,7 +581,7 @@ def resolve_schedule(
 
 
 # ---------------------------------------------------------------------------
-# Single solver dispatch + runners
+# Single solver dispatch + the timed solve of the recorded path
 # ---------------------------------------------------------------------------
 
 def solve_step(y: jax.Array, step: ModeStep, *, als_iters: int = DEFAULT_ALS_ITERS,
@@ -607,62 +604,88 @@ def solve_step(y: jax.Array, step: ModeStep, *, als_iters: int = DEFAULT_ALS_ITE
     return SOLVERS[step.method](y, step.mode, step.r_n, impl=impl)
 
 
-def run_schedule(x: jax.Array, steps: Sequence[ModeStep], *,
-                 sequential: bool, als_iters: int = DEFAULT_ALS_ITERS,
-                 oversample: int = DEFAULT_OVERSAMPLE,
-                 power_iters: int = DEFAULT_POWER_ITERS,
-                 impl: str | None = None, block_until_ready: bool = False):
-    """Eager runner: per-mode jitted solves with wall-clock per step.
+def _solve_attrs(batch: Sequence[ModeStep], backend: str,
+                 platform: str) -> dict:
+    """Attributes of the ``solve`` span around one step, or around a
+    mode-parallel group as a whole (its modes, their shared solver or
+    ``"mixed"``, and the sum of their predictions)."""
+    s = batch[0]
+    attrs = dict(solver=s.method, backend=backend, platform=platform)
+    if backend == "sharded":
+        attrs.update(n_shards=s.n_shards, group=s.group)
+    if len(batch) == 1:
+        return dict(attrs, mode=s.mode, rank=s.r_n, i_n=s.i_n, j_n=s.j_n,
+                    predicted_s=s.predicted_s)
+    methods = {b.method for b in batch}
+    return dict(attrs, modes=[b.mode for b in batch],
+                solver=methods.pop() if len(methods) == 1 else "mixed",
+                predicted_s=sum(b.predicted_s for b in batch))
 
-    ``sequential=True`` threads the shrinking tensor through the steps
-    (st-HOSVD); ``sequential=False`` solves every step against ``x`` itself
-    (t-HOSVD factors, HOOI inner solves on pre-projected tensors).
 
-    Returns ``(y_or_none, factors, seconds)`` where ``factors[mode]`` is the
-    LAST factor computed for that mode and ``seconds[k]`` is step k's wall
-    time.
+class StepTimer:
+    """The timed solve a sweep runs with when it runs eagerly.
+
+    Passed as a sweep's ``solve`` (``solve=timer`` to the single-device
+    sweeps, ``solve=timer.sharded`` to
+    :func:`repro.core.distributed.sweep_sharded`), it turns the unjitted
+    sweep into the recorded path: each step, or each mode-parallel group as
+    one unit, runs inside a ``solve`` span with the ``solve`` chaos seam
+    and the ``solve_out`` poison, is waited on, has its factors checked
+    for non-finite values (a :class:`NumericalError` naming the step), and
+    feeds the drift monitor.  ``seconds`` gets one entry per step, a
+    group's wall-clock split evenly over its members, so it stays
+    index-aligned with the schedule.
     """
-    y = x
-    factors: dict[int, jax.Array] = {}
-    seconds: list[float] = []
-    platform = jax.default_backend()
-    for step in steps:
-        # the eager per-step path is the only place a mode solve has real
-        # wall-clock: a blocking run spans each solve and feeds
-        # predicted-vs-actual drift
-        with (_obs.span("solve", mode=step.mode, solver=step.method,
-                        backend=impl or step.backend, platform=platform,
-                        rank=step.r_n, i_n=step.i_n, j_n=step.j_n,
-                        predicted_s=step.predicted_s)
-              if block_until_ready else nullcontext()):
+
+    def __init__(self):
+        self.seconds: list[float] = []
+        self.platform = jax.default_backend()
+
+    def __call__(self, y: jax.Array, step: ModeStep, *,
+                 impl: str | None = None, **kw) -> SolveResult:
+        """:func:`solve_step`, timed."""
+        def run():
+            res = solve_step(y, step, impl=impl, **kw)
+            return {step.mode: res.u}, res.y_new
+
+        factors, y_new = self._timed([step], impl or step.backend, run)
+        return SolveResult(factors[step.mode], y_new)
+
+    def sharded(self, y: jax.Array, batch, mesh, axis: str, *,
+                als_iters: int):
+        """:func:`repro.core.distributed.solve_batch_sharded`, timed."""
+        from .distributed import solve_batch_sharded
+        return self._timed(batch, "sharded", lambda: solve_batch_sharded(
+            y, batch, mesh, axis, als_iters=als_iters))
+
+    def _timed(self, batch, backend: str, run):
+        with _obs.span("solve", **_solve_attrs(batch, backend, self.platform)):
             t0 = time.perf_counter()
-            _chaos.fire("solve", mode=step.mode, method=step.method)
-            res = solve_step(y if sequential else x, step,
-                             als_iters=als_iters, oversample=oversample,
-                             power_iters=power_iters, impl=impl)
-            if _chaos.active() and _chaos.poison("solve_out", mode=step.mode):
-                res = res._replace(u=res.u * float("nan"))
-            if block_until_ready:
-                jax.block_until_ready(res.y_new)
+            for s in batch:
+                _chaos.fire("solve", mode=s.mode, method=s.method)
+            factors, y = run()
+            if _chaos.active():
+                factors = {m: u * float("nan")
+                           if _chaos.poison("solve_out", mode=m) else u
+                           for m, u in factors.items()}
+            jax.block_until_ready((factors, y))
             dt = time.perf_counter() - t0
-        if block_until_ready:
+        for s in batch:
             # a breakdown that slipped past the in-solver guards (e.g. a
             # non-finite Gram) shows up here as NaN factors — surface it
             # as a classified error naming the step, not as silent poison
-            if not bool(jax.numpy.all(jax.numpy.isfinite(res.u))):
+            if not bool(jax.numpy.all(jax.numpy.isfinite(factors[s.mode]))):
                 raise NumericalError(
-                    f"{step.method} solve on mode {step.mode} produced a "
+                    f"{s.method} solve on mode {s.mode} produced a "
                     "non-finite factor (numerical breakdown)")
-            _drift.MONITOR.observe(platform=platform,
-                                   backend=impl or step.backend,
-                                   solver=step.method,
-                                   predicted_s=step.predicted_s,
-                                   actual_s=dt, source="execute")
-        seconds.append(dt)
-        factors[step.mode] = res.u
-        if sequential:
-            y = res.y_new
-    return (y if sequential else None), factors, seconds
+        share = dt / len(batch)
+        for s in batch:
+            _drift.MONITOR.observe(platform=self.platform, backend=backend,
+                                   solver=s.method,
+                                   predicted_s=s.predicted_s,
+                                   actual_s=share, source="execute")
+        self.seconds.extend([share] * len(batch))
+        return factors, y
 
 
 # ---------------------------------------------------------------------------
@@ -678,14 +701,13 @@ def step_scope(step: ModeStep):
 def sweep_sthosvd(x, steps: Sequence[ModeStep], *, als_iters: int,
                   oversample: int = DEFAULT_OVERSAMPLE,
                   power_iters: int = DEFAULT_POWER_ITERS,
-                  impl: str | None = None):
+                  impl: str | None = None, solve=solve_step):
     y = x
     factors: dict[int, jax.Array] = {}
     for step in steps:
         with step_scope(step):
-            res = solve_step(y, step, als_iters=als_iters,
-                             oversample=oversample, power_iters=power_iters,
-                             impl=impl)
+            res = solve(y, step, als_iters=als_iters, oversample=oversample,
+                        power_iters=power_iters, impl=impl)
         factors[step.mode] = res.u
         y = res.y_new
     return y, [factors[m] for m in range(x.ndim)]
@@ -694,11 +716,11 @@ def sweep_sthosvd(x, steps: Sequence[ModeStep], *, als_iters: int,
 def sweep_thosvd(x, steps: Sequence[ModeStep], *, als_iters: int,
                  oversample: int = DEFAULT_OVERSAMPLE,
                  power_iters: int = DEFAULT_POWER_ITERS,
-                 impl: str | None = None):
+                 impl: str | None = None, solve=solve_step):
     factors = []
     for step in steps:
         with step_scope(step):
-            factors.append(solve_step(
+            factors.append(solve(
                 x, step, als_iters=als_iters, oversample=oversample,
                 power_iters=power_iters, impl=impl).u)
     core = x
@@ -707,27 +729,32 @@ def sweep_thosvd(x, steps: Sequence[ModeStep], *, als_iters: int,
     return core, factors
 
 
-def sweep_hooi(x, steps: Sequence[ModeStep], *, als_iters: int, n_init: int,
+def sweep_hooi(x, steps: Sequence[ModeStep], *, als_iters: int,
                oversample: int = DEFAULT_OVERSAMPLE,
                power_iters: int = DEFAULT_POWER_ITERS,
-               impl: str | None = None):
-    """HOOI with its st-HOSVD init inlined: ``steps[:n_init]`` is the init
-    sweep (sequential shrink), the rest are refinement solves on x projected
-    over every factor but the step's mode."""
-    _, factors = sweep_sthosvd(x, steps[:n_init], als_iters=als_iters,
-                               oversample=oversample, power_iters=power_iters,
-                               impl=impl)
-    for step in steps[n_init:]:
+               impl: str | None = None, solve=solve_step):
+    """HOOI with its st-HOSVD init inlined: the first ``x.ndim`` steps are
+    the init sweep (sequential shrink), the rest are refinement solves on x
+    projected over every factor but the step's mode."""
+    kw = dict(als_iters=als_iters, oversample=oversample,
+              power_iters=power_iters, impl=impl)
+    _, factors = sweep_sthosvd(x, steps[:x.ndim], solve=solve, **kw)
+    for step in steps[x.ndim:]:
         with step_scope(step):
             y = x
             for m, u in enumerate(factors):
                 if m != step.mode:
                     y = T.ttm(y, u.T, m)
-            factors[step.mode] = solve_step(y, step, als_iters=als_iters,
-                                            oversample=oversample,
-                                            power_iters=power_iters,
-                                            impl=impl).u
+            factors[step.mode] = solve(y, step, **kw).u
     core = x
     for mode, u in enumerate(factors):
         core = T.ttm(core, u.T, mode)
     return core, factors
+
+
+#: THE definition of each variant: the compiled program
+#: (:func:`repro.core.api._make_sweep`) and the recorded path
+#: (``TuckerPlan.execute(record=True)``, with a :class:`StepTimer` as the
+#: ``solve``) both run the function this table names.
+SWEEPS = {"sthosvd": sweep_sthosvd, "thosvd": sweep_thosvd,
+          "hooi": sweep_hooi}

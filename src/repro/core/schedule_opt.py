@@ -55,7 +55,7 @@ singular-value tail at run time (:func:`repro.core.solvers.rand_sketch`).
 
 With ``max_group > 1`` the DP also searches MODE-PARALLEL GROUPS: a
 transition may shrink a whole set of modes at once, modeling the sharded
-runner's concurrent-Gram path (all members' Grams from the same un-shrunk
+sweep's concurrent-Gram path (all members' Grams from the same un-shrunk
 tensor, one fused multi-TTM truncation).  A group edge is priced as the
 ``max`` of its members' step costs — latency, not work — while a FLOPs sum
 is kept as the lexicographic tie-break so sequential execution wins exact

@@ -1,10 +1,12 @@
 """Mode-wise flexible st-HOSVD (a-Tucker Alg. 2) and coarse-grained variants.
 
-These are the legacy per-call entry points, kept as thin wrappers over the
-plan/execute machinery (:mod:`repro.core.plan`): the per-mode solver schedule
-is resolved up front (selector time is reported as ``select_overhead_s``) and
-then run eagerly — per-mode jitted solves with real wall-clock in the trace.
-For amortized/batched execution use :mod:`repro.core.api` instead.
+These are the legacy per-call entry points, kept as adapters with no
+algorithm of their own: each maps its arguments onto a
+:class:`~repro.core.api.TuckerConfig`, plans it, and runs the plan's
+recorded path (``execute(record=True)``: the plan's own sweep, per-mode
+wall-clock in the trace), reporting the plan's selector time as
+``select_overhead_s``.  For amortized/batched execution use
+:mod:`repro.core.api` instead.
 
 ``methods`` accepts:
   - "auto"              → adaptive selector (decision tree, cost-model fallback)
@@ -19,8 +21,8 @@ from dataclasses import dataclass, field
 from typing import Callable, Sequence
 
 import jax
+import jax.numpy as jnp
 
-from ..obs import trace as _obs
 from . import tensor_ops as T
 from .solvers import ALS, DEFAULT_ALS_ITERS, EIG, SVD
 
@@ -129,7 +131,6 @@ def sthosvd(
     als_iters: int = DEFAULT_ALS_ITERS,
     impl: str = "matfree",
     memory_cap_bytes: int | None = None,
-    block_until_ready: bool = False,
 ) -> SthosvdResult:
     """Flexible st-HOSVD (Alg. 2).  Returns factors, core, per-mode trace.
 
@@ -146,33 +147,23 @@ def sthosvd(
     ``impl`` names an ops backend (``matfree`` | ``explicit`` | ``pallas`` |
     custom-registered) or ``"auto"`` for the platform default.
     """
-    from .backend import resolve_backend
-    from .plan import TimedSelector, resolve_schedule, run_schedule
+    from .api import TuckerConfig
+    return _run_legacy(x, TuckerConfig(
+        ranks=ranks, methods=methods, mode_order=mode_order,
+        als_iters=als_iters, impl=impl, memory_cap_bytes=memory_cap_bytes),
+        selector)
 
-    backend = resolve_backend(impl, dtype=x.dtype)
-    timed = None
-    if methods == "auto":
-        if selector is None:
-            from .selector import default_selector
-            selector = default_selector()
-        selector = timed = TimedSelector(selector)
-    schedule = resolve_schedule(
-        x.shape, ranks, variant="sthosvd", methods=methods,
-        mode_order=mode_order, selector=selector, als_iters=als_iters,
-        itemsize=x.dtype.itemsize, backend=backend.name,
-        memory_cap_bytes=memory_cap_bytes)
 
-    with _obs.span("execute", shape=list(x.shape), dtype=str(x.dtype),
-                   backend=backend.name, variant="sthosvd", legacy=True):
-        core, factors, seconds = run_schedule(
-            x, schedule, sequential=True, als_iters=als_iters,
-            block_until_ready=block_until_ready)
-    trace = [ModeTrace(s.mode, s.method, s.i_n, s.r_n, s.j_n, dt,
-                       backend=s.backend, predicted_s=s.predicted_s)
-             for s, dt in zip(schedule, seconds)]
-    tucker = TuckerTensor(core=core, factors=[factors[m] for m in range(x.ndim)])
-    return SthosvdResult(tucker=tucker, trace=trace,
-                         select_overhead_s=timed.seconds if timed else 0.0)
+def _run_legacy(x, cfg, selector) -> SthosvdResult:
+    """What every legacy entry point does with its ``TuckerConfig``: plan
+    it, run the plan's recorded path (per-mode wall-clock in the trace),
+    and report the plan's selector time as ``select_overhead_s``."""
+    from .api import plan
+    x = jnp.asarray(x)
+    p = plan(x.shape, x.dtype, cfg, selector=selector)
+    res = p.execute(x, record=True)
+    res.select_overhead_s = p.select_seconds
+    return res
 
 
 # Coarse-grained baselines (paper Sec. VI) -----------------------------------

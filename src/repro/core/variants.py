@@ -2,7 +2,7 @@
 
 The paper focuses on st-HOSVD and names t-HOSVD and HOOI as the natural
 extensions ("owning to the similar algorithm structure, the proposed ideas
-and optimizations can also be extended") — both are built here on the same
+and optimizations can also be extended") — both are built on the same
 matricization-free solvers and the same adaptive selector:
 
   * t-HOSVD: every factor computed from the ORIGINAL tensor (no sequential
@@ -13,67 +13,39 @@ matricization-free solvers and the same adaptive selector:
     subproblem is a mode solve of the partially-projected tensor, so the
     EIG/ALS switch and the selector apply verbatim.
 
-Both route through :mod:`repro.core.plan`'s schedule resolution and solver
-dispatch — the per-variant copies of the selector logic are gone, and
-``impl=``/``block_until_ready=`` behave exactly as in :func:`sthosvd`.
+The algorithms live in :mod:`repro.core.plan` (``sweep_thosvd``,
+``sweep_hooi``); these entry points are adapters over
+``plan(..., TuckerConfig(variant=...)).execute(x, record=True)``, exactly
+like :func:`repro.core.sthosvd.sthosvd`.
 """
 
 from __future__ import annotations
 
-import time
-
 import jax
 
-from . import tensor_ops as T
-from .backend import resolve_backend
-from .plan import TimedSelector, resolve_schedule, run_schedule, solve_step
+from .api import TuckerConfig
 from .solvers import DEFAULT_ALS_ITERS
-from .sthosvd import ModeTrace, SthosvdResult, TuckerTensor, sthosvd
-
-
-def _auto_selector(methods, selector):
-    if methods == "auto" and selector is None:
-        from .selector import default_selector
-        selector = default_selector()
-    return TimedSelector(selector) if methods == "auto" else None
+from .sthosvd import SthosvdResult, _run_legacy
 
 
 def thosvd(x: jax.Array, ranks, methods: str = "auto", *,
            selector=None, als_iters: int = DEFAULT_ALS_ITERS,
-           impl: str = "matfree", memory_cap_bytes: int | None = None,
-           block_until_ready: bool = False) -> SthosvdResult:
+           impl: str = "matfree",
+           memory_cap_bytes: int | None = None) -> SthosvdResult:
     """Truncated HOSVD: factors from the original tensor, one projection.
 
     ``memory_cap_bytes`` fails the plan loudly when any mode solve's modeled
     peak exceeds it — t-HOSVD has no order freedom, so the cap can only be
     met by a smaller solver (or not at all)."""
-    backend = resolve_backend(impl, dtype=x.dtype)
-    timed = _auto_selector(methods, selector)
-    schedule = resolve_schedule(
-        x.shape, ranks, variant="thosvd", methods=methods,
-        selector=timed or selector, als_iters=als_iters,
-        itemsize=x.dtype.itemsize, backend=backend.name,
-        memory_cap_bytes=memory_cap_bytes)
-    _, factors, seconds = run_schedule(
-        x, schedule, sequential=False, als_iters=als_iters,
-        block_until_ready=block_until_ready)
-    trace = [ModeTrace(s.mode, s.method, s.i_n, s.r_n, s.j_n, dt,
-                       backend=s.backend, predicted_s=s.predicted_s)
-             for s, dt in zip(schedule, seconds)]
-    core = x
-    for mode in range(x.ndim):
-        core = T.ttm(core, factors[mode].T, mode)
-    return SthosvdResult(
-        TuckerTensor(core=core, factors=[factors[m] for m in range(x.ndim)]),
-        trace=trace, select_overhead_s=timed.seconds if timed else 0.0)
+    return _run_legacy(x, TuckerConfig(
+        ranks=ranks, variant="thosvd", methods=methods, als_iters=als_iters,
+        impl=impl, memory_cap_bytes=memory_cap_bytes), selector)
 
 
 def hooi(x: jax.Array, ranks, *, n_iters: int = 3, methods: str = "auto",
          selector=None, als_iters: int = DEFAULT_ALS_ITERS,
          impl: str = "matfree", mode_order=None,
-         memory_cap_bytes: int | None = None,
-         block_until_ready: bool = False,
-         init: SthosvdResult | None = None) -> SthosvdResult:
+         memory_cap_bytes: int | None = None) -> SthosvdResult:
     """Higher-order orthogonal iteration, st-HOSVD-initialized.
 
     Per sweep and mode: project x on all OTHER factors, then solve the mode
@@ -84,39 +56,7 @@ def hooi(x: jax.Array, ranks, *, n_iters: int = 3, methods: str = "auto",
     sweep — refinement sweeps always cycle 0..N-1; ``memory_cap_bytes``
     caps every step (init and refinements) at plan time.
     """
-    backend = resolve_backend(impl, dtype=x.dtype)
-    timed = _auto_selector(methods, selector)
-    base = init or sthosvd(x, ranks, methods=methods,
-                           selector=timed or selector, als_iters=als_iters,
-                           impl=impl, mode_order=mode_order,
-                           memory_cap_bytes=memory_cap_bytes,
-                           block_until_ready=block_until_ready)
-    factors = list(base.tucker.factors)
-    trace = list(base.trace)
-
-    schedule = resolve_schedule(
-        x.shape, ranks, variant="hooi", methods=methods,
-        selector=timed or selector, als_iters=als_iters, hooi_iters=n_iters,
-        include_init=False, itemsize=x.dtype.itemsize, backend=backend.name,
-        memory_cap_bytes=memory_cap_bytes)
-    for step in schedule:
-        y = x
-        for m, u in enumerate(factors):
-            if m != step.mode:
-                y = T.ttm(y, u.T, m)
-        t0 = time.perf_counter()
-        res = solve_step(y, step, als_iters=als_iters)
-        if block_until_ready:
-            jax.block_until_ready(res.u)
-        factors[step.mode] = res.u
-        trace.append(ModeTrace(step.mode, step.method, step.i_n, step.r_n,
-                               step.j_n, time.perf_counter() - t0,
-                               backend=step.backend,
-                               predicted_s=step.predicted_s))
-
-    core = x
-    for mode, u in enumerate(factors):
-        core = T.ttm(core, u.T, mode)
-    return SthosvdResult(TuckerTensor(core=core, factors=factors),
-                         trace=trace,
-                         select_overhead_s=timed.seconds if timed else 0.0)
+    return _run_legacy(x, TuckerConfig(
+        ranks=ranks, variant="hooi", methods=methods, mode_order=mode_order,
+        als_iters=als_iters, hooi_iters=n_iters, impl=impl,
+        memory_cap_bytes=memory_cap_bytes), selector)

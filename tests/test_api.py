@@ -89,26 +89,38 @@ class TestPlanning:
 
 
 class TestExecuteParity:
-    def test_execute_matches_legacy_bitwise(self):
-        """Acceptance: same resolved schedule → bitwise-identical results."""
+    @pytest.mark.parametrize("impl", ["matfree", "explicit"])
+    def test_execute_matches_legacy_bitwise(self, impl):
+        """Acceptance: same resolved schedule → bitwise-identical results,
+        from the compiled sweep, the recorded path and the legacy call."""
         x = lowrank((12, 15, 10), (3, 4, 2), noise=0.05)
-        p = plan(x.shape, x.dtype, TuckerConfig(ranks=(3, 4, 2)))
-        legacy = sthosvd(x, (3, 4, 2), methods=p.methods)
+        p = plan(x.shape, x.dtype, TuckerConfig(ranks=(3, 4, 2), impl=impl))
+        legacy = sthosvd(x, (3, 4, 2), methods=p.methods, impl=impl)
         res = p.execute(x)
-        assert bool(jnp.all(res.tucker.core == legacy.tucker.core))
-        for u_new, u_old in zip(res.tucker.factors, legacy.tucker.factors):
-            assert bool(jnp.all(u_new == u_old))
+        rec = p.execute(x, record=True)
+        for other in (legacy, rec):
+            assert bool(jnp.all(res.tucker.core == other.tucker.core))
+            for u_new, u_old in zip(res.tucker.factors, other.tucker.factors):
+                assert bool(jnp.all(u_new == u_old))
 
+    @pytest.mark.parametrize("impl", ["matfree", "explicit"])
     @pytest.mark.parametrize("variant,legacy_fn", [
-        ("thosvd", lambda x, r: thosvd(x, r, methods="eig")),
-        ("hooi", lambda x, r: hooi(x, r, n_iters=2, methods="eig")),
+        ("thosvd", lambda x, r, impl: thosvd(x, r, methods="eig", impl=impl)),
+        ("hooi", lambda x, r, impl: hooi(x, r, n_iters=2, methods="eig",
+                                         impl=impl)),
     ])
-    def test_variant_plans_match_legacy(self, variant, legacy_fn):
+    def test_variant_plans_match_legacy(self, variant, legacy_fn, impl):
         x = lowrank((10, 9, 8), (2, 3, 2), noise=0.05)
         cfg = TuckerConfig(ranks=(2, 3, 2), variant=variant, methods="eig",
-                           hooi_iters=2)
-        res = plan(x.shape, x.dtype, cfg).execute(x)
-        ref = legacy_fn(x, (2, 3, 2))
+                           hooi_iters=2, impl=impl)
+        p = plan(x.shape, x.dtype, cfg)
+        res = p.execute(x)
+        rec = p.execute(x, record=True)
+        assert all(t.seconds > 0 for t in rec.trace)
+        assert bool(jnp.all(res.tucker.core == rec.tucker.core))
+        for u_new, u_rec in zip(res.tucker.factors, rec.tucker.factors):
+            assert bool(jnp.all(u_new == u_rec))
+        ref = legacy_fn(x, (2, 3, 2), impl)
         np.testing.assert_allclose(np.asarray(res.tucker.core),
                                    np.asarray(ref.tucker.core),
                                    rtol=1e-5, atol=1e-5)

@@ -106,18 +106,22 @@ class TestSolverGuards:
         res = plan(x.shape, F32, cfg).execute(x, validate="finite")
         assert np.all(np.isfinite(np.asarray(res.tucker.core)))
 
-    def test_solver_breakdown_is_classified(self):
-        # poison an eager (per-step) solve output: the run_schedule guard
-        # must classify it as NumericalError, not let NaNs flow downstream
-        from repro.core.plan import run_schedule
-        from repro.core.api import plan as make_plan
-        chaos.install([chaos.Rule(seam="solve_out", action="nan", at=0,
+    @pytest.mark.parametrize("variant,at", [
+        ("sthosvd", 0), ("thosvd", 0),
+        ("hooi", 3),   # steps 0-2 are the init sweep: poison a refinement
+    ])
+    def test_solver_breakdown_is_classified(self, variant, at):
+        # poison one recorded (per-step) solve output: the timed solve's
+        # guard must classify it as NumericalError, not let NaNs flow
+        # downstream (eig only, so the ladder has no als->eig hop to take)
+        chaos.install([chaos.Rule(seam="solve_out", action="nan", at=at,
                                   times=1)])
         x = _rand((10, 9, 8))
-        p = make_plan(x.shape, F32, TuckerConfig(ranks=(3, 3, 3)))
+        p = plan(x.shape, F32, TuckerConfig(ranks=(3, 3, 3), methods="eig",
+                                            variant=variant, hooi_iters=1))
         with pytest.raises(NumericalError, match="non-finite"):
-            run_schedule(jnp.asarray(x), p.schedule, sequential=True,
-                         block_until_ready=True)
+            p.execute(jnp.asarray(x), record=True)
+        assert chaos.fired() == {"solve_out:nan": 1}
 
 
 # -- execute-time fallback ladder --------------------------------------------

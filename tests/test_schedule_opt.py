@@ -242,7 +242,7 @@ class TestOptPlans:
 
     def test_trace_reports_predicted_vs_actual(self):
         x = lowrank((24, 20, 16), (3, 3, 3))
-        res = sthosvd(x, (3, 3, 3), methods="eig", block_until_ready=True)
+        res = sthosvd(x, (3, 3, 3), methods="eig")
         rep = res.report()
         assert "seconds" in rep and "total" in rep
         for t in res.trace:

@@ -306,8 +306,10 @@ def test_sharded_plan_json_roundtrip_rebuilds_mesh():
 
 
 def test_distributed_wrapper_records_real_wall_clock():
-    """Satellite: sthosvd_distributed no longer hardcodes 0.0 seconds."""
+    """sthosvd_distributed, and record=True on sharded and mode-parallel
+    plans, time every step and agree with the compiled sweep."""
     run_in_subprocess("""
+        from repro.core import TuckerConfig, plan
         from repro.core.distributed import sthosvd_distributed
         mesh = jax.make_mesh((8,), ("data",))
         rng = np.random.default_rng(2)
@@ -318,6 +320,23 @@ def test_distributed_wrapper_records_real_wall_clock():
                 (methods, [t.seconds for t in res.trace])
             assert all(t.backend == "sharded" for t in res.trace)
             assert res.tucker.core.shape == (4, 5, 6)
+        for methods in ("eig", "als"):
+            for mp in ("off", 2):
+                p = plan(X.shape, X.dtype,
+                         TuckerConfig(ranks=(4, 5, 6), methods=methods,
+                                      impl="sharded", mesh=mesh,
+                                      mode_parallel=mp))
+                assert (mp == 2) == any(s.group is not None
+                                        for s in p.schedule)
+                ref = p.execute(X)
+                rec = p.execute(X, record=True)
+                assert len(rec.trace) == len(p.schedule)
+                assert all(t.seconds > 0 for t in rec.trace), \
+                    (methods, mp, [t.seconds for t in rec.trace])
+                np.testing.assert_allclose(
+                    np.asarray(rec.tucker.reconstruct()),
+                    np.asarray(ref.tucker.reconstruct()),
+                    rtol=1e-5, atol=1e-5)
         print("OK")
     """)
 
