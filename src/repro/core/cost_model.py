@@ -9,6 +9,12 @@ symbolic; :class:`CostModel` makes them *data*: the textbook defaults
 seconds-per-FLOP scale per solver — from measured records, so the same
 Eq. 4/5 structure predicts wall-clock on the box it was calibrated on.
 
+On a matrix unit the width of a contraction's output is paid in whole
+lanes: on the TPU's MXU a rank-10 TTM costs what a 128-wide one does.
+``CostModel.lane`` rounds every such width up to a multiple of the lane
+(1 on the CPU, :data:`MXU_LANE` on the TPU), so a model fitted on the
+chip prices the solvers as the chip runs them.
+
 The module-level functions (``eig_flops`` & friends) delegate to
 ``DEFAULT_COST_MODEL`` and keep the pre-CostModel call sites working.
 """
@@ -20,7 +26,15 @@ from dataclasses import dataclass, replace
 from .solvers import DEFAULT_ALS_ITERS, DEFAULT_OVERSAMPLE, DEFAULT_POWER_ITERS
 
 #: model JSON schema version (bumped when the constant set changes)
-COST_MODEL_VERSION = 1
+COST_MODEL_VERSION = 2
+
+#: output-width granularity of each platform's matrix unit; 1 elsewhere
+MXU_LANE = {"tpu": 128}
+
+
+def lane_for(platform: str) -> int:
+    """The ``CostModel.lane`` a model fitted on ``platform`` prices with."""
+    return MXU_LANE.get(platform, 1)
 
 
 @dataclass(frozen=True)
@@ -43,6 +57,11 @@ class CostModel:
         seconds.  Pure FLOP models mispredict small modes badly — ALS
         launches many more kernels per solve than EIG — so the intercept is
         part of the model, not noise (textbook default: 0).
+    lane
+        Output-width granularity of the matrix unit: every contraction
+        output width (R_n in the TTM, TTT and projection, ℓ in RAND, the
+        Gram's I_n) is rounded up to a multiple of it.  At 1 (the CPU and
+        the textbook) the counts are the plain Eq. 4/5 ones.
     source
         ``"textbook"`` or ``"calibrated"`` — whether the constants came
         from operation counts or from measured records
@@ -56,6 +75,7 @@ class CostModel:
     eig_overhead_s: float = 0.0
     als_overhead_s: float = 0.0
     rand_overhead_s: float = 0.0
+    lane: int = 1
     source: str = "textbook"
 
     @property
@@ -69,6 +89,10 @@ class CostModel:
         return self.eig_scale if self.rand_scale is None else self.rand_scale
 
     # -- kernel counts -------------------------------------------------------
+    def width(self, w: int) -> int:
+        """Output width ``w`` as the matrix unit computes it."""
+        return -(-int(w) // self.lane) * self.lane
+
     def f_eig(self, n: int) -> float:
         return self.c_eig * float(n) ** 3
 
@@ -78,7 +102,8 @@ class CostModel:
     # -- Eq. 4/5 -------------------------------------------------------------
     def eig_flops(self, i_n: int, r_n: int, j_n: int) -> float:
         """Eq. (4): Gram (I_n² J_n) + TTM (2 I_n R_n J_n) + eig."""
-        return float(i_n) * i_n * j_n + 2.0 * i_n * r_n * j_n + self.f_eig(i_n)
+        return float(i_n) * self.width(i_n) * j_n \
+            + 2.0 * i_n * self.width(r_n) * j_n + self.f_eig(i_n)
 
     def als_flops(self, i_n: int, r_n: int, j_n: int,
                   num_iters: int = DEFAULT_ALS_ITERS) -> float:
@@ -86,8 +111,9 @@ class CostModel:
         orthonormalized iteration: per iteration the R-update TTM
         (2 I_n R_n J_n), the L-update TTT (2 I_n R_n J_n) and the QR of the
         I_n×R_n block; then the closing projection TTM (2 I_n R_n J_n)."""
-        per_iter = 4.0 * i_n * j_n * r_n + self.f_qr(i_n, r_n)
-        return per_iter * num_iters + 2.0 * i_n * j_n * r_n
+        w = self.width(r_n)
+        per_iter = 4.0 * i_n * j_n * w + self.f_qr(i_n, r_n)
+        return per_iter * num_iters + 2.0 * i_n * j_n * w
 
     def rand_flops(self, i_n: int, r_n: int, j_n: int,
                    oversample: int = DEFAULT_OVERSAMPLE,
@@ -99,11 +125,12 @@ class CostModel:
         eig, and the ℓ→R_n core rotation (2 ℓ R_n J_n).  Linear in I_n
         where EIG's Gram is quadratic — this is the whole point."""
         ell = min(i_n, r_n + oversample)
-        sketch = 2.0 * i_n * ell * j_n + self.f_qr(i_n, ell)
-        power = power_iters * (4.0 * i_n * ell * j_n + self.f_qr(i_n, ell))
-        project = 2.0 * i_n * ell * j_n
-        ritz = float(ell) * ell * j_n + self.f_eig(ell) + i_n * ell * r_n
-        rotate = 2.0 * ell * r_n * j_n
+        w_ell, w_r = self.width(ell), self.width(r_n)
+        sketch = 2.0 * i_n * w_ell * j_n + self.f_qr(i_n, ell)
+        power = power_iters * (4.0 * i_n * w_ell * j_n + self.f_qr(i_n, ell))
+        project = 2.0 * i_n * w_ell * j_n
+        ritz = float(ell) * w_ell * j_n + self.f_eig(ell) + i_n * ell * w_r
+        rotate = 2.0 * ell * w_r * j_n
         return sketch + power + project + ritz + rotate
 
     def svd_flops(self, i_n: int, r_n: int, j_n: int) -> float:
@@ -153,7 +180,7 @@ class CostModel:
                 "eig_overhead_s": self.eig_overhead_s,
                 "als_overhead_s": self.als_overhead_s,
                 "rand_overhead_s": self.rand_overhead_s,
-                "source": self.source}
+                "lane": self.lane, "source": self.source}
 
     @classmethod
     def from_dict(cls, d: dict) -> "CostModel":
@@ -166,6 +193,7 @@ class CostModel:
                    eig_overhead_s=float(d.get("eig_overhead_s", 0.0)),
                    als_overhead_s=float(d.get("als_overhead_s", 0.0)),
                    rand_overhead_s=float(d.get("rand_overhead_s", 0.0)),
+                   lane=int(d.get("lane", 1)),
                    source=str(d.get("source", "textbook")))
 
     def with_(self, **kw) -> "CostModel":

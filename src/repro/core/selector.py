@@ -25,7 +25,7 @@ from pathlib import Path
 
 import numpy as np
 
-from .cost_model import DEFAULT_COST_MODEL, CostModel
+from .cost_model import DEFAULT_COST_MODEL, CostModel, lane_for
 from .dtree import DecisionTree
 
 FEATURE_NAMES = (
@@ -178,9 +178,10 @@ def default_selector(platform: str | None = None,
                      backend: str | None = None) -> Selector:
     """Trained tree for ``(platform, backend)`` if present, else the
     platform-pooled tree, else cost-model fallback (hardware-calibrated when
-    a calibration file exists for the pair).  Cached per (platform, backend),
-    so CPU and GPU model files — and per-backend refinements — resolve
-    correctly side by side in one process.
+    a calibration file exists for the pair, else the textbook counts at the
+    platform's lane, :func:`~repro.core.cost_model.lane_for`).  Cached per
+    (platform, backend), so CPU and GPU model files — and per-backend
+    refinements — resolve correctly side by side in one process.
     """
     import jax
     platform = platform or jax.default_backend()
@@ -195,7 +196,8 @@ def default_selector(platform: str | None = None,
         if sel is None:
             sel = Selector(platform=platform, backend=backend,
                            cost_model=load_calibration(platform, backend)
-                           or DEFAULT_COST_MODEL)
+                           or DEFAULT_COST_MODEL.with_(
+                               lane=lane_for(platform)))
         _DEFAULT_BY_PLATFORM[key] = sel
     return sel
 

@@ -21,6 +21,11 @@ small-problem regime wrong.)  The result feeds the trained selector's
 out-of-range guardrail, so the paper's huge-mode regime is decided by
 hardware-calibrated constants instead of textbook ones.
 
+The GEMM-family columns count each contraction at the width the
+platform's matrix unit computes (``CostModel.lane``: 128 on the TPU, 1
+elsewhere), so the fitted per-FLOP scales are per padded FLOP and the
+saved model prices with the same lane.
+
 A constant whose fitted coefficient comes back non-positive (collinear or
 starved design) silently keeps its textbook value — calibration degrades
 toward the default, never past it.
@@ -34,7 +39,7 @@ from typing import Iterable
 
 import numpy as np
 
-from ..core.cost_model import CostModel
+from ..core.cost_model import CostModel, lane_for
 from ..core.selector import calibration_path
 from .records import Measurement, RecordStore
 
@@ -42,20 +47,18 @@ from .records import Measurement, RecordStore
 MIN_RECORDS = 8
 
 
-def _eig_basis(i, r, j):
+def _eig_basis(i, r, j, lane):
     """(intercept, GEMM-family, I³) columns of the Eq. 4 decomposition."""
-    i, r, j = float(i), float(r), float(j)
-    return np.array([1.0, i * i * j + 2.0 * i * r * j, i ** 3])
+    gemm = CostModel(c_eig=0.0, lane=lane).eig_flops(i, r, j)
+    return np.array([1.0, gemm, float(i) ** 3])
 
 
-def _als_basis(i, r, j, iters):
+def _als_basis(i, r, j, iters, lane):
     """(intercept, GEMM-family, QR-count) columns of the Eq. 5
     decomposition — the QR column carries one Householder count at
     c_qr = 1 per iteration."""
-    i, r, j = float(i), float(r), float(j)
-    gemm = 4.0 * i * j * r * iters + 2.0 * i * j * r
-    return np.array([1.0, gemm,
-                     iters * (2.0 * i * r * r - (2.0 / 3.0) * r ** 3)])
+    gemm = CostModel(c_qr=0.0, lane=lane).als_flops(i, r, j, iters)
+    return np.array([1.0, gemm, iters * CostModel().f_qr(i, r)])
 
 
 def _nonneg_lstsq(a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -83,8 +86,17 @@ def fit_cost_model(measurements: Iterable[Measurement],
     Returns None when either method has fewer than ``min_records`` deduped
     records (a starved fit is worse than the textbook default).  Records
     should come from ONE (platform, backend) stratum — mixing hardware
-    mixes the very constants being fitted.
+    mixes the very constants being fitted, so records from more than one
+    platform raise ``ValueError``.  The fit counts contractions at the
+    records' platform's lane (:func:`~repro.core.cost_model.lane_for`) and
+    the model keeps it.
     """
+    measurements = list(measurements)
+    platforms = {m.platform for m in measurements}
+    if len(platforms) > 1:
+        raise ValueError("fit_cost_model needs one platform's records, got "
+                         f"{sorted(platforms)}")
+    lane = lane_for(platforms.pop()) if platforms else 1
     eig, als = {}, {}
     for m in measurements:
         slot = eig if m.method == "eig" else als if m.method == "als" else None
@@ -96,11 +108,12 @@ def fit_cost_model(measurements: Iterable[Measurement],
     if len(eig) < min_records or len(als) < min_records:
         return None
 
-    a_e = np.stack([_eig_basis(m.i_n, m.r_n, m.j_n) for m in eig.values()])
+    a_e = np.stack([_eig_basis(m.i_n, m.r_n, m.j_n, lane)
+                    for m in eig.values()])
     b_e = np.array([m.seconds for m in eig.values()])
     ce = _nonneg_lstsq(a_e, b_e)
 
-    a_a = np.stack([_als_basis(m.i_n, m.r_n, m.j_n, m.als_iters)
+    a_a = np.stack([_als_basis(m.i_n, m.r_n, m.j_n, m.als_iters, lane)
                     for m in als.values()])
     b_a = np.array([m.seconds for m in als.values()])
     ca = _nonneg_lstsq(a_a, b_a)
@@ -119,7 +132,7 @@ def fit_cost_model(measurements: Iterable[Measurement],
                      als_scale=float(a_a1) if a_a1 > 0 else 1.0,
                      eig_overhead_s=float(max(o_e, 0.0)),
                      als_overhead_s=float(max(o_a, 0.0)),
-                     source="calibrated")
+                     lane=lane, source="calibrated")
 
 
 def calibrate_store(store: RecordStore, *, platform: str | None = None,

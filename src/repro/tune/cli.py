@@ -31,10 +31,26 @@ def cmd_collect(args) -> int:
         backends=tuple(args.backends.split(",")),
         orders=tuple(int(o) for o in args.orders.split(",")),
         reps=args.reps)
-    kw.update(seed=args.seed, verbose=not args.quiet)
+    kw.update(seed=args.seed, verbose=not args.quiet,
+              tensors=[_parse_tensor(t) for t in args.tensor])
     n = collect_into(_store(args), **kw)
     print(f"collected {n} records into {args.store}")
     return 0
+
+
+def _parse_tensor(spec: str) -> tuple[tuple[int, ...], tuple[int, ...]]:
+    """``"320x240x7000:10"`` or ``"320x240x7000:10x10x10"`` → (shape,
+    ranks); a single rank applies to every mode."""
+    shape_s, _, ranks_s = spec.partition(":")
+    shape = tuple(int(d) for d in shape_s.split("x"))
+    ranks = tuple(int(r) for r in ranks_s.split("x"))
+    if len(ranks) == 1:
+        ranks = ranks * len(shape)
+    if len(ranks) != len(shape) or \
+            not all(1 <= r <= d for r, d in zip(ranks, shape)):
+        raise SystemExit(f"--tensor {spec!r}: need SHAPE:RANKS with "
+                         "1 <= rank <= dim on every mode")
+    return shape, ranks
 
 
 def cmd_harvest(args) -> int:
@@ -145,6 +161,10 @@ def build_parser() -> argparse.ArgumentParser:
     c.add_argument("--orders", default="3",
                    help="comma-separated tensor orders to rotate through")
     c.add_argument("--reps", type=int, default=2)
+    c.add_argument("--tensor", action="append", default=[],
+                   metavar="SHAPE:RANKS",
+                   help="also time every st-HOSVD step of this tensor at "
+                        "these ranks, e.g. 320x240x7000:10 (repeatable)")
     c.add_argument("--seed", type=int, default=0)
     c.add_argument("--quiet", action="store_true")
     c.set_defaults(fn=cmd_collect)

@@ -3,7 +3,9 @@
 Offline (:func:`collect`): random tensors, log-uniform dims/ranks (paper
 Sec. IV-B; covers the asymmetric one-huge-mode shapes where the EIG/ALS
 crossover lives), each mode timed with BOTH solvers through each requested
-ops backend — the paired records are exactly what labeling needs.
+ops backend — the paired records are exactly what labeling needs.  Named
+``tensors`` add every step of their st-HOSVD sweep at stated ranks, so a
+calibration also covers the step shapes a deployment runs.
 
 Online (:func:`recording` / :func:`harvest_result`): every executed
 ``TuckerPlan`` already produces per-mode ``ModeTrace`` records; inside a
@@ -38,6 +40,12 @@ SMOKE = dict(n_tensors=8, dim_range=(8, 40), backends=("matfree",),
              orders=(3,), reps=1)
 
 
+#: longest mode whose EIG is timed, per platform: on the TPU the compile of
+#: an I_n×I_n ``eigh`` grows steeply with I_n (about 4.5 GB of host memory
+#: at 1000, 11 GB at 2000), so the calibrated I³ term prices longer ones
+MAX_EIG_DIM = {"tpu": 1024}
+
+
 def _time_solver(y, mode, rank, method: str, *, impl: str,
                  als_iters: int = DEFAULT_ALS_ITERS, reps: int = 2) -> float:
     import jax
@@ -63,6 +71,7 @@ def collect(
     als_iters: int = DEFAULT_ALS_ITERS,
     reps: int = 2,
     max_elements: int = 1 << 22,
+    tensors: Sequence[tuple[tuple[int, ...], tuple[int, ...]]] = (),
     verbose: bool = False,
 ) -> list[Measurement]:
     """Time EIG vs ALS per (tensor, mode, backend) → paired Measurements.
@@ -75,6 +84,14 @@ def collect(
     ``max_elements`` caps the sampled tensor volume (higher orders would
     otherwise explode: dim_range's top end to the 4th power is terabytes)
     by halving the largest sampled dim until the tensor fits.
+
+    ``tensors`` lists ``(shape, ranks)`` pairs timed on top of the random
+    sample, at no cap: each step of the natural-order st-HOSVD sweep, on a
+    Gaussian tensor of that step's input shape (the modes before it
+    already shrunk to their ranks).
+
+    A mode longer than the platform's :data:`MAX_EIG_DIM` is timed with
+    ALS alone.
     """
     import jax
     import jax.numpy as jnp
@@ -91,6 +108,28 @@ def collect(
         return int(round(np.exp(rng.uniform(np.log(lo), np.log(hi)))))
 
     out: list[Measurement] = []
+
+    def time_modes(x, ranks, modes):
+        for impl in backends:
+            for mode in modes:
+                i_n, r_n = x.shape[mode], ranks[mode]
+                common = dict(platform=platform, backend=impl, device=device,
+                              i_n=i_n, r_n=r_n, j_n=x.size // i_n,
+                              dtype=dtype_name, order=x.ndim,
+                              als_iters=als_iters, source=COLLECT)
+                if i_n <= MAX_EIG_DIM.get(platform, i_n):
+                    # throwaway to exclude compile time, then measure
+                    _time_solver(x, mode, r_n, "eig", impl=impl, reps=1)
+                    te = _time_solver(x, mode, r_n, "eig", impl=impl,
+                                      reps=reps)
+                    out.append(Measurement(method="eig", seconds=te,
+                                           **common))
+                _time_solver(x, mode, r_n, "als", impl=impl,
+                             als_iters=als_iters, reps=1)
+                ta = _time_solver(x, mode, r_n, "als", impl=impl,
+                                  als_iters=als_iters, reps=reps)
+                out.append(Measurement(method="als", seconds=ta, **common))
+
     for t in range(n_tensors):
         order = int(orders[t % len(orders)])
         dims = [log_uniform(dim_range[0], dim_range[1])
@@ -109,25 +148,20 @@ def collect(
         ranks = tuple(log_uniform(max(1, min(4, d // 2)), max(2, d // 2))
                       for d in dims)
         x = jnp.asarray(rng.standard_normal(dims), dtype=dtype)
-        for impl in backends:
-            for mode in range(order):
-                i_n, r_n = dims[mode], ranks[mode]
-                j_n = int(np.prod(dims)) // i_n
-                common = dict(platform=platform, backend=impl, device=device,
-                              i_n=i_n, r_n=r_n, j_n=j_n, dtype=dtype_name,
-                              order=order, als_iters=als_iters,
-                              source=COLLECT)
-                # throwaway to exclude compile time, then measure
-                _time_solver(x, mode, r_n, "eig", impl=impl, reps=1)
-                _time_solver(x, mode, r_n, "als", impl=impl,
-                             als_iters=als_iters, reps=1)
-                te = _time_solver(x, mode, r_n, "eig", impl=impl, reps=reps)
-                ta = _time_solver(x, mode, r_n, "als", impl=impl,
-                                  als_iters=als_iters, reps=reps)
-                out.append(Measurement(method="eig", seconds=te, **common))
-                out.append(Measurement(method="als", seconds=ta, **common))
+        time_modes(x, ranks, range(order))
         if verbose and (t + 1) % 10 == 0:
             print(f"[tune.collect] {t + 1}/{n_tensors} tensors sampled "
+                  f"({len(out)} records)")
+    key = jax.random.PRNGKey(seed)
+    for shape, ranks in tensors:
+        for mode in range(len(shape)):
+            step = tuple(ranks[:mode]) + tuple(shape[mode:])
+            key, sub = jax.random.split(key)
+            x = jax.random.normal(sub, step, dtype)
+            time_modes(x, ranks, (mode,))
+            del x
+        if verbose:
+            print(f"[tune.collect] sweep of {shape} at ranks {ranks} timed "
                   f"({len(out)} records)")
     return out
 

@@ -369,3 +369,25 @@ class TestCLI:
         assert main(["report", "--store", store, "--model-dir", mdir]) == 0
         out = capsys.readouterr().out
         assert "selector_cpu.json" in out
+
+    def test_collect_times_each_step_of_a_named_tensor(self, tmp_path,
+                                                       monkeypatch):
+        from repro.tune.cli import main
+        from repro.tune.collect import MAX_EIG_DIM
+        monkeypatch.setitem(MAX_EIG_DIM, "cpu", 10)
+        store = str(tmp_path / "named.jsonl")
+        assert main(["collect", "--store", store, "--n-tensors", "0",
+                     "--tensor", "12x8x6:3", "--reps", "1", "--quiet"]) == 0
+        steps = {(m.i_n, m.r_n, m.j_n, m.method)
+                 for m in RecordStore(store).load()}
+        # mode n's input has the modes before it shrunk to their ranks; the
+        # 12-long mode is past the platform's MAX_EIG_DIM: ALS alone
+        assert steps == {(12, 3, 48, "als"),
+                         (8, 3, 18, "eig"), (8, 3, 18, "als"),
+                         (6, 3, 9, "eig"), (6, 3, 9, "als")}
+
+    def test_tensor_spec_needs_a_rank_per_mode(self, tmp_path):
+        from repro.tune.cli import main
+        with pytest.raises(SystemExit, match="SHAPE:RANKS"):
+            main(["collect", "--store", str(tmp_path / "x.jsonl"),
+                  "--tensor", "12x8x6:3x3"])
